@@ -50,7 +50,12 @@ class DeviceRecvPool:
     def __init__(self, capacity_bytes: int = 256 << 20):
         self.capacity = capacity_bytes
         self._used = 0
-        self._lock = threading.Lock()
+        # reentrant: release() is a weakref finalizer, and the garbage
+        # collector can fire it on ANY thread at a call boundary —
+        # including inside reserve() on the thread that already holds
+        # this lock (seen: the first smoke run from a clean checkout
+        # hung in _class_index -> finalizer -> release)
+        self._lock = threading.RLock()
         self._freed = threading.Condition(self._lock)
         # stats per class index (len+1 = oversized bucket)
         self.reserved_blocks: List[int] = [0] * (len(BLOCK_CLASSES) + 1)
@@ -172,36 +177,23 @@ class DevicePinnedStager:
     parks on the PjRt future via DeviceEventPoller.watch instead of
     anyone blocking.
 
-    Active only when BOTH the native pinned arena can serve blocks AND
-    the jax build has ``jax.experimental.transfer`` (the DMA-capable
-    transfer runtime this staging exists for). Otherwise ``land()`` is
-    exactly ``jax.device_put`` — same signature, clean fallback, which
-    is what this env without the transfer extension exercises. Tests
-    force-enable with ``DevicePinnedStager(force=True)``.
+    Active when the native pinned arena can serve blocks (the native
+    library loaded and ``mlock`` was granted). Otherwise ``land()`` is
+    exactly ``jax.device_put`` and counts the call in
+    ``fallback_count`` — same signature, and visible on /device and in
+    ``chip_smoke.py``'s summary, which fails when the arena is missing.
     """
 
-    def __init__(self, force: bool = False):
-        self._force = force
+    def __init__(self):
         self._active: Optional[bool] = None
         self.staged_count = 0
         self.fallback_count = 0
 
-    def _probe(self) -> bool:
-        from brpc_tpu import native
-        if native.alloc_pinned_block(1) is None:
-            return False
-        if self._force:
-            return True
-        try:
-            import jax.experimental.transfer  # noqa: F401
-        except Exception:
-            return False
-        return True
-
     @property
     def active(self) -> bool:
         if self._active is None:
-            self._active = self._probe()
+            from brpc_tpu import native
+            self._active = native.alloc_pinned_block(1) is not None
         return self._active
 
     def land(self, host_arr, device=None, sharding=None):
@@ -229,10 +221,18 @@ class DevicePinnedStager:
         arr = (jax.device_put(pinned_arr, dst) if dst is not None
                else jax.device_put(pinned_arr))
         self.staged_count += 1
-        # park on the PjRt future: the block goes back to the pinned
-        # freelist only once the H2D copy has consumed it
-        from brpc_tpu.fiber.device_poller import global_poller
-        global_poller().watch(arr, staging.release)
+        if next(iter(arr.devices())).platform == "cpu":
+            # the CPU client does not copy an aligned host buffer, it
+            # aliases it: the array IS the block, so the block goes
+            # back only when the array dies (recycling it on readiness
+            # let the next payload overwrite this one)
+            import weakref
+            weakref.finalize(arr, staging.release)
+        else:
+            # a real H2D copy: park on the PjRt future, the block goes
+            # back to the pinned freelist once the copy has consumed it
+            from brpc_tpu.fiber.device_poller import global_poller
+            global_poller().watch(arr, staging.release)
         return arr
 
 

@@ -79,6 +79,19 @@ def reset_all() -> None:
             _reset_errors.append(f"{name}: {type(e).__name__}: {e}")
 
 
+def abandon(obj) -> None:
+    """Child side: make an inherited device-runtime handle (a PjRt
+    transfer server, its connections, arrays parked on a poller)
+    immortal instead of dropping it. Its destructor would talk to
+    runtime threads that exist only in the parent — under jaxlib 0.9
+    the transfer server's crashes or hangs the child — so the reset
+    takes one reference that is never given back; the memory is the
+    parent's copy-on-write pages and dies with the child."""
+    if obj is not None:
+        import ctypes
+        ctypes.pythonapi.Py_IncRef(ctypes.py_object(obj))
+
+
 def registered_names() -> List[str]:
     return [n for n, _ in list(_resets)]
 

@@ -186,8 +186,11 @@ def global_poller() -> DeviceEventPoller:
 
 def _postfork_reset() -> None:
     """Fork hygiene: the poller thread and its parked fibers belong to
-    the parent's scheduler; a fresh child polls nothing yet."""
+    the parent's scheduler; a fresh child polls nothing yet. The old
+    poller may hold device arrays it was watching — abandoned, so the
+    child never runs a runtime destructor (postfork.abandon)."""
     global _global_poller, _lock
+    postfork.abandon(_global_poller)
     _global_poller = None
     _lock = threading.Lock()
 

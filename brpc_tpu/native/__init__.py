@@ -184,6 +184,10 @@ def lib() -> Optional[ctypes.CDLL]:
                 # pass the run off as sanitized with zero coverage
                 raise sanitized_load_failure(
                     san, "native library") from e
+            import logging
+            logging.getLogger("brpc_tpu.native").warning(
+                "native library unavailable, running pure Python: %s",
+                str(e)[-400:])
         _latched_san = os.environ.get("BRPC_TPU_SANITIZE", "")
         _tried = True
     return _lib

@@ -1,7 +1,11 @@
 """Builds libbrpc_tpu_native.so from src/*.cc with g++.
 
-Invoked automatically on first import of brpc_tpu.native (and rebuilt when
-any source is newer than the library). Can also be run directly:
+Invoked automatically on first import of brpc_tpu.native. An artifact is
+current when its ``.tag`` sidecar holds the hash of the compiler, the
+flags and the source BYTES it was built from — not when it is newer than
+the sources: the ``.so`` files are ignored by git, so one left on disk by
+an earlier checkout has an mtime that says nothing about the files git
+would commit. Can also be run directly:
     python -m brpc_tpu.native.build
 
 Sanitizer lane: with BRPC_TPU_SANITIZE set (e.g. "address,undefined"),
@@ -108,8 +112,8 @@ def sanitize_changed_error(latched: Optional[str]) -> RuntimeError:
 
 def _san_path(base: str, san: Sequence[str]) -> str:
     """Artifact path for a sanitizer combo: foo.so -> foo.san.so (one
-    cache per combo would be overkill; the .san artifact records its
-    combo in a sidecar tag so a different combo forces a rebuild)."""
+    cache per combo would be overkill; the flags are part of the
+    artifact's tag, so a different combo forces a rebuild)."""
     if not san:
         return base
     root, ext = os.path.splitext(base)
@@ -130,24 +134,54 @@ def _tag_path(out_path: str) -> str:
     return out_path + ".tag"
 
 
-def _stale(out_path: str, srcs, san: Sequence[str] = ()) -> bool:
+def _build_key(cmd: Sequence[str], srcs: Sequence[str]) -> str:
+    """Hash of what the artifact is made from: the command line short
+    of the output path (compiler, flags incl. sanitizers, include dir)
+    and every source's name and bytes."""
+    import hashlib
+    h = hashlib.sha256("\0".join(cmd).encode())
+    for src in srcs:
+        h.update(b"\0" + os.path.basename(src).encode() + b"\0")
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _stale(out_path: str, key: str) -> bool:
     if not os.path.exists(out_path):
         return True
-    if san:
+    try:
+        with open(_tag_path(out_path)) as f:
+            return f.read().strip() != key
+    except OSError:
+        return True
+
+
+def _compile(what: str, cmd: List[str], srcs: Sequence[str], out: str,
+             force: bool) -> str:
+    """Run ``cmd -o out srcs`` unless ``out`` is current. The compiler
+    writes beside ``out`` and the result is renamed into place, so a
+    concurrent importer never loads a half-written library."""
+    key = _build_key(cmd, srcs)
+    if not force and not _stale(out, key):
+        return out
+    tmp = f"{out}.{os.getpid()}.tmp"
+    full = [*cmd, "-o", tmp, *srcs]
+    # graftlint: disable=blocking-under-lock -- the loader latch lock IS
+    # the single-flight compile guard: a concurrent importer must wait
+    # for the one compiler run, not race a second cc1plus at the cache
+    proc = subprocess.run(full, capture_output=True, text=True)
+    if proc.returncode != 0:
         try:
-            with open(_tag_path(out_path)) as f:
-                if f.read().strip() != ",".join(san):
-                    return True
+            os.unlink(tmp)
         except OSError:
-            return True
-    mtime = os.path.getmtime(out_path)
-    return any(os.path.getmtime(s) > mtime for s in srcs)
-
-
-def _write_tag(out_path: str, san: Sequence[str]) -> None:
-    if san:
-        with open(_tag_path(out_path), "w") as f:
-            f.write(",".join(san))
+            pass
+        raise RuntimeError(
+            f"{what} build failed:\n$ {' '.join(full)}\n{proc.stderr}")
+    os.replace(tmp, out)
+    with open(_tag_path(out), "w") as f:
+        f.write(key)
+    return out
 
 
 def sources() -> list:
@@ -160,30 +194,13 @@ def sources() -> list:
     )
 
 
-def needs_build() -> bool:
-    san = sanitize_mode()
-    return _stale(_san_path(LIB_PATH, san), sources(), san)
-
-
 def build(force: bool = False,
           sanitize: Optional[Sequence[str]] = None) -> str:
     """Compile if stale; returns the library path. Raises on failure.
     ``sanitize`` defaults to the BRPC_TPU_SANITIZE env setting."""
     san = sanitize_mode() if sanitize is None else tuple(sanitize)
-    out = _san_path(LIB_PATH, san)
-    srcs = sources()
-    if not force and not _stale(out, srcs, san):
-        return out
-    cmd = [CXX, *_cxxflags(san), "-o", out, *srcs]
-    # graftlint: disable=blocking-under-lock -- the loader latch lock IS
-    # the single-flight compile guard: a concurrent importer must wait
-    # for the one compiler run, not race a second cc1plus at the cache
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"native build failed:\n$ {' '.join(cmd)}\n{proc.stderr}")
-    _write_tag(out, san)
-    return out
+    return _compile("native", [CXX, *_cxxflags(san)], sources(),
+                    _san_path(LIB_PATH, san), force)
 
 
 def build_fastcore(force: bool = False,
@@ -191,21 +208,10 @@ def build_fastcore(force: bool = False,
     """Compile the _brpc_fastcore CPython extension if stale."""
     import sysconfig
     san = sanitize_mode() if sanitize is None else tuple(sanitize)
-    out = _san_path(FASTCORE_PATH, san)
-    srcs = [os.path.join(SRC_DIR, f) for f in FASTCORE_SRCS]
-    if not force and not _stale(out, srcs, san):
-        return out
     include = sysconfig.get_paths()["include"]
-    cmd = [CXX, *_cxxflags(san), f"-I{include}", "-o", out, *srcs]
-    # graftlint: disable=blocking-under-lock -- same single-flight
-    # compile discipline as build(): the fastcore loader lock must hold
-    # through the compiler run so importers share one artifact
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fastcore build failed:\n$ {' '.join(cmd)}\n{proc.stderr}")
-    _write_tag(out, san)
-    return out
+    return _compile("fastcore", [CXX, *_cxxflags(san), f"-I{include}"],
+                    [os.path.join(SRC_DIR, f) for f in FASTCORE_SRCS],
+                    _san_path(FASTCORE_PATH, san), force)
 
 
 def _runtime_lib(name: str) -> Optional[str]:

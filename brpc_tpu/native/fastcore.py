@@ -62,6 +62,10 @@ def get():
                 # pass the run off as sanitized with zero coverage
                 raise sanitized_load_failure(
                     san, "fastcore extension") from e
+            import logging
+            logging.getLogger("brpc_tpu.native").warning(
+                "fastcore extension unavailable, running pure Python: "
+                "%s", str(e)[-400:])
         _latched_san = os.environ.get("BRPC_TPU_SANITIZE", "")
         _tried = True
     return _mod

@@ -20,22 +20,19 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from brpc_tpu.butil.jax_runtime import ensure_compile_cache
+
 REPLICA_AXIS = "replica"
 SHARD_AXIS = "shard"
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across jax versions: newer jax exports it at top
-    level (``check_vma``); older builds keep it in jax.experimental
-    under the ``check_rep`` spelling of the same knob."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as xsm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return xsm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
+    """``jax.shard_map`` over an RpcMesh. Every lowering in parallel/
+    and ops/ring_attention.py comes through here, so this is also where
+    the compile cache is placed before their first compile."""
+    ensure_compile_cache()
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_rpc_mesh(n_replicas: Optional[int] = None,

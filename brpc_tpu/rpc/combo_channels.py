@@ -16,6 +16,7 @@ calls.
 
 from __future__ import annotations
 
+import logging
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -24,6 +25,8 @@ from brpc_tpu.rpc.channel import Channel
 from brpc_tpu.rpc.controller import Controller
 from brpc_tpu.rpc.load_balancer import LoadBalancer, new_load_balancer
 from brpc_tpu.butil.endpoint import EndPoint
+
+logger = logging.getLogger("brpc_tpu.rpc")
 
 
 class SubCall:
@@ -138,6 +141,14 @@ class ParallelChannel:
         try:
             out = coll.call(fn, arrs[0])
         except Exception:
+            if not self.collective_fallbacks:
+                # once per channel: the fan-out below keeps the call's
+                # semantics, but a lowering that never works must not
+                # look like one that does
+                logger.exception(
+                    "collective lowering of %s.%s failed; falling back "
+                    "to the per-sub fan-out (collective_fallbacks "
+                    "counts the rest)", service, method)
             self.collective_fallbacks += 1
             return False
         self.collective_fused += 1

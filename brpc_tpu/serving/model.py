@@ -85,8 +85,10 @@ class TinyDecoder:
         import jax
         import jax.numpy as jnp
 
+        from brpc_tpu.butil.jax_runtime import ensure_compile_cache
         from brpc_tpu.ops.flash_attention import decode_attention
 
+        ensure_compile_cache()
         emb = jnp.asarray(self.emb)
         wq, wk = jnp.asarray(self.wq), jnp.asarray(self.wk)
         wv, wo = jnp.asarray(self.wv), jnp.asarray(self.wo)

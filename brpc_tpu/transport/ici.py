@@ -68,6 +68,7 @@ from brpc_tpu.butil.device_pool import (BLOCK_CLASSES, DeviceRecvPool,
 
 logger = logging.getLogger("brpc_tpu.ici")
 from brpc_tpu.butil.endpoint import EndPoint
+from brpc_tpu.butil.jax_runtime import local_device
 from brpc_tpu.transport import device_stats as _dev_stats
 from brpc_tpu.transport.base import Conn, Listener, Transport
 from brpc_tpu.transport.tcp import TcpConn, TcpTransport
@@ -135,9 +136,13 @@ def _postfork_reset() -> None:
     """Fork hygiene: the PjRt transfer server and its connection cache
     are device-runtime handles owned by the parent — a forked shard
     must re-probe the lane itself (or, the normal case, never touch
-    the device at all)."""
+    the device at all). The handles are abandoned, not dropped: the
+    child must not run their destructors (postfork.abandon)."""
     global _transfer_server, _transfer_failed, _transfer_error
     global _conn_cache, _lane_status_var, _server_lock
+    _postfork.abandon(_transfer_server)
+    for pconn in _conn_cache.values():
+        _postfork.abandon(pconn)
     _transfer_server = None
     _transfer_failed = False
     _transfer_error = None
@@ -194,8 +199,8 @@ def _get_transfer_server():
             import jax
             from jax.experimental import transfer
 
-            from brpc_tpu.butil.jax_env import apply_jax_platforms_env
-            apply_jax_platforms_env()   # env choice beats plugin override
+            from brpc_tpu.butil.jax_runtime import ensure_compile_cache
+            ensure_compile_cache()
             client = jax.devices()[0].client
             # explicit socket transport addresses: the default local bulk
             # transport only moves bytes within one process (aborts on a
@@ -1191,10 +1196,8 @@ class IciConn(Conn):
         per batch."""
         dev = self._recv_dev
         if dev is None:
-            devs = _jax().devices()
-            k = self._recv_device_ordinal
-            dev = devs[k] if 0 <= k < len(devs) else devs[0]
-            self._recv_dev = dev
+            dev = self._recv_dev = local_device(
+                self._recv_device_ordinal, f"ici:// conn to {self._remote}")
         return dev
 
     def _ack_grant_payload(self) -> bytes:
@@ -1664,6 +1667,7 @@ class IciTransport(Transport):
         # second PjRt bring-up there would stall every socket in the process
         _get_transfer_server()
         ordinal = ep.device or 0
+        local_device(ordinal, f"{ep} #device")     # out of range: refuse
         tcp_ep = EndPoint("tcp", ep.host or "127.0.0.1", ep.port, ep.extras)
         ready = threading.Event()
 
@@ -1685,9 +1689,10 @@ class IciTransport(Transport):
         return _IciListener(inner, bound)
 
     def connect(self, ep: EndPoint) -> Conn:
+        reply = int(ep.extra("reply_device") or 0)
+        local_device(reply, f"{ep} #reply_device")  # out of range: refuse
         tcp_ep = EndPoint("tcp", ep.host, ep.port, ep.extras)
         inner = self._tcp.connect(tcp_ep)
-        reply = ep.extra("reply_device")
         return IciConn(inner, inner.local_endpoint, ep,
-                       recv_device_ordinal=int(reply) if reply else 0,
+                       recv_device_ordinal=reply,
                        window=self._window, pool=self._pool)

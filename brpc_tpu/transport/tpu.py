@@ -27,15 +27,11 @@ from brpc_tpu.transport.base import Conn, Listener, Transport
 from brpc_tpu.transport.mem import MemConn, _MemPipe, _MemListener
 
 
-def _device_for(ordinal: Optional[int]):
-    import jax
-
-    from brpc_tpu.butil.jax_env import apply_jax_platforms_env
-    apply_jax_platforms_env()   # env choice beats the plugin's override
-    devs = jax.devices()
-    if ordinal is None or ordinal >= len(devs):
-        return devs[0]
-    return devs[ordinal]
+def _device_for(ordinal: Optional[int], what: str):
+    from brpc_tpu.butil.jax_runtime import (ensure_compile_cache,
+                                            local_device)
+    ensure_compile_cache()
+    return local_device(ordinal, what)
 
 
 class TpuConn(MemConn):
@@ -45,13 +41,21 @@ class TpuConn(MemConn):
     supports_device_lane = True
     lane_kind = "loopback-d2d"   # /device cell label (device_stats)
 
-    def __init__(self, rx, tx, local, remote, peer_device_ordinal: Optional[int]):
+    def __init__(self, rx, tx, local, remote, peer_device_ordinal: Optional[int],
+                 what: str):
         super().__init__(rx, tx, local, remote)
-        self._peer_device_ordinal = peer_device_ordinal
+        # an explicit ordinal is checked NOW (connect time): one this
+        # process does not have is an error, not device 0. The default
+        # resolves at the first payload, so host-only users of this
+        # fixture never touch the backend.
+        self._peer_device = (None if peer_device_ordinal is None
+                             else _device_for(peer_device_ordinal, what))
 
     def write_device_payload(self, arrays) -> bool:
         import jax
-        target = _device_for(self._peer_device_ordinal)
+        target = self._peer_device
+        if target is None:
+            target = self._peer_device = _device_for(None, "tpu://")
         moved = []
         for arr in arrays:
             if getattr(arr, "devices", None) is not None and callable(arr.devices) \
@@ -74,6 +78,8 @@ class TpuTransport(Transport):
         return f"{ep.host}:{ep.port}"
 
     def listen(self, ep: EndPoint, on_new_conn) -> Listener:
+        if ep.device is not None:
+            _device_for(ep.device, f"{ep} #device")
         with self._lock:
             key = self._key(ep)
             if key in self._listeners:
@@ -96,9 +102,11 @@ class TpuTransport(Transport):
         # client's reply device (the `reply_device` extra, default dev 0)
         reply = ep.extra("reply_device")
         client = TpuConn(rx=b2a, tx=a2b, local=client_ep, remote=ep,
-                         peer_device_ordinal=ep.device)
+                         peer_device_ordinal=ep.device,
+                         what=f"{ep} #device")
         server = TpuConn(rx=a2b, tx=b2a, local=server_ep, remote=client_ep,
-                         peer_device_ordinal=int(reply) if reply else None)
+                         peer_device_ordinal=int(reply) if reply else None,
+                         what=f"{ep} #reply_device")
         client.peer = server
         server.peer = client
         lst.on_new_conn(server)

@@ -89,6 +89,25 @@ class TestDeviceRecvPool:
         t.join(5)
         assert got and got[0] == 8 << 10
 
+    def test_finalizer_release_under_the_pools_own_lock(self):
+        """Regression: release() is a weakref finalizer, and the
+        collector can run it inside reserve() on the thread that holds
+        the pool lock. With a plain Lock that thread deadlocked on
+        itself (chip_smoke's first run from a clean checkout hung so)."""
+        pool = DeviceRecvPool(capacity_bytes=64 << 10)
+        f = pool.reserve(1)
+        done = []
+
+        def reenter():
+            with pool._freed:           # where reserve() holds the lock
+                pool.release(f)         # what the finalizer does
+            done.append(pool.used)
+
+        t = threading.Thread(target=reenter, daemon=True)
+        t.start()
+        t.join(5)
+        assert done == [0], "release() under the pool lock deadlocked"
+
     def test_try_reserve(self):
         pool = DeviceRecvPool(capacity_bytes=8 << 10)
         assert pool.try_reserve(1) == 8 << 10
