@@ -1,0 +1,1 @@
+"""Found by name; see benchmark/lib/loader.py."""
