@@ -92,8 +92,15 @@ define_flag("graceful_quit_on_sigterm", True,
 define_flag("rpcz_enabled", False,
             "collect per-RPC spans for /rpcz (off by default like the "
             "reference's rpcz — enable at runtime via /flags; span "
-            "creation + trace propagation cost sits on every call)")
-define_flag("rpcz_max_spans", 1024, "span ring-buffer capacity",
+            "creation + trace propagation cost sits on every call). "
+            "Spans also record, whatever this flag says, while a JAX "
+            "profile runs in the process (jax.profiler.start_trace): "
+            "rpc/span.recording(). Either way the all-C and turbo "
+            "dispatch lanes and the channel's small-call fast path "
+            "stand down meanwhile (they cannot stamp)")
+define_flag("rpcz_max_spans", 16384,
+            "span ring-buffer capacity (a 2 s profile at 550 calls/s "
+            "makes about 6,600 spans)",
             validator=lambda v: v >= 16)
 define_flag("tpu_std_cut_through", True,
             "stream large native-echo frames through the server without "

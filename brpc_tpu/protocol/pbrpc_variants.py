@@ -24,7 +24,8 @@ from brpc_tpu.protocol.proto import tpu_rpc_meta_pb2 as pb
 from brpc_tpu.protocol.registry import (
     PARSE_NOT_ENOUGH_DATA, PARSE_OK, PARSE_TRY_OTHERS, register_protocol,
 )
-from brpc_tpu.protocol.tpu_std import RpcMessage, TpuStdProtocol, pack_message
+from brpc_tpu.protocol.tpu_std import (TpuStdProtocol, cut_message,
+                                       pack_message)
 
 _SOFA_HDR = struct.Struct(">4sIII")
 _SOFA_HEADER_SIZE = 16
@@ -84,16 +85,7 @@ class SofaPbrpcProtocol(TpuStdProtocol):
             return PARSE_NOT_ENOUGH_DATA, None
         payload = portal.cut(data_size - att_size)
         attachment = portal.cut(att_size)
-        device_arrays = []
-        device_recv = None
-        if meta.device_payloads and any(not dp.inline_bytes
-                                        for dp in meta.device_payloads):
-            lane, device_recv = socket.take_device_payload_with_recv()
-            if lane is not None:
-                device_arrays = list(lane)
-        msg = RpcMessage(meta, payload, attachment, device_arrays)
-        msg.device_recv = device_recv
-        return PARSE_OK, msg
+        return PARSE_OK, cut_message(meta, payload, attachment, socket)
 
 
 _hulu: Optional[HuluPbrpcProtocol] = None

@@ -160,6 +160,22 @@ class RpcMessage:
         self.arrival_ns = time.monotonic_ns()
 
 
+def cut_message(meta: pb.RpcMeta, payload: IOBuf, attachment: IOBuf,
+                socket) -> RpcMessage:
+    """The message of a frame just cut, with its lane device arrays
+    taken from the socket (every parse site's last step). ``arrival_ns``
+    is the cut: the take can wait (pull DMA, recv-pool admission) and
+    belongs to what follows the arrival, where its own device-recv span
+    times it."""
+    msg = RpcMessage(meta, payload, attachment)
+    if meta.device_payloads and any(not dp.inline_bytes
+                                    for dp in meta.device_payloads):
+        lane, msg.device_recv = socket.take_device_payload_with_recv()
+        if lane is not None:
+            msg.device_arrays = list(lane)
+    return msg
+
+
 def serialize_payload(obj) -> bytes:
     """Shared request/response serialization ladder: bytes-likes pass
     through, IOBufs flatten, protobuf messages serialize."""
@@ -320,16 +336,7 @@ class TpuStdProtocol(Protocol):
             return PARSE_NOT_ENOUGH_DATA, None
         payload = portal.cut(body_size - meta_size - att_size)
         attachment = portal.cut(att_size) if att_size else IOBuf()
-        device_arrays: List = []
-        device_recv = None
-        if meta.device_payloads and any(not dp.inline_bytes
-                                        for dp in meta.device_payloads):
-            lane, device_recv = socket.take_device_payload_with_recv()
-            if lane is not None:
-                device_arrays = list(lane)
-        msg = RpcMessage(meta, payload, attachment, device_arrays)
-        msg.device_recv = device_recv
-        return PARSE_OK, msg
+        return PARSE_OK, cut_message(meta, payload, attachment, socket)
 
     # ------------------------------------------------------- batch parse
     # frames above this body size take the classic per-frame path (their
@@ -397,17 +404,7 @@ class TpuStdProtocol(Protocol):
             attachment = IOBuf()
             if att_size:
                 attachment.append(bytes(win[p1:off + total]))
-            device_arrays: List = []
-            device_recv = None
-            if meta.device_payloads and any(not dp.inline_bytes
-                                            for dp in meta.device_payloads):
-                lane, device_recv = \
-                    socket.take_device_payload_with_recv()
-                if lane is not None:
-                    device_arrays = list(lane)
-            m = RpcMessage(meta, payload, attachment, device_arrays)
-            m.device_recv = device_recv
-            msgs.append(m)
+            msgs.append(cut_message(meta, payload, attachment, socket))
             processed = off + total
         if not msgs:
             return None
@@ -484,13 +481,14 @@ class TpuStdProtocol(Protocol):
             serve = self._serve_fn = getattr(fcm, "serve_scan", None)
         if serve is None:
             return False     # extension missing or prebuilt-stale
-        global _turbo_ok, _flag, _cap_active
+        global _turbo_ok, _flag, _cap_active, _recording
         if _turbo_ok is None:
             from brpc_tpu.butil.flags import flag as _flag
+            from brpc_tpu.rpc.span import recording as _recording
             from brpc_tpu.rpc.server_dispatch import (
                 _server_turbo_ok as _turbo_ok,
                 capture_active as _cap_active)
-        if not _turbo_ok(server) or _flag("rpcz_enabled") \
+        if not _turbo_ok(server) or _recording() \
                 or _cap_active():
             # capture stands the all-C loop down: serve_scan never
             # crosses the interpreter, so it cannot record — requests
@@ -539,13 +537,14 @@ class TpuStdProtocol(Protocol):
         if socket.pending_responses != 0 or \
                 socket.user_data.get("bound_streams"):
             return False
-        global _turbo_ok, _flag, _cap_active
+        global _turbo_ok, _flag, _cap_active, _recording
         if _turbo_ok is None:
             from brpc_tpu.butil.flags import flag as _flag
+            from brpc_tpu.rpc.span import recording as _recording
             from brpc_tpu.rpc.server_dispatch import (
                 _server_turbo_ok as _turbo_ok,
                 capture_active as _cap_active)
-        if not _turbo_ok(server) or _flag("rpcz_enabled") \
+        if not _turbo_ok(server) or _recording() \
                 or _cap_active() \
                 or not _flag("tpu_std_cut_through"):
             return False
@@ -685,6 +684,7 @@ class TpuStdProtocol(Protocol):
 
 _turbo_ok = None    # lazily bound server_dispatch._server_turbo_ok
 _flag = None        # lazily bound butil.flags.flag
+_recording = None   # lazily bound rpc.span.recording
 _cap_active = None  # lazily bound server_dispatch.capture_active
 
 _instance: Optional[TpuStdProtocol] = None

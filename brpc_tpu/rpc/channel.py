@@ -35,6 +35,7 @@ from brpc_tpu.bvar.reducer import Adder
 from brpc_tpu.rpc import backend_stats as _bs
 from brpc_tpu.rpc import errno_codes as berr
 from brpc_tpu.rpc.controller import Controller, address_call, take_call
+from brpc_tpu.rpc.span import recording as _span_recording
 from brpc_tpu.transport import socket as _socket_mod
 from brpc_tpu.transport.input_messenger import InputMessenger
 from brpc_tpu.transport.socket import Socket, create_client_socket
@@ -483,7 +484,7 @@ class Channel:
             # stream setup piggybacks on this RPC (StreamCreate)
             from brpc_tpu.rpc.stream import Stream
             cntl.stream = Stream(stream_options)
-        if _flag("rpcz_enabled"):
+        if _span_recording():
             from brpc_tpu.rpc.span import finish_span, start_client_span
             span = start_client_span(cntl, service_name, method_name)
             span.request_size = len(cntl._request_bytes)
@@ -873,8 +874,16 @@ class Channel:
             # timeline); a write parked behind a blocked conn (chaos
             # delay, full kernel buffer) lands here late and shows as
             # queue_us.
-            if span is not None and not span.write_done_us:
-                span.write_done_us = time.monotonic_ns() // 1000
+            if span is not None:
+                # this callback runs on the writer's thread and can be
+                # delivered after the reader saw the response (even
+                # after the call completed); span.stamp_first_byte has
+                # then set write_done_us and this late stamp stays out.
+                # Clock read first: no call, so no thread switch,
+                # between the check and the store
+                now_us = time.monotonic_ns() // 1000
+                if not span.write_done_us and not span.first_byte_us:
+                    span.write_done_us = now_us
             return
         self._maybe_retry(cntl, berr.EFAILEDSOCKET, str(err),
                           failed_ep=sock.remote_endpoint

@@ -9,6 +9,7 @@ from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.protocol.tpu_std import RpcMessage, unpack_inline_device_arrays
 from brpc_tpu.rpc import errno_codes as berr
 from brpc_tpu.rpc.controller import address_call, take_call
+from brpc_tpu.rpc.span import stamp_first_byte
 from brpc_tpu.transport.syscall_stats import (note_rpc_messages as
                                               _note_rpc_messages)
 
@@ -134,7 +135,7 @@ def process_response_fast(cid: int, err_code: int, err_text, payload: bytes,
     cntl.__dict__["_bs_resp_bytes"] = len(payload) + len(att)
     span = cntl.__dict__.get("_client_span")
     if span is not None:
-        span.first_byte_us = time.monotonic_ns() // 1000
+        stamp_first_byte(span, time.monotonic_ns() // 1000)
     try:
         cntl.response_payload = PayloadBytes(payload)
         if cntl.response_msg is not None:
@@ -211,8 +212,8 @@ def process_response(proto, msg: RpcMessage, socket) -> None:
     if span is not None:
         # the frame's cut-time stamp is the closest honest "first
         # response byte" the classic path has (span.h received_us)
-        span.first_byte_us = \
-            (getattr(msg, "arrival_ns", 0) or time.monotonic_ns()) // 1000
+        stamp_first_byte(span, (getattr(msg, "arrival_ns", 0)
+                                or time.monotonic_ns()) // 1000)
     try:
         _fill_response(cntl, msg, socket)
     except Exception as e:

@@ -23,6 +23,7 @@ from brpc_tpu.protocol.tpu_std import (
     pack_small_frame, serialize_payload, unpack_inline_device_arrays)
 from brpc_tpu.rpc import errno_codes as berr
 from brpc_tpu.rpc.controller import Controller
+from brpc_tpu.rpc.span import recording
 
 
 _UNSET = object()
@@ -297,7 +298,7 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
     # samples (the dispatcher draining this conn's bytes) attribute to
     # the method the conn last served — one attr store per request
     socket.last_method = method_key
-    rz = flag("rpcz_enabled")
+    rz = recording()
     if rz:
         from brpc_tpu.rpc.span import finish_span, start_server_span
         span = start_server_span(cntl, req_meta.service_name,
@@ -584,7 +585,7 @@ def make_fast_drain(server):
         tgt = server._native_echo
         adm = server._admission
         if tgt is None or not _server_turbo_ok(server) \
-                or flag("rpcz_enabled") or capture_active() \
+                or recording() or capture_active() \
                 or (adm is not None and adm.threshold_engaged()) \
                 or sock.input_portal or sock.input_need \
                 or sock.user_data.get("_cut_forward") is not None:
@@ -815,8 +816,7 @@ def process_request_fast(proto, socket, server, cid: int, service: str,
     is the method lookup, the handler, and the (native) response pack —
     the reference runs the same span compiled
     (baidu_rpc_protocol.cpp:314 ProcessRpcRequest)."""
-    if server is None or not _server_turbo_ok(server) or \
-            flag("rpcz_enabled"):
+    if server is None or not _server_turbo_ok(server) or recording():
         # NOTE: capture no longer bounces this lane to the classic
         # path — the turbo body records in-line (_drive_fast_inner),
         # so the hot lane keeps serving while the recorder runs

@@ -8,13 +8,19 @@ leveldb SpanDB, span.cpp:308, as rotating JSON-lines files):
 /rpcz?history=1 reads back spans that have aged out of the ring. Trace
 ids propagate in RpcMeta (trace_id/span_id/parent_span_id fields), so
 multi-hop call trees link up.
+
+Spans record while ``recording()`` is true: the operator's flag
+``rpcz_enabled`` is set, or a JAX profile is being recorded in this
+process (``jax.profiler.start_trace``). Each profile gets one
+``rpcz.clock`` event that ties the spans' clock to the profile's.
 """
 
 from __future__ import annotations
 
-import itertools
 import json
+import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -31,6 +37,67 @@ from brpc_tpu.butil.flags import flag
 # start_us + this offset (the reference's span.h base_real_us plays the
 # same role for its cpuwide stamps)
 _REAL_OFFSET_US = time.time_ns() // 1000 - time.monotonic_ns() // 1000
+
+# ---- recording(): the one predicate every span site asks.
+# jax's profiler state, looked up through sys.modules so that nothing
+# here imports jax: None = jax.profiler not loaded yet (asked again on
+# the next call), False = this jax has no such attribute (decided once)
+_PROFILER_MODULE = "jax._src.profiler"
+_profile_state = None
+# the profile session ``rpcz.clock`` was last emitted for. Held, not its
+# id: a session that is still referenced cannot share an identity with
+# the next one; dropped when recording() next sees no session
+_clock_session = None
+
+
+def _find_profile_state():
+    global _profile_state
+    mod = sys.modules.get(_PROFILER_MODULE)
+    if mod is None:
+        return None
+    state = getattr(mod, "_profile_state", None)
+    if not hasattr(state, "profile_session") \
+            or not hasattr(mod, "TraceAnnotation"):
+        logging.getLogger(__name__).warning(
+            "rpcz: %s has no _profile_state.profile_session in this jax; "
+            "spans follow the rpcz_enabled flag only", _PROFILER_MODULE)
+        state = False
+    _profile_state = state
+    return state
+
+
+def recording() -> bool:
+    """Whether spans are being recorded: while a JAX profile runs in this
+    process, or while ``rpcz_enabled`` is set. Off, it costs a global
+    read, an attribute read and a flag lookup; no lock, no import."""
+    global _clock_session
+    state = _profile_state
+    if state is None:
+        state = _find_profile_state()
+    if state:
+        session = state.profile_session
+        if session is not None:
+            if session is not _clock_session:
+                _clock_session = session
+                _emit_clock()
+            return True
+        if _clock_session is not None:
+            _clock_session = None
+    return flag("rpcz_enabled")
+
+
+def _emit_clock() -> None:
+    """One event a profile session, nothing per call: ``rpcz.clock``
+    carries this clock's reading at the event's own start, so profile
+    time of any stamp = event start + (stamp - monotonic_ns). Two threads
+    that meet a new session together may both emit; each event is right
+    by itself."""
+    try:
+        annotation = sys.modules[_PROFILER_MODULE].TraceAnnotation
+        with annotation("rpcz.clock", monotonic_ns=time.monotonic_ns()):
+            pass
+    except Exception:  # noqa: BLE001 - tracing must not fail a call
+        logging.getLogger(__name__).exception("rpcz.clock not emitted")
 
 
 @dataclass
@@ -67,9 +134,11 @@ class Span:
     annotations: List[Tuple[int, str]] = field(default_factory=list)
     # response-flush delegation latch (server side): when the response
     # write's completion callback owns the flush stamp, finish_span may
-    # run before OR after it — exactly one of them submits the span
-    _flush_lock: threading.Lock = field(default_factory=threading.Lock,
-                                        repr=False, compare=False)
+    # run before OR after it — exactly one of them submits the span.
+    # The lock is made by expect_flush: only a server span whose flush
+    # is delegated needs one
+    _flush_lock: Optional[threading.Lock] = field(default=None, repr=False,
+                                                  compare=False)
     _await_flush: bool = field(default=False, repr=False, compare=False)
     _finish_ready: bool = field(default=False, repr=False, compare=False)
 
@@ -177,8 +246,10 @@ class SpanCollector:
         self._ring: Deque[Span] = deque(maxlen=capacity or flag("rpcz_max_spans"))
 
     def submit(self, span: Span) -> None:
-        if not flag("rpcz_enabled"):
-            return
+        if recording():
+            self._append(span)
+
+    def _append(self, span: Span) -> None:
         with self._lock:
             # honor runtime /flags mutation of rpcz_max_spans: resize the
             # ring when the flag moved (constructor-captured maxlen would
@@ -215,6 +286,7 @@ class SpanStore:
     FILE = "rpcz_spans.jsonl"
     _FLUSH_EVERY = 32
     _FLUSH_S = 0.5
+    _SETTLE_S = 0.5
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -248,6 +320,8 @@ class SpanStore:
 
     def write(self, span: "Span") -> None:
         dirpath = flag("rpcz_dir")
+        if not dirpath and self._fh is None and not self._buf:
+            return      # memory only, nothing to drop: no lock taken
         with self._lock:
             if not dirpath:
                 # flag cleared at runtime: drop buffered lines and the
@@ -312,10 +386,17 @@ class SpanStore:
     def flush(self) -> None:
         """Force buffered lines to disk (server stop / process exit —
         the last spans before a shutdown are usually the interesting
-        ones)."""
+        ones). A server span trails its response: the peer has the
+        bytes while this side still waits for the interpreter to stamp
+        the flush and submit (2 ms seen), and Server.join counts a
+        request only up to on_request_end. So spans whose response
+        write is under way are waited for first, briefly."""
         dirpath = flag("rpcz_dir")
         if not dirpath:
             return
+        deadline = time.monotonic() + self._SETTLE_S
+        while _pending_flush and time.monotonic() < deadline:
+            time.sleep(0.002)
         with self._lock:
             if self._buf:
                 try:
@@ -323,6 +404,10 @@ class SpanStore:
                 except OSError:
                     self._buf.clear()
 
+
+# server spans armed by expect_flush and not yet submitted, by id (a
+# Span does not hash); dict stores and pops are atomic under the GIL
+_pending_flush: dict = {}
 
 global_store = SpanStore()
 global_collector = SpanCollector()
@@ -349,6 +434,7 @@ def _postfork_reset() -> None:
             pass
     global_collector._lock = threading.Lock()
     global_collector._ring.clear()
+    _pending_flush.clear()
 
 
 from brpc_tpu.butil import postfork as _postfork  # noqa: E402
@@ -462,7 +548,6 @@ def start_device_span(parent: Span, peer: str, lane: str) -> Span:
         start_us=time.monotonic_ns() // 1000,
         log_id=parent.log_id,
     )
-    span.annotate(f"device transfer peer={peer} lane={lane}")
     return span
 
 
@@ -502,12 +587,25 @@ def submit_device_recv_span(parent: Span, dr: dict) -> None:
     drift."""
     span = start_device_span(parent, dr.get("peer", ""),
                              dr.get("lane", ""))
+    # what tells it from the sending half (service "device"); the take's
+    # time and bytes are the span's own latency_us and request_size
+    span.service = "device-recv"
     span.start_us = dr.get("t_us") or span.start_us
     span.end_us = span.start_us + int(dr.get("recv_us", 0))
     span.request_size = dr.get("nbytes", 0)
-    span.annotate(f"device-recv recv_us={dr.get('recv_us')} "
-                  f"nbytes={dr.get('nbytes')}")
     _submit_span(span)
+
+
+def stamp_first_byte(span: Span, us: int) -> None:
+    """The client's reader saw the response frame. Its request write was
+    done by then, whatever the write's completion callback says: that
+    callback runs on the writer's thread and can be delivered later than
+    this, even after the call completed. Of two threads' stamps of one
+    boundary the earlier holds, so write_done_us never passes
+    first_byte_us (Channel._on_write_done keeps out once this ran)."""
+    span.first_byte_us = us
+    if not span.write_done_us or span.write_done_us > us:
+        span.write_done_us = us
 
 
 def submit_span(span: Span) -> None:
@@ -522,7 +620,11 @@ def expect_flush(span: Span) -> None:
     finish_span / mark_flushed runs LAST submits the span — so the
     stored timeline includes the real write completion even when the
     conn blocks (a chaos ``delay`` fault, a saturated peer) and the
-    dispatch context moves on."""
+    dispatch context moves on. Called before the write is issued, so the
+    latch's lock exists before mark_flushed can run."""
+    if span._flush_lock is None:
+        span._flush_lock = threading.Lock()
+        _pending_flush[id(span)] = span
     span._await_flush = True
 
 
@@ -559,6 +661,8 @@ def finish_span(span: Span, cntl) -> None:
 
 
 def _submit_span(span: Span) -> None:
-    global_collector.submit(span)
-    if flag("rpcz_enabled"):
+    if span._flush_lock is not None:
+        _pending_flush.pop(id(span), None)
+    if recording():
+        global_collector._append(span)
         global_store.write(span)

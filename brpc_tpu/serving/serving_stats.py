@@ -445,9 +445,10 @@ def open_generation(service: str, method: str, cntl=None,
     cell = global_serving_stats().serving_cell(label)
     cell.note_gen_open()
     span = None
-    if cntl is not None and _flag("rpcz_enabled"):
-        from brpc_tpu.rpc.span import start_serving_span
-        span = start_serving_span(cntl, service, method)
+    if cntl is not None:
+        from brpc_tpu.rpc.span import recording, start_serving_span
+        if recording():
+            span = start_serving_span(cntl, service, method)
     tr = GenTracker(cell, span,
                     created_ns if created_ns is not None
                     else time.monotonic_ns())

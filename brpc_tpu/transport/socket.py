@@ -291,6 +291,17 @@ _idle_var = PassiveStatus(idle_conn_count)
 _avg_var = PassiveStatus(conn_resident_bytes_avg)
 expose_conn_census_vars()
 
+
+
+def _span_recording() -> bool:
+    """rpc.span.recording, bound on first use (rpc imports this
+    module)."""
+    global _span_recording
+    from brpc_tpu.rpc.span import recording
+    _span_recording = recording
+    return recording()
+
+
 from brpc_tpu.butil import resource_census as _resource_census  # noqa: E402
 #   (census registration ships with the socket registry it measures)
 
@@ -1767,7 +1778,7 @@ class Socket:
             self._dev_recv = cached
         nbytes = sum(getattr(a, "nbytes", 0) or 0 for a in lane)
         cached[2].note_recv(dur_us, nbytes)
-        if flag("rpcz_enabled"):
+        if _span_recording():
             # parse-path handoff: the protocol attaches this to the
             # message so dispatch can hang a device-recv child span off
             # the server span it is about to create (parse per conn is
