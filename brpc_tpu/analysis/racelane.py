@@ -22,7 +22,7 @@ The declared order below is the sanctioned registry published in
 ``docs/invariants.md`` — one line per lock, outermost first. Locks not
 listed are unranked: they perturb but never trip the order assert.
 Runtime naming matches registry rows by UNIQUE attribute suffix
-(``_arb_lock``, ``lane_lock``, ...); rows whose attr is the generic
+(``_arb_lock``, ``_handoff_lock``, ...); rows whose attr is the generic
 ``_lock`` are ambiguous at runtime and covered by the static
 lock-cycle rule only.
 
@@ -75,7 +75,6 @@ LOCK_ORDER: List[Tuple[str, str]] = [
     ("FlightRecorder._lock",        "builtin/flight_recorder.py"),
     ("Stream._grant_lock",          "rpc/stream.py"),
     ("ProgressiveAttachment._lock", "rpc/progressive.py"),
-    ("Socket.lane_lock",            "transport/socket.py"),
     ("Socket._handoff_lock",        "transport/socket.py"),
     ("Socket.pending_lock",         "transport/socket.py"),
     ("Socket._failed_cb_lock",      "transport/socket.py"),

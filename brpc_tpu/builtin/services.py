@@ -56,7 +56,10 @@ def connections_page(server) -> dict:
     # ``connections`` only.
     from brpc_tpu.transport.socket import socket_census_rows
     crows = []
-    for s, resident, idle_s in socket_census_rows():
+    # a fresh walk, not the 0.2 s memo: a page a person reads must list
+    # the connection made a moment ago (the walk re-arms the memo, so
+    # a /census read right after still agrees with these rows)
+    for s, resident, idle_s in socket_census_rows(max_age_s=0):
         ch = s.user_data.get("channel")
         if ch is None:
             continue

@@ -70,7 +70,8 @@ class ChaosConn(Conn):
         self._on_writable: Optional[Callable[[], None]] = None
 
     # ------------------------------------------------------------- writes
-    def write(self, mv: memoryview) -> int:
+    def write(self, mv: memoryview, **kw) -> int:
+        # ``kw``: a gathering inner conn's flush=False passes through
         if self._dropped:
             raise BrokenPipeError("chaos: connection dropped")
         if not isinstance(mv, memoryview):
@@ -131,7 +132,7 @@ class ChaosConn(Conn):
                 mv = mv[:f.at_byte - self._wrote]
                 break
             break
-        n = self._inner.write(mv)
+        n = self._inner.write(mv, **kw)
         self._wrote += n
         if faults and faults[0].kind == "corrupt" \
                 and faults[0]._armed_ns is not None:
@@ -182,12 +183,8 @@ class ChaosConn(Conn):
             return
         self._inner.request_writable_event()
 
-    def write_device_payload(self, arrays, tracker=None):
-        if tracker is not None and \
-                getattr(self._inner, "supports_device_tracker", False):
-            return self._inner.write_device_payload(arrays,
-                                                    tracker=tracker)
-        return self._inner.write_device_payload(arrays)
+    def write_device_payload(self, arrays, **kw):
+        return self._inner.write_device_payload(arrays, **kw)
 
     @property
     def supports_device_lane(self) -> bool:

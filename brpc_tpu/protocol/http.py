@@ -730,6 +730,11 @@ class HttpProtocol(Protocol):
                 "write_queue": (s._wq.depth()
                                 if getattr(s, "_wq", None) is not None else 0),
                 "write_queue_bytes": getattr(s, "wq_bytes", 0),
+                # claims of writership that sent in place / spawned a
+                # keep_write fiber, and the conn family they count under
+                "family": s.family,
+                "write_inplace": s.write_inplace,
+                "write_fiber_spawns": s.write_fiber_spawns,
                 "preferred_protocol": s.preferred_protocol,
             })
             # device-lane introspection for ici:// conns (the page the

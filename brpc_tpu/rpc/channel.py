@@ -824,42 +824,20 @@ class Channel:
             meta, request_bytes, attachment=_copy_buf(cntl.request_attachment),
             device_arrays=cntl.request_device_arrays, device_lane=use_lane)
         try:
-            if lane is not None:
-                # lane + wire must hit the conn as an adjacent pair:
-                # another device-payload call slipping between them would
-                # cross-match lane batches on the receiver. The defer-
-                # flush hold moves the TCP syscalls for both frames out
-                # from under lane_lock (one gather-write at release), so
-                # concurrent callers serialize only on the queue pushes.
-                conn = getattr(sock, "conn", None)
-                hold = getattr(conn, "hold_flush", None)
-                if hold is not None:
-                    hold()
-                try:
-                    with sock.lane_lock:
-                        # the device batch's stage tracker hangs its child
-                        # span off this call's client span (trace inherit)
-                        sock.write_device_payload(lane,
-                                                  span=d.get("_client_span"))
-                        # graftlint: disable=callback-under-lock -- lane_lock
-                        # exists to make exactly this pair atomic (device
-                        # batch + envelope adjacent on the conn); Socket.write
-                        # only queues — it never parks and the on_done fires
-                        # from the drain, not here
-                        sock.write(wire, on_done=lambda err, s=sock,
-                                   q=d["_issue_seq"],
-                                   sp=d.get("_client_span"):
-                                   self._on_write_done(cntl, err, s, q, sp))
-                finally:
-                    if hold is not None:
-                        conn.release_flush()
-            else:
-                sock.write(wire, on_done=lambda err, s=sock,
-                           q=d["_issue_seq"], sp=d.get("_client_span"):
-                           self._on_write_done(cntl, err, s, q, sp))
+            # a lane batch and its envelope enter the socket's write
+            # queue as one item (the receiver matches batches to
+            # envelopes FIFO); the socket's single writer sends both and
+            # fires on_done after the flush, in its own context with no
+            # lock held — a failed write may re-issue from there. The
+            # batch's stage tracker hangs its child span off this call's
+            # client span (trace inherit).
+            sock.write(wire, on_done=lambda err, s=sock,
+                       q=d["_issue_seq"], sp=d.get("_client_span"):
+                       self._on_write_done(cntl, err, s, q, sp),
+                       device_arrays=lane, span=d.get("_client_span"))
         except (BlockingIOError, ConnectionError, OSError) as e:
-            # lane backpressure / dead conn must fail the controller (or
-            # retry), never escape to the caller with the call leaked
+            # a dead conn must fail the controller (or retry), never
+            # escape to the caller with the call leaked
             self._maybe_retry(cntl, berr.EFAILEDSOCKET, str(e),
                               failed_ep=sock.remote_endpoint)
 

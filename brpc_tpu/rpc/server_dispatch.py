@@ -976,37 +976,12 @@ def _send_response(proto, socket, cid: int, cntl: Controller,
     if span is not None:
         span.response_size = len(payload)
         span.serialized_us = time.monotonic_ns() // 1000
-    if lane is not None:
-        # adjacent pair under the lane lock (see Channel._issue_rpc).
-        # The defer-flush hold keeps the TCP syscalls for both frames
-        # OUT of the lane_lock critical section (one gather-write at
-        # release) — worker fibers were measurably serializing on the
-        # flush here under concurrent device-payload responses.
-        conn = getattr(socket, "conn", None)
-        hold = getattr(conn, "hold_flush", None)
-        if hold is not None:
-            hold()
-        try:
-            with socket.lane_lock:
-                # the response batch's stage tracker hangs its device span
-                # off this request's server span (trace inheritance)
-                socket.write_device_payload(lane, span=span)
-                if span is not None:
-                    # armed only once the write is certain to be issued (an
-                    # armed latch with no callback would strand the span)
-                    expect_flush(span)
-                # graftlint: disable=callback-under-lock -- lane_lock makes
-                # the device batch + envelope adjacent on the conn (same
-                # pairing discipline as Channel._issue_rpc); Socket.write
-                # only queues and on_done fires from the drain
-                socket.write(wire, on_done=on_done)
-        finally:
-            if hold is not None:
-                conn.release_flush()
-    else:
-        if span is not None:
-            expect_flush(span)
-        socket.write(wire, on_done=on_done)
+    if span is not None:
+        expect_flush(span)
+    # a lane batch rides the same queue item as its envelope (see
+    # Socket.write); its stage tracker hangs the device span off this
+    # request's server span (trace inheritance)
+    socket.write(wire, on_done=on_done, device_arrays=lane, span=span)
 
 
 def _send_error(proto, socket, cid: int, code: int, text: str) -> None:

@@ -181,11 +181,9 @@ class Stream:
         use_lane = bool(device_arrays) and self.socket.conn.supports_device_lane
         wire, lane = pack_message(meta, payload, device_arrays=device_arrays,
                                   device_lane=use_lane)
-        if lane is not None:
-            self.socket.write_device_payload(lane)
         # graftlint: disable=callback-under-lock -- see _send_frame's
         # raw-frame branch: write only queues, sender locks order tokens
-        self.socket.write(wire)
+        self.socket.write(wire, device_arrays=lane)
 
     # -------------------------------------------------------------- receive
     def _on_frame(self, msg) -> None:

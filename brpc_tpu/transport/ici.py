@@ -585,9 +585,16 @@ class IciConn(Conn):
     (rdma_endpoint.h:235-241)."""
 
     supports_device_lane = True
-    # Socket.write_device_payload passes a stage tracker through to the
-    # flush/ack machinery (transport/device_stats.BatchTracker)
+    # the socket's writer hands a queued batch's stage tracker through
+    # to the flush/ack machinery (transport/device_stats.BatchTracker)
     supports_device_tracker = True
+    # write() and write_device_payload() only enqueue and flush; they
+    # never park the caller, so the context whose push claims a
+    # socket's writership sends in place (TcpConn's write-once
+    # discipline). Both take flush=False: the socket's single writer
+    # queues a device batch, its envelope and whatever other callers
+    # queued behind them, then calls flush() once — one TCP write.
+    inline_write_ok = True
 
     def __init__(self, inner: TcpConn, local: EndPoint, remote: EndPoint,
                  recv_device_ordinal: int = 0,
@@ -649,11 +656,6 @@ class IciConn(Conn):
         # flow-control state (receiver side)
         self._consumed = 0                       # batches we pulled
         self._acked_sent = 0                     # last consumed count sent
-        # defer-flush window (hold_flush/release_flush): >0 means a
-        # caller is batching enqueues (device batch + its envelope) and
-        # will drain them in one gather-write at release
-        self._hold_depth = 0
-        self._flush_pending = False
         # adaptive window: last grant the peer rode on a bare ACK
         # (0 = none yet; effective window stays the hello window)
         self._peer_grant = 0
@@ -927,33 +929,6 @@ class IciConn(Conn):
             self._coalesced_batches += len(items)
         return frame
 
-    def hold_flush(self) -> None:
-        """Open a defer-flush window: while at least one hold is open,
-        _flush() only notes that work is pending — the matching
-        release_flush() drains everything in ONE gather-write. Channel
-        and server dispatch hold across their lane_lock pairing (device
-        batch + its envelope) so the TCP syscalls run OUTSIDE the lock
-        instead of serializing every worker fiber on it."""
-        with self._lock:
-            self._hold_depth += 1
-
-    def release_flush(self) -> None:
-        with self._lock:
-            self._hold_depth -= 1
-            fire = self._hold_depth == 0 and self._flush_pending
-            if fire:
-                self._flush_pending = False
-        if fire:
-            drained = self._flush()
-            # mirror _pump_locked's tail: a deferred flush that drains
-            # a previously-stalled queue must still fire the writable
-            # edge, or a parked keep_write fiber stays parked
-            if drained and self._want_writable:
-                self._want_writable = False
-                cb = self._on_writable_cb
-                if cb is not None:
-                    cb()
-
     def _flush(self) -> bool:
         """Drain wirebuf + eligible queue items into TCP. Single-flight
         (two flushers would interleave framed bytes). True = all
@@ -962,11 +937,6 @@ class IciConn(Conn):
         syscall, not one per item."""
         if self._poisoned is not None:
             raise ConnectionError(self._poisoned)
-        if self._hold_depth > 0:
-            with self._lock:
-                if self._hold_depth > 0:
-                    self._flush_pending = True
-                    return False
         with self._flush_lock:
             while True:
                 # re-check INSIDE the lock: a writer that passed the
@@ -1064,19 +1034,31 @@ class IciConn(Conn):
                          tracker))
         return False
 
-    def write(self, mv: memoryview) -> int:
+    def write(self, mv: memoryview, flush: bool = True) -> int:
         if self._poisoned is not None:
             raise ConnectionError(self._poisoned)
         data = bytes(mv)
         self._enqueue(("bytes", data))
-        self._flush()
+        if flush:
+            self._flush()
         return len(data)
 
-    def write_device_payload(self, arrays, tracker=None) -> bool:
+    def flush(self) -> bool:
+        """Frame everything queued and hand it to TCP in one write
+        (True = all drained). The closing half of a run of flush=False
+        writes; a flush that stalls (TCP full, window closed) resumes
+        from the writable event or the ACK edge on its own."""
+        return self._flush()
+
+    def write_device_payload(self, arrays, tracker=None,
+                             flush: bool = True) -> bool:
         """Stage jax arrays on our device and queue the batch. Host
         inputs are device_put once here (H2D staging); from then on the
         payload moves device-to-device only. ``tracker``: the
-        device_stats stage timeline riding this batch (or None)."""
+        device_stats stage timeline riding this batch (or None).
+        ``flush=False``: the envelope follows and its flush carries
+        both. A full out-buffer raises BlockingIOError with the tracker
+        still open: the caller parks the batch and hands it again."""
         jax = _jax()
         staged = []
         for a in arrays:
@@ -1096,13 +1078,14 @@ class IciConn(Conn):
             raise ConnectionError(reason)
         try:
             self._enqueue(("lane", staged, tracker))
-        except (ConnectionError, BlockingIOError) as e:
-            # closed-conn / out-buffer refusal: settle here — the batch
-            # never entered a queue any sweep covers
+        except ConnectionError as e:
+            # closed-conn refusal: settle here — the batch never
+            # entered a queue any sweep covers
             if tracker is not None:
                 tracker.lane_failed(str(e))
             raise
-        self._flush()
+        if flush:
+            self._flush()
         return True
 
     # ---------------------------------------------------------- inbound

@@ -182,6 +182,39 @@ def _fastcore():
 # socket-level traffic + fast-lane health, visible at /vars (the
 # reference self-instruments every subsystem the same way)
 nwrites = Adder().expose("socket_writes")
+# how each claim of writership sent: in place, in the context whose
+# push claimed it, or through a keep_write fiber spawned for it (one
+# scheduling hop before the conn sees the frame). Writes that queued
+# behind an active writer are in neither. One Adder pair per conn
+# family (tcp, ici, mem, ...: the conn class), made when the family's
+# first socket is; /sockets shows each socket's own pair and
+# syscall_stats.snapshot() carries the totals.
+_write_mode: dict = {}       # family -> (in place, fiber spawns)
+_write_mode_lock = threading.Lock()
+
+
+def _write_mode_pair(family: str):
+    pair = _write_mode.get(family)
+    if pair is None:
+        with _write_mode_lock:
+            pair = _write_mode.get(family)
+            if pair is None:
+                pair = (Adder(), Adder())
+                _write_mode[family] = pair
+                _expose_write_mode(family, pair)
+    return pair
+
+
+def _expose_write_mode(family: str, pair) -> None:
+    pair[0].expose(f"socket_write_inplace_{family}")
+    pair[1].expose(f"socket_write_fiber_spawns_{family}")
+
+
+def write_mode_totals():
+    """(in-place claims, keep_write fiber spawns) over every family."""
+    pairs = list(_write_mode.values())
+    return (sum(p[0].get_value() or 0 for p in pairs),
+            sum(p[1].get_value() or 0 for p in pairs))
 nreads = Adder().expose("socket_read_bytes")
 npluck_fast = Adder().expose("pluck_fast_responses")   # native-loop wins
 npluck_defer = Adder().expose("pluck_defers")          # classic fallbacks
@@ -280,11 +313,13 @@ def conn_resident_bytes_avg() -> float:
 
 
 def expose_conn_census_vars() -> None:
-    """(Re-)expose the connection-cost bvars — called at import and
-    again from Server.start, surviving a test fixture's unexpose_all
-    like the other socket counters."""
+    """(Re-)expose the connection-cost bvars and the write-mode pairs —
+    called at import and again from Server.start, surviving a test
+    fixture's unexpose_all like the other socket counters."""
     _idle_var.expose("idle_conn_count")
     _avg_var.expose("conn_resident_bytes_avg")
+    for family, pair in list(_write_mode.items()):
+        _expose_write_mode(family, pair)
 
 
 _idle_var = PassiveStatus(idle_conn_count)
@@ -484,10 +519,14 @@ class Socket:
         # thread +=, writer -=; GIL-atomic enough for a gauge) — the
         # per-socket write-queue saturation signal (/sockets page)
         self.wq_bytes = 0
-        # pairs a device-lane batch with its wire frame: concurrent
-        # device-payload writers must not interleave (lane batches are
-        # matched to messages by FIFO order)
-        self.lane_lock = threading.Lock()
+        # writership claims that sent in place / spawned a keep_write
+        # fiber (the /sockets twins of socket_write_inplace and
+        # socket_write_fiber_spawns)
+        self.write_inplace = 0
+        self.write_fiber_spawns = 0
+        self.family = type(conn).__name__.removesuffix("Conn").lower()
+        self._nwrite_inplace, self._nwrite_fiber = \
+            _write_mode_pair(self.family)
         self._on_failed_cbs: list = []
         self._failed_cb_lock = threading.Lock()   # failed-flag/append race
         # captured once: /flags mutation applies to new sockets (a dict
@@ -497,6 +536,10 @@ class Socket:
         self._drain_all_reads = getattr(conn, "drain_all_reads", False)
         self._level_triggered = getattr(conn, "level_triggered", False)
         self._writev = getattr(conn, "writev", None)
+        # a conn that frames its own queue and flushes on request
+        # (ici://) gets the gathering writer: _write_gathered
+        self._conn_flush = getattr(conn, "flush", None)
+        self._lane_tracked = getattr(conn, "supports_device_tracker", False)
         self._readv = getattr(conn, "read_into_v", None)
         self._read_chunks = getattr(conn, "read_chunks", None)
         # async big-write routing applies only to kernel-copy fd conns
@@ -600,20 +643,32 @@ class Socket:
         return self.conn.local_endpoint
 
     # -------------------------------------------------------------- write
-    def write(self, data, on_done: Optional[Callable] = None) -> bool:
+    def write(self, data, on_done: Optional[Callable] = None,
+              device_arrays=None, span=None) -> bool:
         """Enqueue an IOBuf or a ready-made bytes frame and return
         immediately; ordering is FIFO per socket. Bytes frames skip the
         IOBuf machinery unless the conn blocks mid-frame (the reference's
         write-once-in-place, socket.cpp:1960). On an already-failed
         socket the done callback still fires (with the failure) so
-        callers' retry paths run — never a silent drop."""
-        return self._submit(data, on_done)
+        callers' retry paths run — never a silent drop.
+
+        ``device_arrays``: the out-of-band device batch this frame is
+        the envelope of (device-lane conns only; host transports
+        serialize instead). The receiver matches lane batches to
+        envelopes in FIFO order, so the pair enters the write queue as
+        ONE item and the single writer hands batch, then frame, to the
+        conn back to back: no second caller can come between them and
+        no lock is held while either is sent. A batch the conn refuses
+        fails ``on_done`` and its envelope is not sent. ``span``: the
+        owning RPC span — with device telemetry on, the transfer gets
+        a stage tracker (and, with rpcz, a child device span)."""
+        return self._submit(data, on_done, device_arrays, span)
 
     # bytes and IOBufs share one path; the old two-name split survives as
     # an alias so fast-path call sites read as what they are
     write_small = write
 
-    def _submit(self, data, on_done) -> bool:
+    def _submit(self, data, on_done, arrays=None, span=None) -> bool:
         """One write path for bytes and IOBufs: push onto the MPSC queue;
         the producer whose push CLAIMS writership sends — inline in this
         context when the conn allows it (write-once-then-KeepWrite,
@@ -640,7 +695,10 @@ class Socket:
         self.wq_bytes += sz
         nwqueue_bytes.add(sz)
         _wqueue_peak.update(self.wq_bytes)
-        if not self._wq.push((data, on_done)):
+        lane = None
+        if arrays is not None:
+            lane = (arrays, self._open_lane_tracker(arrays, span))
+        if not self._wq.push((data, on_done, lane)):
             return True          # the active writer drains it in order
         if self._ring_attached and type(data) is bytes and \
                 _try_defer_write(self):
@@ -651,7 +709,11 @@ class Socket:
             return True
         m = self._async_write_min
         if self._inline_write and not (m and sz >= m):
+            self.write_inplace += 1
+            self._nwrite_inplace.add(1)
             return self._drain_writes_inline()
+        self.write_fiber_spawns += 1
+        self._nwrite_fiber.add(1)
         self._control.spawn(self._keep_write, name="keep_write")
         return True
 
@@ -718,13 +780,27 @@ class Socket:
                 if self._wq.try_retire():
                     return ok
                 continue          # a racing push landed: keep draining
-            data, cb = item
-            item = None
             err: Optional[BaseException] = None
+            if not self.failed and self._conn_flush is not None:
+                # the conn frames its own queue: hand it every item
+                # queued so far, flush once
+                status = self._write_gathered(item)
+                item = None
+                if status == 0:
+                    continue
+                if status == 1:
+                    return ok
+                if status == 3:
+                    return False
+                ok = False
+                continue
+            data, cb, lane = item
+            item = None
             if not self.failed and self._writev is not None:
                 # gather-write coalescing: if more frames already queued
                 # behind this one, merge the run into one bounded
                 # writev batch — one syscall instead of one per frame
+                # (fd conns only: they carry no device lane)
                 nxt = self._wq.drain_one()
                 if nxt is not None:
                     self._wq_acct_pop(nxt)
@@ -740,7 +816,19 @@ class Socket:
                     continue
             if self.failed:
                 err = self.fail_reason
+                self._fail_lane(lane, err)
             else:
+                refused = self._hand_lane(lane) if lane is not None \
+                    else None
+                if refused is not None:
+                    # the batch never reached the conn: fail this call
+                    # and keep its envelope home; the conn stays usable
+                    if cb is not None:
+                        try:
+                            cb(refused)
+                        except Exception:
+                            pass
+                    continue
                 err, leftover = self._write_data_once(data)
                 if err is None and leftover is not None:
                     # blocked mid-frame: park writership on the
@@ -761,6 +849,163 @@ class Socket:
                 except Exception:
                     pass
 
+    def _open_lane_tracker(self, arrays, span):
+        """The device_stats stage tracker of one queued batch, opened
+        in the submitting context (t_submit is the hand-over to the
+        socket); None with device telemetry off."""
+        _ds = _device_stats
+        if not _ds.enabled():
+            return None
+        conn = self.conn
+        lane = getattr(conn, "lane_kind", None) or \
+            getattr(conn.remote_endpoint, "scheme", "device")
+        # (lane, peer, cell) cached on the socket — the PR 7
+        # cells-cached-per-channel discipline; lane_kind can change
+        # once the hello lands, so the cache keys on it
+        cached = self.__dict__.get("_dev_send")
+        if cached is None or cached[0] != lane:
+            peer = _ds.peer_key(conn.remote_endpoint)
+            cached = (lane, peer,
+                      _ds.global_device_stats().device_cell(peer, lane))
+            self._dev_send = cached
+        nbytes = sum(getattr(a, "nbytes", 0) or 0 for a in arrays)
+        return _ds.open_transfer(cached[1], lane, nbytes,
+                                 parent_span=span, cell=cached[2])
+
+    @staticmethod
+    def _fail_lane(lane, err) -> None:
+        """Settle the tracker of a queued batch that will not be sent
+        (the settle latch makes a second report harmless)."""
+        if lane is not None and lane[1] is not None:
+            lane[1].lane_failed(f"{type(err).__name__}: {err}")
+
+    def _hand_lane(self, lane, flush: bool = True):
+        """Writer-side half of a paired write: hand the queued device
+        batch to the conn, just before its envelope. Returns the
+        refusal (the tracker is settled) or None. With ``flush`` False
+        (gathering conns) a full out-buffer raises BlockingIOError and
+        leaves the tracker open: the pair parks and is handed again."""
+        arrays, tracker = lane
+        try:
+            if self._lane_tracked:
+                # the conn's flush/ack legs stamp the tracker
+                self.conn.write_device_payload(arrays, tracker=tracker,
+                                               flush=flush)
+                return None
+            self.conn.write_device_payload(arrays)
+        except BlockingIOError as e:
+            if not flush:
+                raise
+            self._fail_lane(lane, e)
+            return e
+        except Exception as e:
+            # the conn settles the refusals it detects (poison,
+            # unsendable); a raise before those checks (device_put OOM,
+            # bad dtype) must not strand an opened cell record
+            self._fail_lane(lane, e)
+            return e
+        if tracker is not None:
+            # loopback/staged conns deliver synchronously: the whole
+            # timeline collapses into one settle (stage≈call, ack≈0)
+            tracker.lane_encoded()
+            tracker.lane_flushed()
+            tracker.lane_acked()
+        return None
+
+    def _write_gathered(self, item) -> int:
+        """Writer for a conn that queues what it is given and flushes
+        on request (ici://): ``item`` and every item queued behind it,
+        up to the coalescing caps, go to the conn unflushed — a device
+        batch, then its envelope, pair after pair in queue order — and
+        ONE flush carries the lot. A pair costs one TCP write, pairs of
+        concurrent callers share it, and adjacent small batches reach
+        the conn's own coalescer together. Callbacks fire after that
+        flush, in this context with no lock held: write_done_us /
+        flushed_us stamp bytes the conn has handed to TCP (or, behind a
+        full TCP buffer or a closed window, holds to send on its own
+        wake). A full out-buffer parks the rest — an unhanded batch
+        with its envelope — through _park_handoff.
+
+        Returns 0 = all handed and flushed (keep draining), 1 = parked
+        on the writable event, 2 = failed (socket now failed, every
+        callback fired), 3 = failed AND a concurrent set_failed claimed
+        the queue (the caller must stop draining)."""
+        conn = self.conn
+
+        def write_unflushed(mv):
+            return conn.write(mv, flush=False)
+
+        done = []                    # (cb, refusal) in queue order
+        parked = None
+        fatal: Optional[BaseException] = None
+        total = 0
+        while True:
+            data, cb, lane = item
+            try:
+                if lane is not None:
+                    refused = self._hand_lane(lane, flush=False)
+                    lane = None
+                    if refused is not None:
+                        done.append((cb, refused))
+                        data = None
+                if data is not None:
+                    total += data.size if isinstance(data, IOBuf) \
+                        else len(data)
+                    if isinstance(data, IOBuf):
+                        data.cut_into_writer(write_unflushed)
+                        if data:
+                            raise BlockingIOError
+                    else:
+                        write_unflushed(data)
+                    done.append((cb, None))
+            except BlockingIOError:
+                if not isinstance(data, IOBuf):
+                    buf = IOBuf()
+                    buf.append(bytes(data))
+                    data = buf
+                parked = (data, cb, lane)
+                break
+            except (BrokenPipeError, ConnectionError, OSError) as e:
+                fatal = e
+                done.append((cb, None))
+                break
+            if total >= _COALESCE_MAX_BYTES or \
+                    len(done) >= _COALESCE_MAX_FRAMES:
+                break
+            item = self._wq.drain_one()
+            if item is None:
+                break
+            self._wq_acct_pop(item)
+        if len(done) > 1:
+            ncoalesced.add(len(done) - 1)
+        if fatal is None:
+            try:
+                self._conn_flush()
+            except (BrokenPipeError, ConnectionError, OSError) as e:
+                fatal = e
+        if fatal is not None:
+            self.set_failed(fatal)
+        for cb, refused in done:
+            if cb is not None:
+                try:
+                    cb(refused or fatal)
+                except Exception:
+                    pass
+        if parked is None:
+            return 0 if fatal is None else 2
+        if fatal is not None:
+            self._fail_lane(parked[2], fatal)
+            if parked[1] is not None:
+                try:
+                    parked[1](fatal)
+                except Exception:
+                    pass
+            return 2
+        st = self._park_handoff(*parked)
+        if st == 1:
+            return 1
+        return 3 if st == -1 else 2
+
     def _take_handoff(self):
         with self._handoff_lock:
             item, self._handoff = self._handoff, None
@@ -772,11 +1017,12 @@ class Socket:
                 nwqueue_bytes.add(-sz)
         return item
 
-    def _park_handoff(self, leftover, comp) -> int:
+    def _park_handoff(self, leftover, comp, lane=None) -> int:
         """Park a blocked write remainder on the writable event (the
         continuation takes it via _take_handoff) — the ONE copy of the
         park protocol the single-frame, coalesced and ring write paths
-        all share. The parked bytes re-enter the queue gauge: a
+        all share; ``lane`` is a parked envelope's device batch the
+        conn has not taken yet. The parked bytes re-enter the queue gauge: a
         stalled peer holding megabytes mid-frame is exactly what
         socket_wqueue_bytes exists to show (_take_handoff settles it
         when the park resolves).
@@ -789,7 +1035,7 @@ class Socket:
         draining here too would put two consumers on it)."""
         lsz = leftover.size
         with self._handoff_lock:
-            self._handoff = (leftover, comp)
+            self._handoff = (leftover, comp, lane)
             self.wq_bytes += lsz
             nwqueue_bytes.add(lsz)
         try:
@@ -801,6 +1047,7 @@ class Socket:
                             else ConnectionError(str(e)))
             if took is None:
                 return -1
+            self._fail_lane(took[2], self.fail_reason)
             if took[1] is not None:
                 try:
                     took[1](self.fail_reason)
@@ -895,7 +1142,7 @@ class Socket:
             if item is None:
                 break
             self._wq_acct_pop(item)
-            data, cb = item
+            data, cb = item[0], item[1]   # fd conns: no device lane
             if isinstance(data, IOBuf):
                 # rare on this lane (deferral only claims bytes frames,
                 # but racing producers may queue IOBufs behind one):
@@ -1015,60 +1262,6 @@ class Socket:
                     except Exception:
                         pass
 
-    def write_device_payload(self, arrays, span=None) -> bool:
-        """Out-of-band device lane (mem/tpu transports); host transports
-        must serialize instead. ``span``: the owning RPC span — when
-        device telemetry is on, the transfer gets a stage tracker (and,
-        with rpcz, a child device span) stamped through the conn's
-        flush/ack machinery; conns without tracker support settle the
-        whole timeline synchronously around the call."""
-        _ds = _device_stats
-        tracker = None
-        if _ds.enabled():
-            conn = self.conn
-            lane = getattr(conn, "lane_kind", None) or \
-                getattr(conn.remote_endpoint, "scheme", "device")
-            # (lane, peer, cell) cached on the socket — the PR 7
-            # cells-cached-per-channel discipline; lane_kind can change
-            # once the hello lands, so the cache keys on it
-            cached = self.__dict__.get("_dev_send")
-            if cached is None or cached[0] != lane:
-                peer = _ds.peer_key(conn.remote_endpoint)
-                cached = (lane, peer,
-                          _ds.global_device_stats().device_cell(peer,
-                                                                lane))
-                self._dev_send = cached
-            nbytes = sum(getattr(a, "nbytes", 0) or 0 for a in arrays)
-            tracker = _ds.open_transfer(cached[1], lane, nbytes,
-                                        parent_span=span,
-                                        cell=cached[2])
-        if tracker is not None and \
-                getattr(self.conn, "supports_device_tracker", False):
-            try:
-                return bool(self.conn.write_device_payload(
-                    arrays, tracker=tracker))
-            except BaseException as e:
-                # the conn's own failure paths settle the tracker for
-                # the cases they detect (poison, unsendable) — but a
-                # raise BEFORE those checks (device_put OOM, bad
-                # dtype) must not strand an opened cell record; the
-                # settle latch makes a double report harmless
-                tracker.lane_failed(f"{type(e).__name__}: {e}")
-                raise
-        try:
-            r = self.conn.write_device_payload(arrays)
-        except BaseException as e:
-            if tracker is not None:
-                tracker.lane_failed(f"{type(e).__name__}: {e}")
-            raise
-        if tracker is not None:
-            # loopback/staged conns deliver synchronously: the whole
-            # timeline collapses into one settle (stage≈call, ack≈0)
-            tracker.lane_encoded()
-            tracker.lane_flushed()
-            tracker.lane_acked()
-        return bool(r)
-
     def _cut_buf(self, buf: IOBuf) -> None:
         """Write as much of the chain as the conn accepts: gather-write
         (one sendmsg per iovec batch) when available and worthwhile,
@@ -1102,7 +1295,7 @@ class Socket:
         fires with the reason — never a silent drop."""
         handoff = self._take_handoff()
         if handoff is not None:
-            buf, cb = handoff
+            buf, cb = handoff[0], handoff[1]
             err = await self._write_buf_blocking(buf)
             if err is not None:
                 self.set_failed(err)
@@ -1118,10 +1311,21 @@ class Socket:
                     return
                 continue
             self._wq_acct_pop(item)
-            data, cb = item
+            data, cb, lane = item
             err: Optional[BaseException] = None
             if self.failed:
                 err = self.fail_reason
+                self._fail_lane(lane, err)
+            elif lane is not None and \
+                    (refused := self._hand_lane(lane)) is not None:
+                # as in _drain_writes_inline: the call fails, its
+                # envelope stays home, the conn stays usable
+                if cb is not None:
+                    try:
+                        cb(refused)
+                    except Exception:
+                        pass
+                continue
             else:
                 if not isinstance(data, IOBuf):
                     b = IOBuf()

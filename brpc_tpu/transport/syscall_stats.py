@@ -67,12 +67,18 @@ def snapshot() -> dict:
     """Merged totals since process start — the bench lanes window-delta
     this around their measurement to derive per-RPC costs."""
     nrecv, nsend, naccept, npoll = _native_counts()
+    # claims of writership that sent in place / spawned a keep_write
+    # fiber (socket.py imports this module, hence the late import)
+    from brpc_tpu.transport.socket import write_mode_totals
+    inplace, fibers = write_mode_totals()
     return {
         "recv": nrecv + (py_recv.get_value() or 0),
         "writev": nsend + (py_writev.get_value() or 0),
         "accept": naccept + (py_accept.get_value() or 0),
         "poll": npoll,
         "rpc_msgs": rpc_msgs.get_value() or 0,
+        "write_inplace": inplace,
+        "write_fiber_spawns": fibers,
     }
 
 

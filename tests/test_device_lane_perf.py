@@ -141,8 +141,8 @@ class TestIdleAckSettlesWithoutClose:
 class TestCoalescedSmallBatches:
     def test_coalesced_round_trip_counts_and_bytes_exact(
             self, device_stats_on):
-        """Small lane batches queued behind a flush hold ride ONE
-        coalesced descriptor frame; the receiver FIFO-takes each
+        """Small lane batches queued unflushed ride ONE coalesced
+        descriptor frame at the flush; the receiver FIFO-takes each
         sub-batch intact and the /device cells count every batch and
         every byte exactly (per-sub accounting under the shared
         frame)."""
@@ -152,16 +152,14 @@ class TestCoalescedSmallBatches:
             n = 4
             peer = f"coal-{next(_seq)}"
             trackers = []
-            h.client.hold_flush()
-            try:
-                for i in range(n):
-                    t = ds.open_transfer(peer, "test-lane", 64,
-                                         parent_span=None)
-                    trackers.append(t)
-                    h.client.write_device_payload(
-                        [jnp.full((16,), i, jnp.float32)], tracker=t)
-            finally:
-                h.client.release_flush()
+            for i in range(n):
+                t = ds.open_transfer(peer, "test-lane", 64,
+                                     parent_span=None)
+                trackers.append(t)
+                h.client.write_device_payload(
+                    [jnp.full((16,), i, jnp.float32)], tracker=t,
+                    flush=False)
+            h.client.flush()
             intro = h.client.lane_introspection()
             assert intro["coalesced_frames"] >= 1, intro
             assert intro["coalesced_batches"] >= 2, intro
@@ -191,13 +189,10 @@ class TestCoalescedSmallBatches:
         h = _ConnHarness(window=8)
         try:
             big = (int(flag("ici_coalesce_bytes")) // 4) + 32
-            h.client.hold_flush()
-            try:
-                for i in range(3):
-                    h.client.write_device_payload(
-                        [jnp.full((big,), i, jnp.float32)])
-            finally:
-                h.client.release_flush()
+            for i in range(3):
+                h.client.write_device_payload(
+                    [jnp.full((big,), i, jnp.float32)], flush=False)
+            h.client.flush()
             intro = h.client.lane_introspection()
             assert intro["coalesced_frames"] == 0, intro
             for i in range(3):

@@ -696,7 +696,7 @@ class TestLockModelSnapshot:
     # fabricating Butex/timer chains under PeriodicTask._lock,
     # Controller._arb_lock and Butex._lock. RingDispatcher._lock
     # itself adds no edges: only native ring calls run under it
-    # (LOCK_ORDER row 25).
+    # (LOCK_ORDER row 24).
     #
     # 40 -> 42 with guardlint (ISSUE 16): fluent-chain receiver
     # typing (`ndropped_queue = Adder().expose(...)` now types the
