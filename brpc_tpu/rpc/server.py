@@ -365,6 +365,9 @@ class Server:
             from brpc_tpu.transport.syscall_stats import (
                 expose_syscall_vars)
             expose_syscall_vars()
+            # the process-wide stream_* sums: same survival rule
+            from brpc_tpu.rpc.stream import expose_stream_vars
+            expose_stream_vars()
             # per-backend client stat cells (labeled prometheus family)
             # follow the same re-expose lifecycle
             from brpc_tpu.rpc.backend_stats import expose_backend_vars

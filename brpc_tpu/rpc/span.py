@@ -596,6 +596,70 @@ def submit_device_recv_span(parent: Span, dr: dict) -> None:
     _submit_span(span)
 
 
+@dataclass
+class FrameSpan(Span):
+    """One data frame of a stream on one side (``service`` is
+    ``stream-send`` or ``stream-recv``); the two halves of a frame join
+    by ``stream_id`` (the RECEIVING stream's id) and ``frame_seq``, and
+    in one process they lie on one clock. The sender stamps
+    ``start_us`` (entry to ``write``: ``write_start_us`` in the dict),
+    ``credit_us`` (credit held) and ``write_done_us`` (the socket's
+    writer flushed the frame); the receiver ``received_us`` (the frame
+    cut), ``deliver_start_us`` and ``deliver_end_us`` (around
+    ``on_received``). The frame's ``device``/``device-recv`` child spans
+    hang on its half as they do on a call's span."""
+    stream_id: int = 0
+    frame_seq: int = 0
+    credit_us: int = 0
+    deliver_start_us: int = 0
+    deliver_end_us: int = 0
+
+    def write_done(self, err=None) -> None:
+        """The frame write's ``on_done``: ends and submits the sending
+        half (a failed write has no flush time)."""
+        self.end_us = time.monotonic_ns() // 1000
+        if err is None:
+            self.write_done_us = self.end_us
+        else:
+            self.error_code = -1
+        _submit_span(self)
+
+    def delivered(self) -> None:
+        """``on_received`` returned: ends and submits the receiving half."""
+        self.deliver_end_us = self.end_us = time.monotonic_ns() // 1000
+        _submit_span(self)
+
+    def to_dict(self) -> dict:
+        d = super().to_dict()
+        d.update(stream_id=self.stream_id, frame_seq=self.frame_seq,
+                 write_start_us=(self.start_us if self.service == "stream-send"
+                                 else 0),
+                 credit_us=self.credit_us,
+                 deliver_start_us=self.deliver_start_us,
+                 deliver_end_us=self.deliver_end_us)
+        return d
+
+
+def start_frame_span(service: str, stream_id: int = 0, frame_seq: int = 0,
+                     msg=None) -> FrameSpan:
+    """A frame's sending half (at the entry to ``Stream.write``; the id
+    and sequence number are filled in once the credit is held) or, with
+    the message just cut, its receiving half. A scanner-lane message has
+    no cut stamp of its own: its arrival is now."""
+    now = time.monotonic_ns()
+    half = FrameSpan(trace_id=new_trace_id(), span_id=new_trace_id(),
+                     side="stream", service=service, method="frame",
+                     stream_id=stream_id, frame_seq=frame_seq,
+                     start_us=now // 1000)
+    if msg is not None:
+        half.received_us = half.start_us = \
+            getattr(msg, "arrival_ns", now) // 1000
+        dr = getattr(msg, "device_recv", None)
+        if dr is not None:
+            submit_device_recv_span(half, dr)
+    return half
+
+
 def stamp_first_byte(span: Span, us: int) -> None:
     """The client's reader saw the response frame. Its request write was
     done by then, whatever the write's completion callback says: that

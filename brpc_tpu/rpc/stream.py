@@ -6,33 +6,110 @@ meta with stream_settings but neither request nor response — flow both
 ways on the same socket with credit-based flow control:
 
   - each side starts with ``initial_credits`` frames of send budget
-  - the receiver returns credits in batches (piggybacked on its own
-    frames or as bare credit grants) after delivering frames
+    (frames, whatever a frame weighs; upstream counts bytes)
+  - the receiver returns credits as bare grant frames, one after every
+    ``CREDIT_BATCH`` frames delivered; nothing rides on its data frames
+  - the frame that takes a writer's last credit says ``need_feedback``
+    (upstream's name for a writer that wants to hear of consumption),
+    and the receiver grants what it holds once that frame is delivered:
+    a window smaller than ``CREDIT_BATCH`` would never be granted back
   - a writer with no credits parks on a butex until a grant arrives
 
 Device arrays stream over the same device lane as unary RPC. Ordered
 delivery comes from the socket's FIFO write queue + per-stream
 ExecutionQueue on the receive side (the reference's per-stream
 ExecutionQueue write path, SURVEY.md §2.6).
+
+Always-on counts per stream (``Stream.counters()``) and summed over the
+process as ``stream_*`` bvars; while ``span.recording()`` every data
+frame leaves one rpcz span a side (``span.FrameSpan``), joined by the
+receiving stream's id and ``frame_seq``.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, List, Optional
 
 from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.butil.resource_pool import ResourcePool
+from brpc_tpu.bvar.reducer import PassiveStatus
 from brpc_tpu.fiber import ExecutionQueue, global_control
 from brpc_tpu.fiber.butex import Butex, WAIT_TIMEOUT
 from brpc_tpu.protocol.proto import tpu_rpc_meta_pb2 as pb
 from brpc_tpu.protocol.tpu_std import (_HDR, MAGIC, _varint, pack_message)
+from brpc_tpu.rpc import span as _span
 
 _stream_pool: ResourcePool = ResourcePool()
 _stream_pool.insert(None)  # stream id 0 = invalid (proto3 zero default)
 
 DEFAULT_CREDITS = 64
 CREDIT_BATCH = 16  # grant credits back every K delivered frames
+
+# what a stream counts, always on. ``ctrl`` frames carry no data: bare
+# credit grants, and one close frame a stream. A credit park is a writer
+# that found no credit and waited for a grant; ``ungranted_frames_max``
+# is the most frames a writer had out with their credits not yet granted
+# back, ``recv_queue_depth_max`` the most frames cut and not yet through
+# ``on_received``
+COUNTS = ("data_frames_out", "data_frames_in", "host_bytes_out",
+          "host_bytes_in", "device_bytes_out", "device_bytes_in",
+          "ctrl_frames_out", "ctrl_frames_in", "credit_parks",
+          "credit_park_us")
+MAXIMA = ("ungranted_frames_max", "recv_queue_depth_max")
+
+
+class StreamCounters:
+    """One stream's counts. Each field has one writer at a time (the
+    stream's writer, its socket's reader or the drainer fiber), as
+    ``_frame_seq`` has, so plain ints do; a frame pays a few slot
+    increments and nothing process-wide."""
+
+    __slots__ = COUNTS + MAXIMA + ("delivered",)
+
+    def __init__(self):
+        for name in self.__slots__:
+            setattr(self, name, 0)
+
+    def fold(self, other: "StreamCounters") -> None:
+        for name in COUNTS:
+            setattr(self, name, getattr(self, name) + getattr(other, name))
+        for name in MAXIMA:
+            setattr(self, name, max(getattr(self, name),
+                                    getattr(other, name)))
+
+
+# the process-wide sums: the open streams' counts, read when asked for,
+# over those of the streams closed since the process began
+_open_counts: dict = {}         # stream id -> its StreamCounters
+_closed_counts = StreamCounters()
+_counts_lock = threading.Lock()
+
+
+def counters_snapshot() -> dict:
+    """The process-wide sums since start, by the names of COUNTS and
+    MAXIMA."""
+    total = StreamCounters()
+    with _counts_lock:
+        total.fold(_closed_counts)
+        for counts in _open_counts.values():
+            total.fold(counts)
+    return {name: getattr(total, name) for name in COUNTS + MAXIMA}
+
+
+_vars = {name: PassiveStatus(lambda name=name: counters_snapshot()[name])
+         for name in COUNTS + MAXIMA}
+
+
+def expose_stream_vars() -> None:
+    """(Re-)expose the process-wide ``stream_*`` sums (import, and again
+    from Server.start: they outlive a test fixture's unexpose_all)."""
+    for name, var in _vars.items():
+        var.expose(f"stream_{name}")
+
+
+expose_stream_vars()
 
 
 def _release_stream_slot(sock) -> None:
@@ -58,6 +135,9 @@ class Stream:
         self.closed = False
         self.remote_closed = False
         self._frame_seq = 0
+        self.counts = StreamCounters()
+        with _counts_lock:
+            _open_counts[self.id] = self.counts
         self._credits = Butex(self.options.initial_credits)
         self._pending_grants = 0
         self._grant_lock = threading.Lock()
@@ -72,11 +152,16 @@ class Stream:
         Flush any credit grants deferred while peer_id was unknown."""
         self._established.set()
         with self._grant_lock:
-            grant = 0
-            if self._pending_grants >= CREDIT_BATCH:
-                grant, self._pending_grants = self._pending_grants, 0
+            # whatever is held: a frame that asked for feedback may be
+            # among those delivered before the peer was known
+            grant, self._pending_grants = self._pending_grants, 0
         if grant and not self.closed:
             self._send_frame(b"", None, credits=grant, data=False)
+
+    def counters(self) -> dict:
+        """This stream's counts, by the names of COUNTS and MAXIMA."""
+        return {name: getattr(self.counts, name)
+                for name in COUNTS + MAXIMA}
 
     # --------------------------------------------------------------- write
     async def write(self, payload: bytes | IOBuf = b"",
@@ -86,6 +171,8 @@ class Stream:
         exhausted. Returns False if the stream closed."""
         if self.closed or self.remote_closed:
             return False
+        span = _span.start_frame_span("stream-send") \
+            if _span.recording() else None
         if self.peer_id == 0:
             # establishment still in flight: a frame to stream id 0 would
             # be dropped and its credit lost
@@ -100,12 +187,18 @@ class Stream:
             if self.closed or self.remote_closed:
                 return False
             v = self._credits.value
-            if v > 0 and self._credits.compare_exchange(v, v - 1):
-                break
+            if v > 0:
+                if self._credits.compare_exchange(v, v - 1):
+                    break
+                continue        # another writer took it: look again
+            t0 = time.monotonic_ns()
             r = await self._credits.wait(expected=0, timeout_s=timeout_s)
+            self.counts.credit_parks += 1
+            self.counts.credit_park_us += (time.monotonic_ns() - t0) // 1000
             if r == WAIT_TIMEOUT:
                 return False
-        self._send_frame(payload, device_arrays)
+        self._send_frame(payload, device_arrays, span=span,
+                         credits_left=v - 1)
         return True
 
     def write_nowait(self, payload: bytes | IOBuf = b"",
@@ -114,17 +207,47 @@ class Stream:
         before the stream is established."""
         if self.closed or self.remote_closed or self.peer_id == 0:
             return False
+        span = _span.start_frame_span("stream-send") \
+            if _span.recording() else None
         while True:
             v = self._credits.value
             if v <= 0:
                 return False
             if self._credits.compare_exchange(v, v - 1):
                 break
-        self._send_frame(payload, device_arrays)
+        self._send_frame(payload, device_arrays, span=span,
+                         credits_left=v - 1)
         return True
 
     def _send_frame(self, payload, device_arrays, close: bool = False,
-                    credits: int = 0, data: bool = True) -> None:
+                    credits: int = 0, data: bool = True, span=None,
+                    credits_left: Optional[int] = None) -> None:
+        """``credits_left``: what the writer of a data frame has after
+        taking this frame's credit; at 0 the frame asks for feedback."""
+        c = self.counts
+        feedback = credits_left == 0
+        if data:
+            # frame_seq marks DATA frames (they consume a credit and must
+            # be delivered, even with an empty payload); bare credit grants
+            # and close frames leave it 0
+            self._frame_seq += 1
+            c.data_frames_out += 1
+            c.host_bytes_out += payload.nbytes \
+                if isinstance(payload, memoryview) else len(payload)
+            if credits_left is not None:
+                # that many short of the window are out, not granted back
+                out = self.options.initial_credits - credits_left
+                if out > c.ungranted_frames_max:
+                    c.ungranted_frames_max = out
+        else:
+            c.ctrl_frames_out += 1
+        on_done = None
+        if span is not None:
+            # the credit is held; the socket's writer stamps the flush
+            span.credit_us = time.monotonic_ns() // 1000
+            span.stream_id = self.peer_id
+            span.frame_seq = self._frame_seq
+            on_done = span.write_done
         if not device_arrays and \
                 isinstance(payload, (bytes, bytearray, memoryview)):
             if not isinstance(payload, bytes):
@@ -136,12 +259,13 @@ class Stream:
             # fields — hand-encode it (bit-identical to the pb
             # serializer: ascending field numbers, minimal varints;
             # golden-pinned by tests) instead of building an RpcMeta
-            # per frame. stream_id=1, frame_seq=3, credits=4, close=5
-            # inside stream_settings (RpcMeta field 6); payload bytes
-            # ride zero-copy for big frames.
+            # per frame. stream_id=1, need_feedback=2, frame_seq=3,
+            # credits=4, close=5 inside stream_settings (RpcMeta field
+            # 6); payload bytes ride zero-copy for big frames.
             inner = b"\x08" + _varint(self.peer_id)
+            if feedback:
+                inner += b"\x10\x01"
             if data:
-                self._frame_seq += 1
                 inner += b"\x18" + _varint(self._frame_seq)
             if credits:
                 inner += b"\x20" + _varint(credits)
@@ -156,23 +280,21 @@ class Stream:
                 # hold their own sender lock for token ORDER (the
                 # serving _StreamSender does); Socket.write only queues
                 # — it never parks, and failure paths flip flags
-                self.socket.write(hdr + payload)
+                self.socket.write(hdr + payload, on_done)
             else:
                 wire = IOBuf()
                 wire.append(hdr)
                 wire.append_user_data(payload)
                 # graftlint: disable=callback-under-lock -- see the
                 # small-frame branch above: write only queues
-                self.socket.write(wire)
+                self.socket.write(wire, on_done)
             return
         meta = pb.RpcMeta()
         ss = meta.stream_settings
         ss.stream_id = self.peer_id
+        if feedback:
+            ss.need_feedback = True
         if data:
-            # frame_seq marks DATA frames (they consume a credit and must
-            # be delivered, even with an empty payload); bare credit grants
-            # and close frames leave it 0
-            self._frame_seq += 1
             ss.frame_seq = self._frame_seq
         if close:
             ss.close = True
@@ -181,25 +303,49 @@ class Stream:
         use_lane = bool(device_arrays) and self.socket.conn.supports_device_lane
         wire, lane = pack_message(meta, payload, device_arrays=device_arrays,
                                   device_lane=use_lane)
+        if data:
+            for dp in meta.device_payloads:     # sized by pack_message
+                c.device_bytes_out += dp.nbytes
         # graftlint: disable=callback-under-lock -- see _send_frame's
         # raw-frame branch: write only queues, sender locks order tokens
-        self.socket.write(wire, device_arrays=lane)
+        self.socket.write(wire, on_done, device_arrays=lane, span=span)
 
     # -------------------------------------------------------------- receive
     def _on_frame(self, msg) -> None:
         ss = msg.meta.stream_settings
-        if ss.credits:
-            self._credits.fetch_add(ss.credits)
+        self._accept(ss.credits, ss.close, ss.frame_seq, msg,
+                     ss.need_feedback)
+
+    def _accept(self, credits: int, close, seq: int, msg,
+                feedback: bool = False) -> None:
+        """One frame off the socket, in parse order (both lanes: the
+        classic ``_on_frame`` and the scanner's
+        ``process_stream_frame_fast``)."""
+        c = self.counts
+        if credits:
+            self._credits.fetch_add(credits)
             self._credits.wake_all()
-        if ss.close:
-            self._remote_close_once()
+        if close or not seq:
+            c.ctrl_frames_in += 1
+            if close:
+                self._remote_close_once()
             return
-        if ss.frame_seq:  # DATA frame (possibly empty payload)
-            self._recv_q.execute(("frame", msg))
+        # DATA frame (possibly empty payload)
+        c.data_frames_in += 1
+        c.host_bytes_in += msg.payload.size
+        if msg.device_arrays:
+            for dp in msg.meta.device_payloads:     # as the sender sized them
+                c.device_bytes_in += dp.nbytes
+        depth = c.data_frames_in - c.delivered
+        if depth > c.recv_queue_depth_max:
+            c.recv_queue_depth_max = depth
+        span = _span.start_frame_span("stream-recv", self.id, seq, msg) \
+            if _span.recording() else None
+        self._recv_q.execute(("asked" if feedback else "frame", msg, span))
 
     async def _deliver(self, batch) -> None:
         import inspect
-        for kind, msg in batch:
+        for kind, msg, span in batch:
             if kind == "close":
                 for cb in self._close_cbs:
                     try:
@@ -207,6 +353,8 @@ class Stream:
                     except Exception:
                         pass
                 continue
+            if span is not None:
+                span.deliver_start_us = time.monotonic_ns() // 1000
             if self.options.on_received is not None:
                 try:
                     r = self.options.on_received(self, msg)
@@ -216,10 +364,14 @@ class Stream:
                     import logging
                     logging.getLogger("brpc_tpu.rpc").exception(
                         "stream on_received failed")
+            self.counts.delivered += 1
+            if span is not None:
+                span.delivered()
             with self._grant_lock:
                 self._pending_grants += 1
                 grant = 0
-                if self._pending_grants >= CREDIT_BATCH and self.peer_id:
+                if (self._pending_grants >= CREDIT_BATCH
+                        or kind == "asked") and self.peer_id:
                     grant, self._pending_grants = self._pending_grants, 0
             if grant and not self.closed:
                 self._send_frame(b"", None, credits=grant, data=False)
@@ -293,7 +445,7 @@ class Stream:
         self._credits.fetch_add(1 << 20)
         self._credits.wake_all()
         self._established.set()        # unblock pre-establish waiters
-        self._recv_q.execute(("close", None))   # fire on_close callbacks
+        self._recv_q.execute(("close", None, None))  # fire on_close callbacks
 
     # ---------------------------------------------------------------- close
     def close(self) -> None:
@@ -321,6 +473,9 @@ class Stream:
             except AttributeError:
                 pass
         _stream_pool.remove(self.id)
+        with _counts_lock:
+            if _open_counts.pop(self.id, None) is not None:
+                _closed_counts.fold(self.counts)
         self._credits.fetch_add(1 << 20)   # short-circuit pending parks
         self._credits.wake_all()
 
@@ -394,21 +549,13 @@ class FastStreamMsg:
 
 def process_stream_frame_fast(sid: int, seq: int, credits: int, close: int,
                               payload: bytes, att: bytes) -> None:
-    """Dispatch a scan_frames stream record (turbo lane): the inlined
-    twin of Stream._on_frame — keep their semantics in lockstep."""
+    """Dispatch a scan_frames stream record (turbo lane)."""
     stream = _stream_pool.address(sid)
     if stream is None:
         return  # stream already closed; drop (reference drops too)
-    if credits:
-        stream._credits.fetch_add(credits)
-        stream._credits.wake_all()
-    if close:
-        stream._remote_close_once()
-        return
-    if seq:  # DATA frame (possibly empty payload)
-        stream._recv_q.execute(("frame", FastStreamMsg(payload, att, sid,
-                                                       seq, credits,
-                                                       close)))
+    msg = FastStreamMsg(payload, att, sid, seq, credits, close) \
+        if seq and not close else None
+    stream._accept(credits, close, seq, msg)
 
 
 # ------------------------------------------------------------- establishment

@@ -110,7 +110,7 @@ def _make_fabric_service(smoke: Smoke, server_device):
 
     step_fn, (x0, w_in, w_out) = graft.entry(**smoke.size("mlp"))
     step = jax.jit(step_fn)
-    seen = {"misplaced": [], "stream": []}
+    seen = {"misplaced": [], "stream": [], "stream_arrays": []}
     svc = Service("Smoke")
 
     def note_placement(arrs):
@@ -138,10 +138,12 @@ def _make_fabric_service(smoke: Smoke, server_device):
 
     @svc.method()
     def Open(cntl, request):
-        st = stream_accept(cntl, StreamOptions(
-            on_received=lambda s, m: (
-                seen["stream"].append(m.payload.to_bytes()),
-                s.write_nowait(b"ack:" + m.payload.to_bytes()))))
+        def on_received(s, m):
+            note_placement(m.device_arrays)
+            seen["stream_arrays"].extend(m.device_arrays)
+            seen["stream"].append(m.payload.to_bytes())
+            s.write_nowait(b"ack:" + m.payload.to_bytes())
+        st = stream_accept(cntl, StreamOptions(on_received=on_received))
         return b"opened" if st is not None else b"no-stream"
 
     return svc, seen, (x0, w_in, w_out)
@@ -367,7 +369,8 @@ def phase_fabric(smoke: Smoke) -> dict:
                   f"{handler_seen['misplaced'][:3]}")
 
             if scheme == "ici":
-                # eight streaming frames with acks over the same lane
+                # eight streaming frames with acks over the same lane,
+                # the last with a device array of the step's own size
                 acks: list = []
                 scntl = ch.call_sync(
                     "Smoke", "Open", b"", stream_options=StreamOptions(
@@ -378,8 +381,10 @@ def phase_fabric(smoke: Smoke) -> dict:
                 frames = [f"seq-{i}".encode() for i in range(8)]
 
                 async def writer():
-                    for f in frames:
+                    for f in frames[:-1]:
                         assert await stream.write(f)
+                    assert await stream.write(
+                        frames[-1], device_arrays=[roll(x0, 1)])
                 check(fiber.spawn(writer).join(30), "stream writer hung")
                 deadline = time.monotonic() + 30
                 while (len(handler_seen["stream"]) < 8 or len(acks) < 8) \
@@ -389,6 +394,14 @@ def phase_fabric(smoke: Smoke) -> dict:
                       f"stream frames: {handler_seen['stream']}")
                 check(acks == [b"ack:" + f for f in frames],
                       f"stream acks: {acks}")
+                (framed,) = handler_seen["stream_arrays"]
+                np.testing.assert_array_equal(
+                    np.asarray(framed).astype(np.float32),
+                    np.roll(x_np, 1, axis=0))
+                check(not handler_seen["misplaced"],
+                      f"a stream frame's array off {dev0}: "
+                      f"{handler_seen['misplaced'][:3]}")
+                t_seen["stream_device_frame_bytes"] = int(framed.nbytes)
                 stream.close()
                 t_seen["stream_frames_acked"] = len(acks)
                 seen["device_cells"] = _cells_balance()

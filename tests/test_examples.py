@@ -7,6 +7,8 @@ import importlib.util
 import os
 import sys
 
+import pytest
+
 _EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "examples")
 
@@ -45,8 +47,12 @@ def test_backup_request_example():
     _load("backup_request").main()
 
 
-def test_streaming_echo_example():
-    _load("streaming_echo").main(n_frames=5)
+@pytest.mark.parametrize("device_frames", ["", "device_frames"])
+def test_streaming_echo_example(device_frames, capsys):
+    _load("streaming_echo").main(n_frames=5, device_frames=device_frames)
+    out = capsys.readouterr().out
+    assert "got 5 echoes" in out
+    assert ("5 device frames came back" in out) == bool(device_frames)
 
 
 def test_inference_serving_example(capsys):

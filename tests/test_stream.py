@@ -17,13 +17,17 @@ from brpc_tpu.rpc.stream import (
 _seq = iter(range(100000))
 
 
-def start_stream_server(server_received, echo_back=False):
+def start_stream_server(server_received, echo_back=False, gate=None):
+    """``gate``: the receiver delivers nothing until it is set (a
+    receiver that can't drain)."""
     server = Server(ServerOptions(enable_builtin_services=False))
     svc = Service("StreamService")
 
     @svc.method()
     def Open(cntl, request):
         def on_received(stream, msg):
+            if gate is not None:
+                gate.wait(10)
             payload = msg.payload.to_bytes()
             server_received.append((payload, list(msg.device_arrays)))
             if echo_back:
@@ -101,7 +105,8 @@ class TestStreaming:
         """With a tiny window and a receiver that can't drain, the writer
         must run out of credits rather than buffer unboundedly."""
         received = []
-        server, ep = start_stream_server(received)
+        gate = threading.Event()
+        server, ep = start_stream_server(received, gate=gate)
         try:
             ch = Channel(str(ep))
             cntl = ch.call_sync("StreamService", "Open", b"",
@@ -115,6 +120,7 @@ class TestStreaming:
             assert sent == 4  # window exhausted without grants
             stream.close()
         finally:
+            gate.set()
             server.stop(); server.join(2)
 
     def test_credits_replenish(self):
@@ -314,7 +320,7 @@ class TestNativeStreamLane:
             def __init__(self):
                 self.wires = []
 
-            def write(self, w):
+            def write(self, w, on_done=None):
                 self.wires.append(w if isinstance(w, bytes) else w.to_bytes())
 
         import array
@@ -362,7 +368,7 @@ class TestNativeStreamLane:
             def __init__(self):
                 self.wires = []
 
-            def write(self, w):
+            def write(self, w, on_done=None):
                 self.wires.append(w if isinstance(w, bytes) else w.to_bytes())
 
         s = Stream()
@@ -422,7 +428,7 @@ class TestNativeStreamLane:
             def __init__(self):
                 self.wires = []
 
-            def write(self, w):
+            def write(self, w, on_done=None):
                 self.wires.append(w if isinstance(w, bytes) else w.to_bytes())
 
         s = Stream()
