@@ -203,8 +203,9 @@ class ChaosConn(Conn):
         return self._inner.remote_endpoint
 
     def __getattr__(self, name):
-        # transport extras (read_chunks, pending_bytes, pluck_fd, ...):
-        # read-side and identity surfaces pass straight through
+        # transport extras (read_chunks, pending_bytes, pluck_fd,
+        # stream_fd, ...): read-side and identity surfaces pass
+        # straight through
         return getattr(self._inner, name)
 
 

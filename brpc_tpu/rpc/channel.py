@@ -717,6 +717,14 @@ class Channel:
         cntl.remote_side = sock.remote_endpoint
         cntl.local_side = sock.local_endpoint
         cntl._set_issue_socket(sock)  # sync-pluck lane (Controller.join)
+        # first-issue sync call: claim the lane pre-send so the
+        # dispatcher can never win the race to the response, whatever
+        # the frame (small, large, with a device batch). A socket left
+        # sticky-paused by the previous call's settle is claimed for
+        # free, where a write without the claim would first re-arm
+        # reads (Socket._submit) for join() to pause them again
+        if sync_fast and sock.pluck_preclaim():
+            d["_pluck_preclaimed"] = sock
         att = cntl.__dict__.get("request_attachment")
         # per-backend telemetry: this attempt is now issued AT a
         # concrete backend — open its stat-cell record (closed by
@@ -767,10 +775,6 @@ class Channel:
                 # call (Socket.pluck_until fast lane): the expected
                 # response is a small tpu_std frame
                 cntl.__dict__["_pluck_fast"] = (_TPU_MAGIC, SMALL_FRAME_MAX)
-                # first-issue sync call: claim the lane pre-send so the
-                # dispatcher can never win the race to the response
-                if sync_fast and sock.pluck_preclaim():
-                    d["_pluck_preclaimed"] = sock
             else:
                 # large attachment: same cached-prefix meta (no pb build
                 # per call), header+meta in one native allocation

@@ -22,6 +22,8 @@ from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.butil.resource_pool import ResourcePool
 from brpc_tpu.fiber.sync import FiberEvent
 from brpc_tpu.rpc import errno_codes as berr
+from brpc_tpu.transport.syscall_stats import (join_plucked as _njoin_plucked,
+                                              join_waited as _njoin_waited)
 
 # global correlation-id pool: id -> client Controller (the reference's
 # bthread_id space, id.h:46). Native when available: fastcore's Pool is
@@ -636,6 +638,7 @@ class Controller:
                         if sock.pluck_until(lambda: self._finalized,
                                             pluck_deadline, fast=fast,
                                             preclaimed=claimed):
+                            _njoin_plucked.add(1)
                             return True
                     except Exception:
                         pass   # pluck is an optimization, never a failure
@@ -664,7 +667,10 @@ class Controller:
         # caller, claim contention): the deadline needs a real timer
         self._arm_lazy_deadline()
         ev = self._join_event()
-        return True if ev is None else ev.wait_pthread(timeout_s)
+        if ev is None:
+            return True
+        _njoin_waited.add(1)
+        return ev.wait_pthread(timeout_s)
 
     def _arm_lazy_deadline(self) -> None:
         """Convert a pending (lazily-enforced) deadline into a real

@@ -321,6 +321,14 @@ def peek_dispatcher() -> Optional[EventDispatcher]:
     return _global
 
 
+def dispatcher_ticks() -> int:
+    """Wakeups of this process's event thread that fired at least one
+    callback, since the dispatcher was made (syscall_stats.snapshot
+    carries it; a level-triggered fd nobody pauses shows here)."""
+    d = _global
+    return d._tick_seq if d is not None else 0
+
+
 def _postfork_reset() -> None:
     """Fork hygiene: the dispatcher thread exists only in the parent,
     and the inherited epoll fd is the parent's kernel object — any

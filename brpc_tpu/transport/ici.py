@@ -88,6 +88,9 @@ DEFAULT_WINDOW = 32
 # cap on framed-but-unwritten bytes per flush pass: one gather pass
 # frames every sendable queue item up to this, then pays ONE TCP write
 _FLUSH_CHUNK = 1 << 20
+# the pump's read size; a conn keeps one buffer of it (pages no frame
+# ever reached are never touched)
+_PUMP_READ = 256 << 10
 
 _jax_mod = None
 
@@ -625,6 +628,9 @@ class IciConn(Conn):
         # counter passes its frame's end
         self._wire_written = 0
         self._wire_marks: Deque[Tuple[int, object]] = deque()
+        # the pump's read buffer, kept for the conn's life (under
+        # _pump_lock) in place of a fresh 256 KB a pump
+        self._rbuf = memoryview(bytearray(_PUMP_READ))
         self._inbuf = bytearray()
         self._appbuf = bytearray()
         self._lane: Deque[Tuple] = deque()       # inbound batch descriptors
@@ -982,6 +988,7 @@ class IciConn(Conn):
         while len(self._wirebuf) < _FLUSH_CHUNK:
             poison = None
             extras = None
+            gated = False
             with self._lock:
                 if not self._outq:
                     return False
@@ -999,7 +1006,7 @@ class IciConn(Conn):
                     elif not self._lane_ready():
                         # out of credit: park until an ACK arrives
                         self._want_writable = True
-                        return True
+                        gated = True
                     else:
                         self._outq.popleft()
                         extras = self._collect_coalesce(item)
@@ -1007,6 +1014,17 @@ class IciConn(Conn):
                     self._outq.popleft()
                     if item[0] == "bytes":
                         self._out_bytes -= len(item[1])
+            if gated:
+                # what this side has consumed still has to reach the
+                # peer, whose own window may be closed on those very
+                # batches: a bare ACK queued behind the gated head would
+                # wait for an ACK that waits for it, both ways. It goes
+                # ahead of the head (every frame carries the count, so
+                # its place in the order means nothing)
+                if self._consumed > self._acked_sent:
+                    self._wirebuf += self._frame(F_ACK,
+                                                 self._ack_grant_payload())
+                return True
             if poison is not None:
                 if len(item) > 2 and item[2] is not None:
                     # the popped batch's tracker settles as failed
@@ -1101,10 +1119,10 @@ class IciConn(Conn):
     def _pump_locked(self) -> Optional[Callable[[], None]]:
         """Drain + decode inbound frames; returns the writable callback
         to fire once the caller has dropped _pump_lock (or None)."""
-        buf = bytearray(256 << 10)
+        buf = self._rbuf
         while True:
             try:
-                n = self._inner.read_into(memoryview(buf))
+                n = self._inner.read_into(buf)
             except BlockingIOError:
                 break
             if n == 0:
@@ -1163,6 +1181,12 @@ class IciConn(Conn):
         return None
 
     def read_into(self, mv: memoryview) -> int:
+        # A read under 4096 bytes handed over all of _appbuf (the
+        # Socket never offers less: a fresh block of 8 KB or more, or a
+        # tail gap of 4096 and up) after a pump that read to EAGAIN: a
+        # plucking joiner ends its drain there and polls. The event-
+        # driven drain reads on to EAGAIN (this conn does not say
+        # short_read_drained)
         self._pump()
         if self._appbuf:
             n = min(len(mv), len(self._appbuf))
@@ -1380,9 +1404,10 @@ class IciConn(Conn):
         # NO TCP pump here: a descriptor frame always precedes its
         # message's byte frames on the wire, so by the time the parser
         # saw those bytes the descriptor was already de-enveloped into
-        # _lane. Pumping TCP from the parse path would steal the readable
-        # edge — frames drained into _appbuf with the event already
-        # consumed would never wake the input fiber again.
+        # _lane. Pumping TCP from the parse path would strand what it
+        # read: bytes moved into _appbuf leave the kernel's buffer
+        # empty, the level trigger stays silent, and no event sends the
+        # input pass back for them.
         with self._pump_lock:
             if not self._lane:
                 return None
@@ -1554,10 +1579,42 @@ class IciConn(Conn):
         self._want_writable = True
         self._inner.request_writable_event()
 
-    def resume_read_events(self) -> None:
-        resume = getattr(self._inner, "resume_read_events", None)
-        if resume is not None:
-            resume()
+    # The inner conn's fd is this conn's fd: what it can do as an event
+    # source, this conn can, and says so by handing the inner conn's own
+    # attribute over (one it lacks, this conn lacks). The Socket learns
+    # it once, at birth, and runs the read cycle TCP has: a busy period
+    # with data pending pauses read interest once and resumes once, and
+    # a sync joiner polls pluck_fd and reads its own reply through
+    # read_into, its drain ending at a short read. stream_fd is not among
+    # them: what arrives on the fd are lane frames, not the
+    # application's bytes, so no native loop and no raw write may use it.
+    _INNER_EVENT_CAPS = frozenset((
+        "level_triggered", "pause_read_events", "resume_read_events",
+        "pluck_fd"))
+
+    def __getattr__(self, name):
+        if name in IciConn._INNER_EVENT_CAPS:
+            return getattr(self._inner, name)
+        raise AttributeError(name)
+
+    def peek_closed(self) -> bool:
+        """True only when the peer's FIN has arrived, the kernel holds
+        no byte more, and nothing this conn has read is still to be
+        delivered: frames pumped into _inbuf, _appbuf or _lane keep the
+        connection alive until a drain has handed them on."""
+        if not self._inner.peek_closed():
+            return False
+        # after the FIN nothing more can arrive; a pump in flight ends,
+        # and what it read shows under its lock
+        with self._pump_lock:
+            return not (self._inbuf or self._appbuf or self._lane)
+
+    def awaits_peer_frame(self) -> bool:
+        """True while queued output waits for a frame of the peer's
+        (hello, ACK, grant) or for TCP to take more. Read interest has
+        to stay on then: the Socket leaves no sticky pause behind, or
+        a bare ACK would wait in the kernel with nobody to read it."""
+        return self._want_writable
 
     @property
     def local_endpoint(self):

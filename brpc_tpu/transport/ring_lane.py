@@ -296,7 +296,7 @@ class RingDispatcher:
                     sock._drain_writes_inline()   # raced empty: retire
                     continue
                 fd = -1
-                pfd = getattr(sock.conn, "pluck_fd", None)
+                pfd = getattr(sock.conn, "stream_fd", None)
                 if pfd is not None:
                     try:
                         fd = pfd()

@@ -535,17 +535,26 @@ class Socket:
         self._inline_write = getattr(conn, "inline_write_ok", False)
         self._drain_all_reads = getattr(conn, "drain_all_reads", False)
         self._level_triggered = getattr(conn, "level_triggered", False)
+        # a read under 4096 bytes emptied the conn: the drain may stop
+        # there and leave the rest to the level trigger. A level-
+        # triggered conn says so apart (TcpConn does, IciConn does not)
+        self._short_read_drained = self._level_triggered and \
+            getattr(conn, "short_read_drained", False)
         self._writev = getattr(conn, "writev", None)
         # a conn that frames its own queue and flushes on request
         # (ici://) gets the gathering writer: _write_gathered
         self._conn_flush = getattr(conn, "flush", None)
+        # a conn with flow control of its own (ici://) may hold output
+        # that only a frame from the peer releases: while it does, no
+        # sticky pluck pause may leave the fd unread (pluck_release)
+        self._conn_awaits_peer = getattr(conn, "awaits_peer_frame", None)
         self._lane_tracked = getattr(conn, "supports_device_tracker", False)
         self._readv = getattr(conn, "read_into_v", None)
         self._read_chunks = getattr(conn, "read_chunks", None)
         # async big-write routing applies only to kernel-copy fd conns
-        # (pluck_fd is the "real fd" marker shared with the pluck lane)
+        # (stream_fd: the conn's writes are the fd's own byte stream)
         self._async_write_min = (flag("socket_async_write_min")
-                                 if getattr(conn, "pluck_fd", None)
+                                 if getattr(conn, "stream_fd", None)
                                  is not None else 0)
         # pinned-fd cache for the native fd loops (pluck_scan /
         # serve_drain): ONE dup per socket instead of one dup+close
@@ -596,7 +605,10 @@ class Socket:
                 return -1
             fd = self._pin_cell[0]
             if fd is None:
-                pfd = getattr(self.conn, "pluck_fd", None)
+                # stream_fd, not pluck_fd: a native loop reads the fd's
+                # bytes as the application's (on ici:// they are lane
+                # frames, and the joiner's poll is all the fd is for)
+                pfd = getattr(self.conn, "stream_fd", None)
                 if pfd is None:
                     return -1
                 try:
@@ -1615,8 +1627,10 @@ class Socket:
                 self._plucking = False
                 leftover = self._nevent > 0
                 if self._busy_paused and not leftover:
+                    awaits = self._conn_awaits_peer
                     if (not self.failed and self.client_inflight == 0
-                            and not self.user_data.get("bound_streams")):
+                            and not self.user_data.get("bound_streams")
+                            and not (awaits is not None and awaits())):
                         # sticky pause: nothing in flight can produce
                         # input — leave reads off so the next sync call
                         # claims the lane for free (unstick_reads is
@@ -1949,13 +1963,17 @@ class Socket:
                 # per message. Safe only because such conns notify on
                 # every write, so a refill re-triggers _process_input.
                 break
-            if self._level_triggered and n < 4096:
+            if n < 4096 and (self._short_read_drained or (
+                    self._plucking and self._level_triggered)):
                 # short read on a level-triggered fd: almost certainly
                 # drained — skip the EAGAIN recv round trip. 4096 is
                 # below every buffer this loop offers (fresh blocks are
                 # >=8KB; tail gaps <4KB are never offered), so a short
                 # read really was short. If the kernel does hold more,
                 # the level trigger fires again — no stall possible.
+                # A plucking joiner stops here on every level-triggered
+                # conn: its next step is a poll of the fd, which says
+                # exactly what an EAGAIN would.
                 break
         return total
 

@@ -35,6 +35,12 @@ py_accept = Adder()
 # messages dispatched / natively served — syscalls_per_rpc's denominator
 rpc_msgs = Adder()
 
+# sync joins (Controller.join on a plain thread) that settled on the
+# pluck lane, the joiner reading its own reply, and those that fell to
+# the event wait, woken by whichever thread processed the reply
+join_plucked = Adder()
+join_waited = Adder()
+
 
 def note_rpc_messages(n: int) -> None:
     rpc_msgs.add(n)
@@ -69,6 +75,7 @@ def snapshot() -> dict:
     nrecv, nsend, naccept, npoll = _native_counts()
     # claims of writership that sent in place / spawned a keep_write
     # fiber (socket.py imports this module, hence the late import)
+    from brpc_tpu.transport.event_dispatcher import dispatcher_ticks
     from brpc_tpu.transport.socket import write_mode_totals
     inplace, fibers = write_mode_totals()
     return {
@@ -79,6 +86,10 @@ def snapshot() -> dict:
         "rpc_msgs": rpc_msgs.get_value() or 0,
         "write_inplace": inplace,
         "write_fiber_spawns": fibers,
+        # wakeups of the event thread that fired a callback
+        "dispatcher_ticks": dispatcher_ticks(),
+        "join_plucked": join_plucked.get_value() or 0,
+        "join_waited": join_waited.get_value() or 0,
     }
 
 

@@ -137,13 +137,25 @@ class TcpConn(Conn):
     # without the EAGAIN recv round trip. Pause/resume move the
     # read-interest syscalls from per-message to per-busy-period.
     level_triggered = True
+    # the first half of the comment above, said on its own: read_into
+    # hands the kernel's bytes straight over, so a short read drained
+    # it. (ici:// leaves it out; PERF.md section 6, PR 27, has why.)
+    short_read_drained = True
 
     def pluck_fd(self) -> int:
         """fd for the sync-pluck lane (Socket.pluck_until): a joining
-        thread may poll+drain this conn directly. Only plain TCP offers
-        it — SSL buffers decrypted bytes above the fd (a poll would
-        miss them) and mem/ici have no fd."""
+        thread may poll it and drain this conn through read_into. A
+        conn offers it when everything a poll would miss is still in
+        the kernel: plain TCP, and ici:// over it. SSL buffers
+        decrypted bytes above the fd and mem:// has no fd."""
         return self._sock.fileno()
+
+    # The same fd, for those that read or write the byte stream on it
+    # directly: the native loops on a pinned dup (pluck_scan,
+    # serve_drain), the ring lane's gather write, the async big-write
+    # routing. Only this conn says it: on every conn layered over one
+    # (ici://, tpud://) the fd's bytes are that conn's frames.
+    stream_fd = pluck_fd
 
     def peek_closed(self) -> bool:
         """Non-consuming liveness probe (MSG_PEEK): True only when the

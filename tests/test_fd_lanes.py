@@ -336,7 +336,22 @@ class TestServeDrain:
         a.close()
 
 
-def _echo_server():
+SCHEMES = ("tcp", "ici")
+over_schemes = pytest.mark.parametrize("scheme", SCHEMES)
+
+
+def _addr(scheme, port):
+    """The channel address of a server started by _listen(scheme)."""
+    return f"{scheme}://127.0.0.1:{port}" + (
+        "#reply_device=0" if scheme == "ici" else "")
+
+
+def _listen(server, scheme):
+    return server.start(f"{scheme}://127.0.0.1:0" + (
+        "#device=0" if scheme == "ici" else ""))
+
+
+def _echo_server(scheme="tcp"):
     server = Server(ServerOptions(enable_builtin_services=False))
     svc = Service("Bench")
 
@@ -351,15 +366,15 @@ def _echo_server():
         return data.upper()
 
     server.add_service(svc)
-    ep = server.start("tcp://127.0.0.1:0")
-    return server, ep
+    return server, _listen(server, scheme)
 
 
 class TestLanesEndToEnd:
-    def test_sync_echo_uses_native_lanes(self):
-        server, ep = _echo_server()
+    @over_schemes
+    def test_sync_echo_uses_native_lanes(self, scheme):
+        server, ep = _echo_server(scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=5000))
             for i in range(50):
                 cl = ch.call_sync("Bench", "Echo", b"m%d" % i)
@@ -378,10 +393,11 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_mixed_native_and_classic_methods_interleave(self):
-        server, ep = _echo_server()
+    @over_schemes
+    def test_mixed_native_and_classic_methods_interleave(self, scheme):
+        server, ep = _echo_server(scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=5000))
             for i in range(20):
                 a = ch.call_sync("Bench", "Echo", b"low%d" % i)
@@ -392,12 +408,13 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_large_response_defers_mid_pluck(self):
+    @over_schemes
+    def test_large_response_defers_mid_pluck(self, scheme):
         # response exceeds SMALL_FRAME_MAX: the native loop must defer
         # to the classic path, which assembles it correctly
-        server, ep = _echo_server()
+        server, ep = _echo_server(scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=10000))
             big = b"B" * (SMALL_FRAME_MAX * 3 + 17)
             cl = ch.call_sync("Bench", "Echo", big)
@@ -407,7 +424,8 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_handler_error_via_native_pluck(self):
+    @over_schemes
+    def test_handler_error_via_native_pluck(self, scheme):
         server = Server(ServerOptions(enable_builtin_services=False))
         svc = Service("Bench")
 
@@ -416,9 +434,9 @@ class TestLanesEndToEnd:
             cntl.set_failed(1007, "handler says no")
 
         server.add_service(svc)
-        ep = server.start("tcp://127.0.0.1:0")
+        ep = _listen(server, scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=5000, max_retry=0))
             cl = ch.call_sync("Bench", "Fail", b"x")
             assert cl.failed() and cl.error_code == 1007
@@ -427,7 +445,8 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_timeout_through_native_loop(self):
+    @over_schemes
+    def test_timeout_through_native_loop(self, scheme):
         server = Server(ServerOptions(enable_builtin_services=False))
         svc = Service("Bench")
         release = threading.Event()
@@ -439,9 +458,9 @@ class TestLanesEndToEnd:
             return b"late"
 
         server.add_service(svc)
-        ep = server.start("tcp://127.0.0.1:0")
+        ep = _listen(server, scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=150, max_retry=0))
             t0 = time.monotonic()
             cl = ch.call_sync("Bench", "Slow", b"x")
@@ -454,7 +473,8 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_peer_close_mid_pluck_fails_the_call(self):
+    @over_schemes
+    def test_peer_close_mid_pluck_fails_the_call(self, scheme):
         # a server that reads the request and closes without answering:
         # the native loop's EOF verdict must fail the call promptly
         # (connection error or timeout-free fast failure), never hang
@@ -470,7 +490,7 @@ class TestLanesEndToEnd:
 
         t = threading.Thread(target=evil, daemon=True)
         t.start()
-        ch = Channel(f"tcp://127.0.0.1:{port}",
+        ch = Channel(_addr(scheme, port),
                      ChannelOptions(timeout_ms=3000, max_retry=0))
         t0 = time.monotonic()
         cl = ch.call_sync("Bench", "Echo", b"x")
@@ -548,7 +568,8 @@ class TestLanesEndToEnd:
             protocol="hulu_pbrpc")) is None
         assert client_fast_drain_hook(ChannelOptions()) is not None
 
-    def test_timeout_releases_preclaim_and_socket_survives(self):
+    @over_schemes
+    def test_timeout_releases_preclaim_and_socket_survives(self, scheme):
         # the sync issue path claims the pluck lane PRE-send; a timed-out
         # call must settle that claim (reads resumed) so the connection
         # keeps working — and the late response is dropped as stale
@@ -563,9 +584,9 @@ class TestLanesEndToEnd:
             return b"ok:" + bytes(request)
 
         server.add_service(svc)
-        ep = server.start("tcp://127.0.0.1:0")
+        ep = _listen(server, scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=150, max_retry=0))
             cl = ch.call_sync("Bench", "Sometimes", b"slow")
             from brpc_tpu.rpc import errno_codes as berr
@@ -573,7 +594,7 @@ class TestLanesEndToEnd:
             # same channel, same socket: the lane must have been
             # released; the late 'slow' response must not corrupt or
             # complete this fresh call
-            ch2 = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch2 = Channel(_addr(scheme, ep.port),
                           ChannelOptions(timeout_ms=3000))
             for _ in range(5):
                 cl = ch.call_sync("Bench", "Sometimes", b"fast")
@@ -605,14 +626,15 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_two_sync_threads_share_one_multiplexed_socket(self):
+    @over_schemes
+    def test_two_sync_threads_share_one_multiplexed_socket(self, scheme):
         # two threads call_sync on the SAME shared channel: one wins the
         # pre-send pluck claim, the other's response crosses the winner's
         # native loop as a foreign cid (defer -> classic dispatch) or
         # completes via the event path — results must stay exact
-        server, ep = _echo_server()
+        server, ep = _echo_server(scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=5000))
             errs = []
 
@@ -638,10 +660,11 @@ class TestLanesEndToEnd:
         finally:
             server.stop()
 
-    def test_pipelined_async_then_sync_share_the_connection(self):
-        server, ep = _echo_server()
+    @over_schemes
+    def test_pipelined_async_then_sync_share_the_connection(self, scheme):
+        server, ep = _echo_server(scheme)
         try:
-            ch = Channel(f"tcp://127.0.0.1:{ep.port}",
+            ch = Channel(_addr(scheme, ep.port),
                          ChannelOptions(timeout_ms=5000))
             # async calls in flight force the multiplex gate: the sync
             # joiner must keep full semantics with responses for OTHER
@@ -655,3 +678,252 @@ class TestLanesEndToEnd:
             ch.close()
         finally:
             server.stop()
+
+
+# --------------------------------------------------------------------
+# The pluck protocol and the busy pause on the Socket itself, over every
+# conn that says pluck_fd: a Socket on one end of a real connection, a
+# scripted peer on the other, a messenger that records what it is given.
+class _Messenger:
+    """Stands where InputMessenger does (Socket finds the sync twin by
+    these two names). ``hold``: the next pass that sees bytes suspends
+    until the event is set, as a handler that awaits would."""
+
+    def __init__(self):
+        self.seen = bytearray()
+        self.threads = []
+        self.hold = None
+
+    def _take(self, sock):
+        self.threads.append(threading.current_thread().name)
+        self.seen += sock.input_portal.to_bytes()
+        sock.input_portal.clear()
+
+    async def on_new_messages(self, sock):
+        r = self.on_new_messages_sync(sock)
+        if r is not None:
+            await r
+
+    def on_new_messages_sync(self, sock):
+        if not sock.input_portal:
+            return None
+        self._take(sock)
+        hold, self.hold = self.hold, None
+        if hold is None:
+            return None
+
+        async def suspended():
+            await hold.wait(5)
+        return suspended()
+
+
+class _Wire:
+    """A Socket over a conn of ``kind`` and the far end of it."""
+
+    def __init__(self, kind):
+        from brpc_tpu.butil.endpoint import str2endpoint
+        from brpc_tpu.transport.socket import Socket
+        from brpc_tpu.transport.tcp import TcpConn
+
+        lis = socket.socket()
+        lis.bind(("127.0.0.1", 0))
+        lis.listen(1)
+        port = lis.getsockname()[1]
+        near = socket.create_connection(("127.0.0.1", port))
+        far, _ = lis.accept()
+        lis.close()
+        ep = str2endpoint(f"tcp://127.0.0.1:{port}")
+        conn = TcpConn(near, ep, ep)
+        self._far_sock = far
+        self._far_conn = None
+        if kind == "ici":
+            from brpc_tpu.transport.ici import IciConn
+            conn = IciConn(conn, ep, ep)
+            self._far_conn = IciConn(TcpConn(far, ep, ep), ep, ep)
+        self.messenger = _Messenger()
+        self.pauses, self.resumes = [], []
+        self.sock = Socket(conn, on_input=self.messenger.on_new_messages)
+        pause, resume = conn.pause_read_events, conn.resume_read_events
+        conn.pause_read_events = lambda: (self.pauses.append(1), pause())
+        conn.resume_read_events = lambda: (self.resumes.append(1), resume())
+        if kind == "ici":
+            self.wait_seen(b"")     # the far hello is read by an event
+
+    def send(self, data: bytes):
+        if self._far_conn is not None:
+            self._far_conn.write(memoryview(data))
+        else:
+            self._far_sock.sendall(data)
+
+    def wait_seen(self, want: bytes, timeout=3.0):
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            with self.sock._nevent_lock:
+                idle = self.sock._nevent == 0
+            if bytes(self.messenger.seen) == want and idle:
+                return
+            time.sleep(0.005)
+        raise AssertionError(f"saw {bytes(self.messenger.seen)!r}, "
+                             f"want {want!r}")
+
+    def close(self):
+        self.sock.set_failed(ConnectionError("test over"))
+        if self._far_conn is not None:
+            self._far_conn.close()
+        else:
+            self._far_sock.close()
+
+
+@pytest.fixture(params=SCHEMES)
+def wire(request):
+    w = _Wire(request.param)
+    yield w
+    w.close()
+
+
+class TestPluckProtocol:
+    def test_preclaim_is_exclusive_and_pauses_reads_once(self, wire):
+        s = wire.sock
+        assert s.pluck_preclaim()
+        assert s._plucking and s._busy_paused and len(wire.pauses) == 1
+        assert not s.pluck_preclaim()          # one plucker at a time
+        s.pluck_release()
+        assert not s._plucking
+
+    def test_release_with_nothing_in_flight_goes_sticky(self, wire):
+        """The settle leaves reads OFF; the next claim consumes the
+        pause with no read-interest syscall; any other consumer of the
+        socket re-arms first (unstick_reads)."""
+        s = wire.sock
+        assert s.pluck_preclaim()
+        s.pluck_release()
+        assert s._pluck_sticky and s._busy_paused and not wire.resumes
+        assert s.pluck_preclaim()              # free: no second pause
+        assert len(wire.pauses) == 1 and not s._pluck_sticky
+        s.pluck_release()
+        assert s._pluck_sticky
+        s.unstick_reads()
+        assert not s._pluck_sticky and not s._busy_paused
+        assert len(wire.resumes) == 1
+        wire.send(b"after")                    # the dispatcher is back
+        wire.wait_seen(b"after")
+        assert "dispatcher" in wire.messenger.threads[-1]
+
+    def test_release_with_a_call_in_flight_resumes_reads(self, wire):
+        s = wire.sock
+        assert s.pluck_preclaim()
+        with s.pending_lock:
+            s.client_inflight += 1             # another caller's call
+        s.pluck_release()
+        assert not s._pluck_sticky and not s._busy_paused
+        assert len(wire.resumes) == 1
+        with s.pending_lock:
+            s.client_inflight -= 1
+
+    def test_write_on_a_sticky_socket_rearms_reads(self, wire):
+        s = wire.sock
+        assert s.pluck_preclaim()
+        s.pluck_release()
+        assert s._pluck_sticky
+        s.write(b"ping")                       # a non-pluck consumer
+        assert not s._pluck_sticky and not s._busy_paused
+
+    def test_the_plucker_reads_its_own_bytes(self, wire):
+        """Between claim and release the fd's events are the joiner's:
+        it polls, drains and processes on its own thread."""
+        s, m = wire.sock, wire.messenger
+        assert s.pluck_preclaim()
+        wire.send(b"reply")
+        me = threading.current_thread().name
+        assert s.pluck_until(lambda: bytes(m.seen) == b"reply",
+                             time.monotonic() + 3, preclaimed=True)
+        assert m.threads[-1] == me
+        assert not s._plucking and s._pluck_sticky
+        with s._nevent_lock:
+            assert s._nevent == 0
+
+    def test_events_during_a_pluck_defer_to_it(self, wire):
+        """An event that slips in while a joiner owns the socket bumps
+        _nevent and starts no pass; the release settles it with one
+        normal pass and read interest comes back."""
+        s, m = wire.sock, wire.messenger
+        assert s.pluck_preclaim()
+        wire.send(b"late")
+        s._on_readable_event()                 # as the dispatcher would
+        assert not m.seen
+        s.pluck_release()
+        wire.wait_seen(b"late")
+        assert not s._plucking and not s._busy_paused
+
+    def test_escalation_hands_the_cycle_back(self, wire):
+        """A message whose processing suspends ends the pluck: claim
+        and pending-event accounting go back to the normal machinery,
+        which resumes reads when the suspended pass completes."""
+        from brpc_tpu.fiber.sync import FiberEvent
+        s, m = wire.sock, wire.messenger
+        m.hold = hold = FiberEvent()
+        assert s.pluck_preclaim()
+        wire.send(b"slow")
+        done = s.pluck_until(lambda: False, time.monotonic() + 3,
+                             preclaimed=True)
+        assert not done and bytes(m.seen) == b"slow"
+        assert not s._plucking                 # escalated, not expired
+        with s._nevent_lock:
+            assert s._nevent >= 1              # the pass is still owed
+        assert not s.pluck_preclaim()          # and it owns the socket
+        hold.set()
+        wire.wait_seen(b"slow")
+        assert not s._busy_paused and not s._pluck_sticky
+        wire.send(b"+next")
+        wire.wait_seen(b"slow+next")
+        assert "dispatcher" in m.threads[-1]
+
+
+class TestBusyPause:
+    def test_a_busy_period_pauses_once_and_resumes_once(self, wire):
+        """A pass suspended on its handler with data still arriving:
+        the level-triggered fd is paused for the rest of the busy
+        period (one pause, no dispatcher spin), the pass re-drains what
+        arrived when it resumes, and read interest comes back once."""
+        from brpc_tpu.fiber.sync import FiberEvent
+        from brpc_tpu.transport import syscall_stats
+        s, m = wire.sock, wire.messenger
+        m.hold = hold = FiberEvent()
+        wire.send(b"one")
+        deadline = time.monotonic() + 3
+        while bytes(m.seen) != b"one" and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert bytes(m.seen) == b"one"
+        ticks = syscall_stats.snapshot()["dispatcher_ticks"]
+        wire.send(b"two")
+        time.sleep(0.05)
+        wire.send(b"three")
+        time.sleep(0.1)
+        assert s._busy_paused and len(wire.pauses) == 1
+        assert bytes(m.seen) == b"one"          # still suspended
+        assert syscall_stats.snapshot()["dispatcher_ticks"] - ticks < 10
+        hold.set()
+        wire.wait_seen(b"onetwothree")
+        assert not s._busy_paused
+        assert len(wire.pauses) == 1 and len(wire.resumes) == 1
+
+    def test_a_peer_close_is_seen_while_the_pass_is_suspended(self, wire):
+        """The busy probe is a non-consuming peek: a FIN behind a
+        suspended pass fails the socket now, not when the handler is
+        done."""
+        from brpc_tpu.fiber.sync import FiberEvent
+        s, m = wire.sock, wire.messenger
+        m.hold = hold = FiberEvent()
+        wire.send(b"one")
+        deadline = time.monotonic() + 3
+        while bytes(m.seen) != b"one" and time.monotonic() < deadline:
+            time.sleep(0.005)
+        if wire._far_conn is not None:
+            wire._far_conn.close()
+        else:
+            wire._far_sock.close()
+        deadline = time.monotonic() + 3
+        while not s.failed and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert s.failed
+        hold.set()
