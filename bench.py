@@ -1368,55 +1368,6 @@ def main() -> None:
                 result["partial"] = True
                 _progress({"progress": "error", "phase": "serving",
                            "error": result["serving"]["error"]})
-        # ---- ring lane (ISSUE 15): the batched-syscall event lane.
-        # ring_smoke --burst-pair runs the pipelined multi-connection
-        # small-RPC burst in BOTH lane subprocesses (ring first, then
-        # selector — the event_ring_lane flag is process-global) and
-        # reports the same-run ratios the acceptance gates on:
-        # ring_syscall_drop (selector syscalls_per_rpc / ring, the
-        # native-boundary syscall floor — gate >= 2x), ring_qps_ratio
-        # and ring_p99_ratio (no worse). Subprocesses so a wedged
-        # burst cannot take the bench down.
-        if deadline.remaining() < 30.0:
-            result["ring"] = {"skipped": "wall budget"}
-            result["partial"] = True
-        else:
-            import subprocess as _sp
-            try:
-                p = _sp.run(
-                    [sys.executable,
-                     os.path.join(base, "tools", "ring_smoke.py"),
-                     "--burst-pair"],
-                    capture_output=True, text=True, timeout=300)
-                rep = json.loads(p.stdout.strip().splitlines()[-1])
-                rring = rep.get("ring") or {}
-                rsel = rep.get("selector") or {}
-                lane = {
-                    "backend": rring.get("backend"),
-                    "qps_ring": rring.get("qps"),
-                    "qps_selector": rsel.get("qps"),
-                    "syscalls_per_rpc_ring":
-                        rring.get("syscalls_per_rpc"),
-                    "syscalls_per_rpc_selector":
-                        rsel.get("syscalls_per_rpc"),
-                    "ring_p99_us": rring.get("p99_us"),
-                    "selector_p99_us": rsel.get("p99_us"),
-                    "ring_syscall_drop": rep.get("ring_syscall_drop"),
-                    "ring_qps_ratio": rep.get("ring_qps_ratio"),
-                    "ring_p99_ratio": rep.get("ring_p99_ratio"),
-                    "errors": rep.get("errors")}
-                result["ring"] = lane
-                for k in ("ring_syscall_drop", "ring_qps_ratio",
-                          "ring_p99_ratio"):
-                    if rep.get(k) is not None:
-                        result[k] = rep[k]
-                _progress({"progress": "ring_lane", **lane})
-            except Exception as e:  # noqa: BLE001 - diagnostics only
-                result["ring"] = {
-                    "error": f"{type(e).__name__}: {e}"[:200]}
-                result["partial"] = True
-                _progress({"progress": "error", "phase": "ring",
-                           "error": result["ring"]["error"]})
         ch.close()
     except BaseException as e:  # noqa: BLE001 - salvage partial data
         result["partial"] = True
@@ -1486,9 +1437,6 @@ def main() -> None:
         "replay_fidelity_pct": result.get("replay_fidelity_pct"),
         "capture_overhead_pct": result.get("capture_overhead_pct"),
         "series_overhead_pct": result.get("series_overhead_pct"),
-        "ring_syscall_drop": result.get("ring_syscall_drop"),
-        "ring_qps_ratio": result.get("ring_qps_ratio"),
-        "ring_p99_ratio": result.get("ring_p99_ratio"),
         # serving flight-deck headline set: throughput + TTFT from the
         # flapped bench lane, the deck's measured cost, and the
         # pre-wired prefix-cache ratio (0.0 until one exists)

@@ -79,7 +79,7 @@ _COMMON_METHODS = frozenset((
     "readlines", "fileno", "most_common", "elements", "total",
     "isoformat", "timestamp", "serialize", "parse",
     # threading.Condition verbs: a unique same-named fiber method must
-    # not claim a stdlib condvar's notify (ring_lane's _barrier_cv)
+    # not claim a stdlib condvar's notify
     "notify", "notify_all",
 ))
 

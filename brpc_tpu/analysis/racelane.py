@@ -80,11 +80,6 @@ LOCK_ORDER: List[Tuple[str, str]] = [
     ("Socket._failed_cb_lock",      "transport/socket.py"),
     ("Socket._lock",                "transport/socket.py"),
     ("EventDispatcher._lock",       "transport/event_dispatcher.py"),
-    # ring-lane twin of the dispatcher lock: fd registry + tick-barrier
-    # condvar (transport/ring_lane.py). Completion callbacks and the
-    # write flush fire OUTSIDE it; inside it only native ring calls run,
-    # so it never wraps another Python acquisition
-    ("RingDispatcher._lock",        "transport/ring_lane.py"),
     ("socket_map:_glock",           "transport/socket_map.py"),
     ("IciConn._pump_lock",          "transport/ici.py"),
     ("IciConn._flush_lock",         "transport/ici.py"),
@@ -508,13 +503,33 @@ def _parse_site(site) -> Tuple[str, int]:
     return os.path.basename(str(path)), int(ln)
 
 
-def _make_tracer(files, lines, quals, seed, tix):
+# One yield hands the interpreter to the peer for this many of ITS
+# watched lines at most (the hash picks 1..N), and gives up after this
+# long when the peer cannot advance (blocked on a lock the yielder
+# holds, parked in a join, asleep).
+_HANDOFF_MAX_LINES = 16
+_HANDOFF_TIMEOUT_S = 0.001
+
+
+def _make_tracer(files, lines, quals, seed, tix, progress, waiting, done):
     """One tracer per racer thread. The call-event filter keeps the
     line hook out of every frame not under watch, so the replay's
     overhead stays on the implicated functions only. Yield decisions
     are a pure function of (seed, thread index, site, hit #) — the
-    schedule replays exactly, run after run."""
+    schedule replays exactly, run after run.
+
+    A yield is a HANDOFF, not a nap: the yielder stands still until
+    the peer has executed the number of watched lines the hash drew
+    (``progress`` counts them per racer), so the peer really runs
+    inside the window between the yielder's check and its act. A
+    fixed 20us sleep left that to the OS: on a quiet box the sleeper
+    was back before the peer's thread had been scheduled, and a race
+    the finding describes never happened in the replay. The wait ends
+    early when the peer finishes, when it is itself standing at a
+    yield (two yielders must not wait on each other), or after
+    _HANDOFF_TIMEOUT_S."""
     k = [0]
+    peer = 1 - tix
 
     leaves = {q.split(".")[-1] for q in quals}
 
@@ -535,15 +550,24 @@ def _make_tracer(files, lines, quals, seed, tix):
         bn = os.path.basename(code.co_filename)
         if (bn, frame.f_lineno) in lines or match_qual(code):
             k[0] += 1
+            progress[tix] += 1
             h = zlib.crc32(f"{seed}|{tix}|{bn}:{frame.f_lineno}|"
                            f"{k[0]}".encode())
             if h % 2 == 0:
                 _state.yields += 1
-                # a POSITIVE sleep, unlike the lock twins' sleep(0):
-                # a zero sleep often re-acquires the GIL before the
-                # peer's condvar wakes, silently serializing the
-                # replay — 20us forces a real handoff into the window
-                time.sleep(0.00002)
+                want = progress[peer] + 1 + (h >> 1) % _HANDOFF_MAX_LINES
+                give_up = time.monotonic() + _HANDOFF_TIMEOUT_S
+                waiting[tix] = True
+                try:
+                    # a POSITIVE sleep: a zero sleep often re-acquires
+                    # the GIL before the peer's condvar wakes
+                    time.sleep(0.00002)
+                    while (progress[peer] < want and not done[peer]
+                           and not (tix and waiting[peer])
+                           and time.monotonic() < give_up):
+                        time.sleep(0.00002)
+                finally:
+                    waiting[tix] = False
         return line_hook
 
     def call_hook(frame, event, arg):
@@ -579,6 +603,8 @@ def replay_field_race(setup, racer_a, racer_b, sites, seed: int = 0,
     y0 = _state.yields
     obj = setup()
     done = [False, False]
+    progress = [0, 0]        # watched lines each racer has executed
+    waiting = [False, False]  # racer stands at a yield
     errs: List[str] = []
     # both racers align here before racing: without it the first
     # thread routinely finishes before the second's OS thread even
@@ -587,7 +613,8 @@ def replay_field_race(setup, racer_a, racer_b, sites, seed: int = 0,
 
     def run(fn, i):
         barrier.wait(timeout_s)
-        sys.settrace(_make_tracer(files, lines, quals, seed, i))
+        sys.settrace(_make_tracer(files, lines, quals, seed, i,
+                                  progress, waiting, done))
         try:
             fn(obj)
         except Exception as e:   # noqa: BLE001 - the report carries it

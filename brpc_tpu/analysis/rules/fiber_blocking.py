@@ -10,14 +10,8 @@ bthread" discipline. Fiber contexts are:
   * every ``async def`` in the package (fibers run coroutines);
   * ``parse`` / ``process`` / ``process_inline`` methods of Protocol
     subclasses (they run on the input path's fibers);
-  * everything in transport/event_dispatcher.py and
-    transport/ring_lane.py (the event loop / ring tick thread must
-    never block on anything but its own poll);
-  * the ring-lane completion entrypoints on Socket
-    (``RING_COMPLETION_METHODS``): the batched tick drains its
-    completion ring straight into them, so they are event-thread code
-    wherever they live — the drain only queues bytes, retires writes
-    and schedules fibers (ISSUE 15).
+  * everything in transport/event_dispatcher.py (the event loop
+    must never block on anything but its own poll).
 
 Context propagates through same-module synchronous calls (a helper
 called from a fiber context is itself a fiber context). Awaited calls
@@ -50,19 +44,9 @@ ALLOWLIST = (
 )
 
 # event-loop modules where EVERY function is a fiber-adjacent context
-CONTEXT_MODULES = ("brpc_tpu/transport/event_dispatcher.py",
-                   "brpc_tpu/transport/ring_lane.py")
+CONTEXT_MODULES = ("brpc_tpu/transport/event_dispatcher.py",)
 
 PROTOCOL_CONTEXT_METHODS = ("parse", "process", "process_inline")
-
-# ring-lane completion entrypoints (ISSUE 15): the batched tick drains
-# its completion ring straight into these Socket methods, so they run
-# on the dispatcher thread even though they live outside the
-# CONTEXT_MODULES — the drain must only queue bytes / retire writes /
-# schedule fibers, mirroring the scan lane's deferred-timeout
-# discipline (a blocking call here stalls EVERY fd in the batch)
-RING_COMPLETION_METHODS = ("ring_input", "ring_settle_write",
-                           "ring_collect_writes")
 
 _SUBPROCESS_BLOCKING = ("run", "call", "check_call", "check_output",
                         "Popen", "getoutput", "getstatusoutput")
@@ -135,8 +119,6 @@ class _ModuleIndex:
                 elif (cls is not None
                       and node.name in PROTOCOL_CONTEXT_METHODS
                       and cls in protocol_classes):
-                    self.roots.add(key)
-                elif node.name in RING_COMPLETION_METHODS:
                     self.roots.add(key)
                 v.stack.append(key)
                 for child in node.body:

@@ -27,10 +27,10 @@ function named ``register_postfork_reset``):
      ``is None``/truthiness guard on NAME, and an assignment whose
      value constructs an object (a Call whose callee is CapitalizedName
      or x.CapitalizedAttr) — or calls a SAME-MODULE factory helper
-     whose body constructs one (``_global = _new_dispatcher()`` where
-     ``def _new_dispatcher(): return RingDispatcher() or
-     EventDispatcher()``); the lane-selection indirection must not
-     launder the singleton past the rule. Accessors that hand the
+     whose body constructs one (``_global = _new_thing()`` where
+     ``def _new_thing(): return FastThing() or Thing()``); a
+     selection indirection must not launder the singleton past the
+     rule. Accessors that hand the
      instance to ``register_protocol`` are exempt: the protocol table
      is a fork-safe codec registry (pure data, no threads/fds), owned
      by protocol/registry.py.
@@ -128,7 +128,7 @@ class PostforkResetRule(Rule):
     def _factory_constructs(self, sf: SourceFile, value: ast.AST) -> bool:
         """True when ``value`` calls a same-module factory helper whose
         body contains a constructor-looking call — the
-        ``_global = _new_dispatcher()`` lane-selection idiom."""
+        ``_global = _new_thing()`` selection idiom."""
         factories = {node.name: node for node in sf.tree.body
                      if isinstance(node, ast.FunctionDef)}
         for node in ast.walk(value):

@@ -1,10 +1,11 @@
 """Thread-role model: which thread executes each function.
 
 Seeds come from the lock model's resolved ``threading.Thread`` roots —
-the dispatcher tick, ring-lane tick, timer thread, device poller,
+the dispatcher tick, timer thread, device poller,
 fiber worker pool, shard supervisor, bvar sampler, flight-recorder
 sampler, capture writer — plus every module's ``_postfork_reset``
-handler (the fork child is single-threaded when they run). Each seed
+handler (the fork child is single-threaded when they run) and the two
+callbacks a ``Socket`` stores with the dispatcher. Each seed
 is classified into a ROLE and the role propagates forward over the
 resolved call graph: a function reachable from the dispatcher tick
 runs (at least sometimes) on the dispatcher thread.
@@ -36,7 +37,10 @@ from brpc_tpu.analysis.lockmodel import LockModel, get_lock_model
 #: Known thread entry points: (module suffix, qualname, role).
 _SEED_ROLES: Tuple[Tuple[str, str, str], ...] = (
     ("transport.event_dispatcher", "EventDispatcher._run", "dispatcher"),
-    ("transport.ring_lane", "RingDispatcher._run", "ring-dispatcher"),
+    # the selector fires a Socket's stored callbacks, which the call
+    # graph does not follow: the two it stores are seeded by name
+    ("transport.socket", "Socket._on_readable_event", "dispatcher"),
+    ("transport.socket", "Socket._on_writable_event", "dispatcher"),
     ("fiber.timer", "TimerThread._run", "timer"),
     ("fiber.device_poller", "DeviceEventPoller._run", "device-poller"),
     ("fiber.scheduler", "TaskControl._worker", "fiber"),
@@ -50,7 +54,7 @@ _SEED_ROLES: Tuple[Tuple[str, str, str], ...] = (
 #: and "external" (arbitrary caller threads) are deliberately absent,
 #: as are ad-hoc "thread:<leaf>" roles for unrecognized future roots.
 SINGLE_THREAD_ROLES: FrozenSet[str] = frozenset((
-    "dispatcher", "ring-dispatcher", "timer", "device-poller",
+    "dispatcher", "timer", "device-poller",
     "supervisor", "bvar-sampler", "flight-sampler", "capture-writer",
     "postfork",
 ))
@@ -142,6 +146,11 @@ class ThreadModel:
         for fkey in m.funcs:
             if self._forks(fkey):
                 self.seeds.setdefault(fkey, "postfork")
+            else:
+                # a named entry point no Thread(target=...) points at
+                role = self._classify_seed(fkey)
+                if not role.startswith("thread:"):
+                    self.seeds.setdefault(fkey, role)
         for root, role in sorted(self.seeds.items()):
             parent = self._reach_with_parents(root)
             for fkey in parent:

@@ -666,6 +666,10 @@ class IOPortal(IOBuf):
         nr = recv_into(mv)
         if nr and nr > 0:
             blk.size = nr
+            # graftlint: disable=guarded-by -- a Socket's input portal
+            # is single-owner like every IOBuf: only the context that
+            # holds the input (Socket._nevent's 0->1 winner, or the
+            # plucker that claimed it) reads into it or cuts from it.
             self._refs.append(BlockRef(blk, 0, nr))
             return nr
         return 0

@@ -17,6 +17,7 @@ a storm's victims stay victims until closed.
 
 from __future__ import annotations
 
+import operator
 import threading
 import time
 import weakref
@@ -24,7 +25,9 @@ from typing import Callable, Dict, List, Optional
 
 from brpc_tpu.bvar.reducer import Adder
 from brpc_tpu.chaos.plan import Fault, FaultPlan, endpoint_key
-from brpc_tpu.transport.base import Conn, Listener, Transport
+from brpc_tpu.transport.base import (
+    OPTIONAL_NAMES, Conn, Listener, Transport,
+)
 
 # one injection counter per primitive (/vars chaos_injected_*)
 chaos_counters: Dict[str, Adder] = {
@@ -41,21 +44,21 @@ def _count(kind: str) -> None:
     chaos_counters[_COUNTER_FOR[kind]].add(1)
 
 
+def _passed(name: str) -> property:
+    """The wrapped conn's own answer to one name of the Conn contract,
+    asked each time (peer_info and lane_kind change when a hello
+    lands)."""
+    return property(operator.attrgetter("_inner." + name))
+
+
 class ChaosConn(Conn):
     """A Conn whose outbound stream replays a fault script. Reads,
     events and device payloads delegate to the wrapped conn."""
 
-    # Socket caches conn.writev and would bypass write(): hide it so
-    # every outbound byte crosses the fault script
+    # Socket caches conn.writev and would bypass write(): this conn
+    # lacks it, so every outbound byte crosses the fault script. Every
+    # other optional name of the contract passes (below the class).
     writev = None
-
-    # never ring-native (shadow the inner TcpConn's True before
-    # __getattr__ can forward it): the ring tick's native recv/writev
-    # would move bytes without crossing this fault script. Poll-only
-    # registration keeps the chaos lane observing every byte while the
-    # ring dispatcher still drives readiness.
-    supports_ring_sink = False
-    ring_attached = False
 
     def __init__(self, inner: Conn, faults: Optional[List[Fault]],
                  plan: FaultPlan, key: str, idx: int):
@@ -187,14 +190,6 @@ class ChaosConn(Conn):
         return self._inner.write_device_payload(arrays, **kw)
 
     @property
-    def supports_device_lane(self) -> bool:
-        return self._inner.supports_device_lane
-
-    @property
-    def supports_device_tracker(self) -> bool:
-        return getattr(self._inner, "supports_device_tracker", False)
-
-    @property
     def local_endpoint(self):
         return self._inner.local_endpoint
 
@@ -203,10 +198,15 @@ class ChaosConn(Conn):
         return self._inner.remote_endpoint
 
     def __getattr__(self, name):
-        # transport extras (read_chunks, pending_bytes, pluck_fd,
-        # stream_fd, ...): read-side and identity surfaces pass
-        # straight through
+        # what lies outside the contract (page and debug extras such as
+        # IciConn.lane_introspection and outstanding_batches)
         return getattr(self._inner, name)
+
+
+# A name Conn declares is found on the class, so __getattr__ never sees
+# it: each one this conn passes is passed here, a new one with no edit.
+for _name in OPTIONAL_NAMES - {"writev"}:
+    setattr(ChaosConn, _name, _passed(_name))
 
 
 class _ChaosListener(Listener):

@@ -49,10 +49,7 @@ _SAN_RUNTIMES = {"address": "libasan.so", "undefined": "libubsan.so",
 
 # fastcore.cc is a CPython extension module (needs Python headers,
 # exports PyInit__brpc_fastcore) — built separately from the C-ABI lib.
-# ring.cc (the batched-syscall event lane) rides this build so the
-# sanitizer lane's .san.so instruments it together with the fd loops.
-FASTCORE_SRCS = ("fastcore.cc", "respool.cc", "queues.cc", "httpparse.cc",
-                 "ring.cc")
+FASTCORE_SRCS = ("fastcore.cc", "respool.cc", "queues.cc", "httpparse.cc")
 FASTCORE_PATH = os.path.join(_DIR, "_brpc_fastcore.so")
 
 
@@ -185,12 +182,11 @@ def _compile(what: str, cmd: List[str], srcs: Sequence[str], out: str,
 
 
 def sources() -> list:
-    # fastcore.cc + httpparse.cc + ring.cc need Python headers: they
-    # belong to the extension module build only
+    # fastcore.cc + httpparse.cc need Python headers: they belong to
+    # the extension module build only
     return sorted(
         os.path.join(SRC_DIR, f) for f in os.listdir(SRC_DIR)
-        if f.endswith(".cc") and f not in ("fastcore.cc", "httpparse.cc",
-                                           "ring.cc")
+        if f.endswith(".cc") and f not in ("fastcore.cc", "httpparse.cc")
     )
 
 

@@ -58,16 +58,19 @@ uint64_t bt_mpsc_drained(bt_mpsc*);
 PyObject* fc_http_parse_request(PyObject*, PyObject*);
 PyObject* fc_http_parse_resp_head(PyObject*, PyObject*);
 
-// ring.cc — the batched-syscall event lane (Ring type + the
-// process-wide native-boundary syscall counters the fd loops below
-// stamp; syscall_stats.py derives syscalls_per_rpc from them)
-extern "C" int fc_ring_add_to_module(PyObject* m);
-extern std::atomic<unsigned long long> fc_sys_recv;
-extern std::atomic<unsigned long long> fc_sys_send;
-extern std::atomic<unsigned long long> fc_sys_accept;
-extern std::atomic<unsigned long long> fc_sys_poll;
-
 namespace {
+
+// ------------------------------------------------- syscall accounting --
+// Process-wide, lock-free native-boundary syscall counters: the fd
+// loops below (pluck_scan, serve_drain) bump them with the GIL
+// released, syscall_counts() reads them with it held, and
+// transport/syscall_stats.py merges them with the Python-side conn
+// counters. send and accept have no native caller today; the tuple
+// keeps its four places for its readers.
+std::atomic<unsigned long long> fc_sys_recv{0};
+std::atomic<unsigned long long> fc_sys_send{0};
+std::atomic<unsigned long long> fc_sys_accept{0};
+std::atomic<unsigned long long> fc_sys_poll{0};
 
 // ------------------------------------------------------------- varint --
 inline size_t varint_len(uint64_t v) {
@@ -1143,8 +1146,21 @@ PyTypeObject MpscType = {
     sizeof(MpscObject),             // tp_basicsize
 };
 
+PyObject* fc_syscall_counts(PyObject*, PyObject*) {
+  return Py_BuildValue(
+      "KKKK", fc_sys_recv.load(std::memory_order_relaxed),
+      fc_sys_send.load(std::memory_order_relaxed),
+      fc_sys_accept.load(std::memory_order_relaxed),
+      fc_sys_poll.load(std::memory_order_relaxed));
+}
+
 // ------------------------------------------------------------- module --
 PyMethodDef module_methods[] = {
+    {"syscall_counts", fc_syscall_counts, METH_NOARGS,
+     "syscall_counts() -> (recv, send, accept, poll): process-wide "
+     "native-boundary syscall counters (the fastcore fd loops) — "
+     "transport/syscall_stats.py merges them with the Python-side "
+     "conn counters into syscalls_per_rpc"},
     {"pack_frame", fc_pack_frame, METH_VARARGS,
      "pack_frame(magic, meta_prefix, cid, payload, attachment) -> bytes"},
     {"parse_head", fc_parse_head, METH_VARARGS,
@@ -1216,8 +1232,7 @@ PyMODINIT_FUNC PyInit__brpc_fastcore() {
   if (PyModule_AddObjectRef(m, "Pool",
                             reinterpret_cast<PyObject*>(&PoolType)) < 0 ||
       PyModule_AddObjectRef(m, "Mpsc",
-                            reinterpret_cast<PyObject*>(&MpscType)) < 0 ||
-      fc_ring_add_to_module(m) < 0) {
+                            reinterpret_cast<PyObject*>(&MpscType)) < 0) {
     Py_DECREF(m);
     return nullptr;
   }

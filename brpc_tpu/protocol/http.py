@@ -740,7 +740,7 @@ class HttpProtocol(Protocol):
             # device-lane introspection for ici:// conns (the page the
             # RDMA build exposes per-endpoint window state on)
             conn = s.conn
-            if hasattr(conn, "lane_kind"):
+            if hasattr(conn, "outstanding_batches"):
                 rows[-1]["lane_kind"] = conn.lane_kind
                 rows[-1]["outstanding_batches"] = conn.outstanding_batches
         return json.dumps(rows, indent=1).encode()

@@ -350,13 +350,13 @@ class Channel:
             sock = self._get_socket()
         except Exception:
             return None
-        conn = getattr(sock, "conn", None)
-        if conn is None or not getattr(conn, "supports_device_lane", False):
+        conn = sock.conn
+        if not conn.supports_device_lane:
             return None
-        kind = getattr(conn, "lane_kind", None)
+        kind = conn.lane_kind
         if kind is None:
             return None
-        if getattr(conn, "peer_info", True) is None:
+        if conn.peer_info is None:
             # hello still in flight: the kind would read as the staged
             # floor; wait for the negotiated answer
             deadline = time.monotonic() + timeout_s

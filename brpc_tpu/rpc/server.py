@@ -487,13 +487,7 @@ class Server:
             if fdr is None:    # resolve once; False = unavailable
                 from brpc_tpu.rpc.server_dispatch import make_fast_drain
                 fdr = self._fast_drain_hook = make_fast_drain(self) or False
-            if fdr is not False and not sock._ring_attached:
-                # ring lane: the dispatcher tick is this fd's only recv
-                # authority — the fd-draining serve_drain hook would
-                # read bytes that arrived AFTER chunks the ring already
-                # queued, serving them out of order. The portal-based
-                # native echo (input_messenger's nserve) still engages
-                # on ring-delivered bytes.
+            if fdr is not False:
                 sock.fast_drain = fdr
         with self._conns_lock:
             self._conns.append(sock)

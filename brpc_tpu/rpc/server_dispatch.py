@@ -594,7 +594,7 @@ def make_fast_drain(server):
             # nor piggyback the threshold — while the server is
             # shedding by priority it stands down, like capture)
             return False
-        pfd = getattr(sock.conn, "stream_fd", None)
+        pfd = sock.conn.stream_fd
         if pfd is not None:
             # the pinned dup (Socket.pin_fd_acquire) pins the kernel
             # socket against fd-number recycling mid-recv, amortized

@@ -25,7 +25,7 @@ stage-resolved:
   * **iteration telemetry** — one bounded ring of per-step records
     (batch occupancy, admit/evict counts, sweep/admit/decode/emit
     breakdown, wait-queue depth) behind one LEAF lock
-    (``ServingStats._ring_lock``; LOCK_ORDER row 43): the Orca lesson
+    (``ServingStats._ring_lock``; LOCK_ORDER row 41): the Orca lesson
     is that the STEP is the scheduling unit, so the step is what the
     flight deck must replay.
 
@@ -353,7 +353,7 @@ class ServingStats:
     """Process-wide registry: the labeled cell family, the pooled
     TTFT/TPOT LatencyRecorders (the timeline's quantile tracks), and
     the bounded step ring. ``_ring_lock`` is a LEAF (LOCK_ORDER row
-    43): it guards the ring only and is never held across a callback
+    41): it guards the ring only and is never held across a callback
     or another lock."""
 
     def __init__(self):

@@ -288,30 +288,12 @@ _global: Optional[EventDispatcher] = None
 _glock = threading.Lock()
 
 
-def _new_dispatcher():
-    """Lane selection, per-dispatcher: the ring lane (batched-syscall
-    ticks, transport/ring_lane.py) when the event_ring_lane flag is on
-    AND the native extension loads; the selector lane otherwise — and
-    on ANY ring bring-up failure, so a missing compiler can never take
-    eventing down with it."""
-    try:
-        from brpc_tpu.butil.flags import flag
-        from brpc_tpu.transport import ring_lane
-        if flag("event_ring_lane") and ring_lane.ring_available():
-            return ring_lane.RingDispatcher()
-    except Exception:
-        import logging
-        logging.getLogger("brpc_tpu.transport").exception(
-            "ring lane unavailable; falling back to the selector lane")
-    return EventDispatcher()
-
-
 def global_dispatcher() -> EventDispatcher:
     global _global
     if _global is None:
         with _glock:
             if _global is None:
-                _global = _new_dispatcher()
+                _global = EventDispatcher()
     return _global
 
 
@@ -343,10 +325,6 @@ def _postfork_reset() -> None:
     _stall_win_lock = threading.Lock()
     if d is not None:
         d._stop = True
-        abandon = getattr(d, "_postfork_abandon", None)
-        if abandon is not None:    # ring lane: closes wakeups + ring
-            abandon()
-            return
         try:
             d._selector.close()
         except Exception:

@@ -604,6 +604,21 @@ class IciConn(Conn):
                  window: int = DEFAULT_WINDOW,
                  pool: Optional[DeviceRecvPool] = None):
         self._inner = inner
+        # The inner conn's fd is this conn's fd: what it can do as an
+        # event source, this conn can, and says so by handing the inner
+        # conn's own answer over (one it lacks, this conn lacks). The
+        # Socket learns it once, at birth, and runs the read cycle TCP
+        # has: a busy period with data pending pauses read interest
+        # once and resumes once, and a sync joiner polls pluck_fd and
+        # reads its own reply through read_into. short_read_drained is
+        # not among them (PERF.md section 6, PR 27, has why), nor is
+        # stream_fd: what arrives on the fd are lane frames, not the
+        # application's bytes, so no native loop and no raw write may
+        # use it.
+        self.level_triggered = inner.level_triggered
+        self.pause_read_events = inner.pause_read_events
+        self.resume_read_events = inner.resume_read_events
+        self.pluck_fd = inner.pluck_fd
         self._local = local
         self._remote = remote
         self._recv_device_ordinal = recv_device_ordinal
@@ -1578,24 +1593,6 @@ class IciConn(Conn):
         # wake sources (whichever clears first fires on_writable once)
         self._want_writable = True
         self._inner.request_writable_event()
-
-    # The inner conn's fd is this conn's fd: what it can do as an event
-    # source, this conn can, and says so by handing the inner conn's own
-    # attribute over (one it lacks, this conn lacks). The Socket learns
-    # it once, at birth, and runs the read cycle TCP has: a busy period
-    # with data pending pauses read interest once and resumes once, and
-    # a sync joiner polls pluck_fd and reads its own reply through
-    # read_into, its drain ending at a short read. stream_fd is not among
-    # them: what arrives on the fd are lane frames, not the
-    # application's bytes, so no native loop and no raw write may use it.
-    _INNER_EVENT_CAPS = frozenset((
-        "level_triggered", "pause_read_events", "resume_read_events",
-        "pluck_fd"))
-
-    def __getattr__(self, name):
-        if name in IciConn._INNER_EVENT_CAPS:
-            return getattr(self._inner, name)
-        raise AttributeError(name)
 
     def peek_closed(self) -> bool:
         """True only when the peer's FIN has arrived, the kernel holds

@@ -38,7 +38,6 @@ from brpc_tpu.fiber.butex import Butex
 from brpc_tpu.transport.base import Conn, get_transport
 from brpc_tpu.transport import device_stats as _device_stats
 from brpc_tpu.transport import syscall_stats as _syscall_stats
-from brpc_tpu.transport.ring_lane import try_defer_write as _try_defer_write
 
 define_flag("socket_inline_process", True,
             "process socket input inline on the event-raising thread "
@@ -70,7 +69,7 @@ _COALESCE_MAX_BYTES = 1 << 20
 
 def _composite_cb(pending_cbs):
     """One done-callback firing a batch's unfired per-frame callbacks —
-    the parked-remainder composite every write lane hands to
+    the parked-remainder composite _write_coalesced hands to
     _park_handoff. None when there is nothing to fire."""
     if not pending_cbs:
         return None
@@ -402,7 +401,7 @@ def pull_chunks(sock):
     data=None means no scanning to do — handled tells the hook what to
     return (True: spurious wake or eof dealt with; False: not a chunk
     conn, and the hook was self-disabled)."""
-    rc = getattr(sock.conn, "read_chunks", None)
+    rc = sock.conn.read_chunks
     if rc is None:
         sock.fast_drain = None
         return None, False
@@ -498,18 +497,6 @@ class Socket:
         # sync input path; True = the pass was handled natively.
         # Installed by Server for eligible sockets, self-disabling.
         self.fast_drain: Optional[Callable] = None
-        # ring lane (transport/ring_lane.py): bytes the dispatcher tick
-        # recv'd natively queue here under _nevent_lock; the OWNING
-        # processing context moves them into the portal
-        # (_drain_readable's ring branch), so appender and parser never
-        # touch the portal concurrently — the classic lane's
-        # single-consumer invariant, kept structurally. _ring_fed marks
-        # a pass whose bytes arrived this way (initialized BEFORE
-        # start_events: a ring completion can fire mid-__init__).
-        self._ring_chunks: list = []
-        self._ring_fed = False
-        self._ring_attached = False
-        self._ring_pluck_ok = True
         self.user_data: dict = {}                 # per-conn session state
         # last read-event/write stamp (monotonic ns): the idle-class
         # signal for /census, /connections and idle_conn_count — one
@@ -532,30 +519,23 @@ class Socket:
         # captured once: /flags mutation applies to new sockets (a dict
         # lookup per readable event is measurable on the inline path)
         self._inline_process = flag("socket_inline_process")
-        self._inline_write = getattr(conn, "inline_write_ok", False)
-        self._drain_all_reads = getattr(conn, "drain_all_reads", False)
-        self._level_triggered = getattr(conn, "level_triggered", False)
-        # a read under 4096 bytes emptied the conn: the drain may stop
-        # there and leave the rest to the level trigger. A level-
-        # triggered conn says so apart (TcpConn does, IciConn does not)
+        # what the conn says of itself, read once and turned into this
+        # socket's switches (transport/base.py::Conn declares each
+        # name, its default and what follows from it)
+        self._inline_write = conn.inline_write_ok
+        self._drain_all_reads = conn.drain_all_reads
+        self._level_triggered = conn.level_triggered
         self._short_read_drained = self._level_triggered and \
-            getattr(conn, "short_read_drained", False)
-        self._writev = getattr(conn, "writev", None)
-        # a conn that frames its own queue and flushes on request
-        # (ici://) gets the gathering writer: _write_gathered
-        self._conn_flush = getattr(conn, "flush", None)
-        # a conn with flow control of its own (ici://) may hold output
-        # that only a frame from the peer releases: while it does, no
-        # sticky pluck pause may leave the fd unread (pluck_release)
-        self._conn_awaits_peer = getattr(conn, "awaits_peer_frame", None)
-        self._lane_tracked = getattr(conn, "supports_device_tracker", False)
-        self._readv = getattr(conn, "read_into_v", None)
-        self._read_chunks = getattr(conn, "read_chunks", None)
+            conn.short_read_drained
+        self._writev = conn.writev
+        self._conn_flush = conn.flush      # set: _write_gathered writes
+        self._conn_awaits_peer = conn.awaits_peer_frame
+        self._lane_tracked = conn.supports_device_tracker
+        self._readv = conn.read_into_v
+        self._read_chunks = conn.read_chunks
         # async big-write routing applies only to kernel-copy fd conns
-        # (stream_fd: the conn's writes are the fd's own byte stream)
         self._async_write_min = (flag("socket_async_write_min")
-                                 if getattr(conn, "stream_fd", None)
-                                 is not None else 0)
+                                 if conn.stream_fd is not None else 0)
         # pinned-fd cache for the native fd loops (pluck_scan /
         # serve_drain): ONE dup per socket instead of one dup+close
         # per call/event. Refcounted so set_failed can close it the
@@ -581,14 +561,7 @@ class Socket:
             raise ConnectionError("socket pool exhausted") from None
         with _live_sockets_lock:         # resource-census registry
             _live_sockets.add(self)
-        # ring lane: offer the completion sink BEFORE start_events —
-        # the conn decides there (ring-mode dispatcher + plain fd)
-        # whether to register ring-native or classic
-        if getattr(conn, "supports_ring_sink", False):
-            conn.ring_sink = self.ring_input
         conn.start_events(self._on_readable_event, self._on_writable_event)
-        self._ring_attached = getattr(conn, "ring_attached", False)
-        self._ring_pluck_ok = getattr(conn, "ring_pluck_ok", True)
 
     # ---------------------------------------------------------- pinned fd
     def pin_fd_acquire(self) -> int:
@@ -608,7 +581,7 @@ class Socket:
                 # stream_fd, not pluck_fd: a native loop reads the fd's
                 # bytes as the application's (on ici:// they are lane
                 # frames, and the joiner's poll is all the fd is for)
-                pfd = getattr(self.conn, "stream_fd", None)
+                pfd = self.conn.stream_fd
                 if pfd is None:
                     return -1
                 try:
@@ -698,6 +671,10 @@ class Socket:
             # response/peer data needs live read events again
             self.unstick_reads()
         nwrites.add(1)
+        # graftlint: disable=guarded-by -- last_active_ns is a stamp,
+        # not a count: each store is whole, the last writer wins, and
+        # its one reader (the idle reaper's age) tolerates either. A
+        # lock would sit on every submit and every read event.
         self.last_active_ns = time.monotonic_ns()
         sz = data.size if isinstance(data, IOBuf) else len(data)
         # graftlint: disable=guarded-by -- wq_bytes is approximate
@@ -712,13 +689,6 @@ class Socket:
             lane = (arrays, self._open_lane_tracker(arrays, span))
         if not self._wq.push((data, on_done, lane)):
             return True          # the active writer drains it in order
-        if self._ring_attached and type(data) is bytes and \
-                _try_defer_write(self):
-            # mid-tick on the ring thread: writership just claimed by
-            # the push is handed to the tick's end-of-batch flush — the
-            # whole burst's responses leave as one gather writev per
-            # connection instead of one send per frame
-            return True
         m = self._async_write_min
         if self._inline_write and not (m and sz >= m):
             self.write_inplace += 1
@@ -869,7 +839,7 @@ class Socket:
         if not _ds.enabled():
             return None
         conn = self.conn
-        lane = getattr(conn, "lane_kind", None) or \
+        lane = conn.lane_kind or \
             getattr(conn.remote_endpoint, "scheme", "device")
         # (lane, peer, cell) cached on the socket — the PR 7
         # cells-cached-per-channel discipline; lane_kind can change
@@ -1032,8 +1002,8 @@ class Socket:
     def _park_handoff(self, leftover, comp, lane=None) -> int:
         """Park a blocked write remainder on the writable event (the
         continuation takes it via _take_handoff) — the ONE copy of the
-        park protocol the single-frame, coalesced and ring write paths
-        all share; ``lane`` is a parked envelope's device batch the
+        park protocol the single-frame, coalesced and gathered write
+        paths all share; ``lane`` is a parked envelope's device batch the
         conn has not taken yet. The parked bytes re-enter the queue gauge: a
         stalled peer holding megabytes mid-frame is exactly what
         socket_wqueue_bytes exists to show (_take_handoff settles it
@@ -1139,98 +1109,6 @@ class Socket:
             return 1
         return 3 if st == -1 else 2
 
-    def ring_collect_writes(self):
-        """Ring-flush collect half (ring thread; writership was claimed
-        by the deferring push): drain queued frames into a flat list of
-        buffer views plus per-frame callback marks for ONE native
-        gather write. The coalescing caps bound what one writev pins,
-        exactly like _write_coalesced. Returns (views, marks, total)."""
-        views = []
-        marks = []              # (end_offset, cb) per frame
-        total = 0
-        while total < _COALESCE_MAX_BYTES and \
-                len(marks) < _COALESCE_MAX_FRAMES:
-            item = self._wq.drain_one()
-            if item is None:
-                break
-            self._wq_acct_pop(item)
-            data, cb = item[0], item[1]   # fd conns: no device lane
-            if isinstance(data, IOBuf):
-                # rare on this lane (deferral only claims bytes frames,
-                # but racing producers may queue IOBufs behind one):
-                # flatten — fd conns carry no device refs, and the ring
-                # batch is a small-frame lane
-                data = data.to_bytes()
-            if len(data):
-                views.append(data)
-                total += len(data)
-            marks.append((total, cb))
-        return views, marks, total
-
-    def ring_settle_write(self, res: int, errcode: int, views, marks,
-                          total: int) -> bool:
-        """Ring-flush settle half: fire done callbacks for fully-sent
-        frames, park a blocked remainder through the standard handoff
-        protocol (writable-event continuation), fail the socket on real
-        errors — the exact _write_coalesced contract, split so the
-        syscall itself could run in the tick's native batch. Returns
-        False when the socket failed."""
-        if errcode:
-            e = ConnectionError(
-                f"ring writev: {os.strerror(errcode)}")
-            self.set_failed(e)
-            for _, cb in marks:
-                if cb is not None:
-                    try:
-                        cb(e)
-                    except Exception:
-                        pass
-            # stragglers queued behind the batch fail-drain through the
-            # classic writer (we still hold writership), which retires
-            self._drain_writes_inline()
-            return False
-        sent = res
-        pending_cbs = []
-        for end, cb in marks:
-            if end <= sent:
-                if cb is not None:
-                    try:
-                        cb(None)
-                    except Exception:
-                        pass
-            elif cb is not None:
-                pending_cbs.append(cb)
-        if sent >= total:
-            # batch fully sent: anything that queued meanwhile drains
-            # classically, and try_retire releases writership
-            self._drain_writes_inline()
-            return True
-        # blocked mid-batch: rebuild the unsent tail as zero-copy
-        # user-data refs (only the straddled frame pays a slice) and
-        # park it with the unfired callbacks composited — the same
-        # protocol as _write_coalesced's status-1 exit
-        leftover = IOBuf()
-        off = 0
-        for v in views:
-            lv = len(v)
-            if off + lv <= sent:
-                off += lv
-                continue
-            start = sent - off if sent > off else 0
-            leftover.append_user_data(v[start:] if start else v)
-            off += lv
-        st = self._park_handoff(leftover, _composite_cb(pending_cbs))
-        if st == 1:
-            return True
-        if st == 0:
-            # park failed but writership is still this context's (the
-            # socket is now failed): fail-drain the stragglers queued
-            # behind the batch so their callbacks fire with the reason
-            # and try_retire releases writership — matching the errcode
-            # branch above and _drain_writes_inline's st==0 handling
-            self._drain_writes_inline()
-        return False
-
     def probe_unobserved(self) -> bool:
         """True when this socket is (now) failed. A sticky pluck pause
         leaves NOTHING watching the fd between sync calls, so a peer
@@ -1248,7 +1126,7 @@ class Socket:
             # close in a <5ms window still surfaces through the pluck
             # read itself, this probe exists for IDLE reuse
             return False
-        peek = getattr(self.conn, "peek_closed", None)
+        peek = self.conn.peek_closed
         if peek is not None:
             try:
                 if peek():
@@ -1363,57 +1241,6 @@ class Socket:
                 self._drain_writes_inline(first_item=item)
 
     # -------------------------------------------------------------- input
-    def ring_input(self, data, eof: bool = False, err: int = 0) -> None:
-        """Ring-lane completion sink (ring dispatcher thread): the tick
-        already recv'd ``data`` natively — queue it and run the
-        standard input cycle with the fd drain suppressed. Mirrors
-        _on_readable_event + _drain_readable with the recv replaced by
-        a chunk handoff; the busy/_nevent protocol, EOF verdicts and
-        escalation rules are shared, so the lanes cannot diverge on
-        semantics (completion drain only schedules work — the
-        graftlint ring-entrypoint contract)."""
-        self.last_active_ns = time.monotonic_ns()
-        if data:
-            nreads.add(len(data))
-        with self._nevent_lock:
-            if data:
-                self._ring_chunks.append(data)
-            self._nevent += 1
-            busy = self._nevent > 1 or self._plucking
-            if not busy:
-                self._ring_fed = True
-            elif data and self._level_triggered and not self._busy_paused:
-                # busy period with data still arriving: pause ring read
-                # interest for the rest of it, exactly like the classic
-                # level-trigger path (same lock, same flag — the resume
-                # in _finish_input_cycle cannot disagree)
-                self._busy_paused = True
-                try:
-                    self.conn.pause_read_events()
-                except Exception:
-                    self._busy_paused = False
-        if eof or err:
-            e = (ConnectionResetError("peer closed") if eof
-                 else ConnectionError(f"ring recv: {os.strerror(err)}"))
-            if busy:
-                # the owning pass may be SUSPENDED awaiting a handler;
-                # the failure must not wait for it, and set_failed runs
-                # user callbacks — keep them off the event thread (the
-                # classic peek path's discipline)
-                self._control.spawn(lambda: self.set_failed(e))
-                return
-            self.set_failed(e)   # inline: the drain's own verdict path
-        if busy:
-            return
-        if self._inline_process:
-            if self._on_input_sync is not None:
-                self._process_input_entry()
-            else:
-                self._control.run_inline(self._process_input(),
-                                         name="socket_input")
-        else:
-            self._control.spawn(self._process_input, name="socket_input")
-
     def _on_readable_event(self):
         """May fire from the dispatcher thread or a peer's fiber; only the
         0->1 transition starts a processing fiber."""
@@ -1444,7 +1271,7 @@ class Socket:
         # EOF probe from the dispatcher (the reference's event
         # dispatcher detects the hangup independently of message
         # processing for the same reason)
-        peek = getattr(self.conn, "peek_closed", None)
+        peek = self.conn.peek_closed
         if peek is not None:
             try:
                 if peek():
@@ -1475,16 +1302,22 @@ class Socket:
                         if self._nevent > 0 and not self._busy_paused:
                             self._busy_paused = True
                             self.conn.pause_read_events()
-                elif not self._busy_rearmed:
+                else:
                     # one-shot conns (ssl): this event consumed the read
                     # interest — re-arm so a later FIN during the same
                     # handler still produces an event. ONCE per busy
                     # period: unconditional re-arm with data pending
                     # would storm the dispatcher, and the input loop
-                    # re-drains pending data anyway via _nevent
-                    self._busy_rearmed = True
-                    resume = getattr(self.conn, "resume_read_events", None)
-                    if resume is not None:
+                    # re-drains pending data anyway via _nevent. The
+                    # flag is taken under the lock the busy period ends
+                    # under, and only while it lasts: set after its end
+                    # it would cost the NEXT period its re-arm
+                    with self._nevent_lock:
+                        rearm = not self._busy_rearmed
+                        if rearm and self._nevent > 0:
+                            self._busy_rearmed = True
+                    resume = self.conn.resume_read_events
+                    if rearm and resume is not None:
                         resume()
             except Exception:
                 pass
@@ -1564,12 +1397,8 @@ class Socket:
         (cross-thread wake, event-wait join) on roughly a coin flip.
         Returns True when claimed; the caller MUST hand the claim to
         pluck_until(preclaimed=True) or release via pluck_release()."""
-        if getattr(self.conn, "pluck_fd", None) is None \
+        if self.conn.pluck_fd is None \
                 or self._on_input_sync is None or self.failed:
-            return False
-        if self._ring_attached and not self._ring_pluck_ok:
-            # uring backend: an in-flight kernel RECV cannot be fenced
-            # synchronously — sync joins keep the event-driven path
             return False
         with self._nevent_lock:
             if self._nevent > 0 or self._plucking:
@@ -1579,31 +1408,12 @@ class Socket:
             # read interest is already off, so the claim pays NO
             # epoll_ctl (the steady sync-RPC state)
             self._pluck_sticky = False
-            reads_were_live = not self._busy_paused
             if self._level_triggered and not self._busy_paused:
                 self._busy_paused = True
                 try:
                     self.conn.pause_read_events()
                 except Exception:
                     self._busy_paused = False
-        if self._ring_attached and reads_were_live:
-            # reads were armed on the ring: fence the in-flight tick so
-            # its native pass cannot consume the response this claim is
-            # about to solicit (steady-state sticky claims skip — reads
-            # were already off, the ring never had the fd armed). The
-            # barrier runs OUTSIDE _nevent_lock: the tick may be
-            # delivering to this very socket's ring_input right now.
-            rb = getattr(self.conn, "ring_read_barrier", None)
-            if rb is not None:
-                rb()
-            if self._ring_chunks:
-                # bytes the ring stole before the fence (pre-request
-                # pipelined tails): we own processing now — move them
-                # into the portal so the pluck lanes judge them
-                with self._nevent_lock:
-                    chunks, self._ring_chunks = self._ring_chunks, []
-                for c in chunks:
-                    self.input_portal.append_user_data(c)
         return True
 
     def pluck_release(self) -> None:
@@ -1677,7 +1487,7 @@ class Socket:
         # lock-sensitive pause/resume dance must not exist twice
         if not preclaimed and not self.pluck_preclaim():
             return pred()   # can't pluck / processing in flight
-        pfd = getattr(self.conn, "pluck_fd", None)
+        pfd = self.conn.pluck_fd
         if pfd is None or self._on_input_sync is None:
             self.pluck_release()
             return pred()
@@ -1688,8 +1498,8 @@ class Socket:
             return pred()
         scan = None
         dup_fd = -1
-        if fast is not None and not self.input_portal and \
-                not self.input_need and not self._ring_chunks:
+        if fast is not None and not self.input_portal \
+                and not self.input_need:
             fc = _fastcore()
             scan = getattr(fc, "pluck_scan", None) if fc is not None else None
             if scan is not None:
@@ -1708,19 +1518,6 @@ class Socket:
                 remaining = deadline_s - time.monotonic()
                 if remaining <= 0:
                     break
-                if self._ring_chunks and not carry:
-                    # belt and braces: a ring completion slipped past
-                    # the preclaim fence (uring cross-tick tail) —
-                    # those bytes precede anything still in the kernel,
-                    # so the classic machinery must judge them first,
-                    # and the native scan stands down (a partial frame
-                    # left in the portal must not have its tail read
-                    # into the scan's carry out of order)
-                    scan = None
-                    escalated = self._pluck_process()
-                    if escalated:
-                        break
-                    continue
                 # short slices: pred() can flip without fd traffic
                 # (timeout timer, another thread completing the call)
                 if scan is not None:
@@ -1870,39 +1667,6 @@ class Socket:
         small reads shrink it back so idle connections don't hold large
         buffers — the readv-into-many-blocks effect of
         iobuf.h:469 without the iovec."""
-        if self._ring_fed or self._ring_attached or self._ring_chunks:
-            # ring lane: the dispatcher tick is the ONLY recv authority
-            # for this fd — this pass consumes what it queued (ordered:
-            # one appender, moved here by the one owning processing
-            # context). _ring_fed guards the birth race where a
-            # completion lands before __init__ stamps _ring_attached.
-            self._ring_fed = False
-            with self._nevent_lock:
-                chunks, self._ring_chunks = self._ring_chunks, []
-            total = 0
-            portal = self.input_portal
-            for c in chunks:
-                portal.append_user_data(c)
-                total += len(c)
-            if not (self._plucking and self._busy_paused):
-                return total
-            # pluck claim: preclaim paused ring reads AND fenced the
-            # in-flight tick (read_barrier), so the ring can no longer
-            # touch this fd — the PLUCKING context is the recv
-            # authority now. Everything the pluck lane routes through
-            # the classic machinery (a response past the scan's
-            # max_body, a large-request call that never armed the
-            # scan) reaches here, and without the fd drain below those
-            # bytes would sit in the kernel forever while pluck_until
-            # busy-polls readiness. Queued chunks went first (they
-            # were recv'd before anything the kernel still holds), so
-            # order is preserved; outside the claim the suppression
-            # above stands — an unfenced in-flight tick may hold an
-            # undelivered chunk, and an fd read here would land behind
-            # it out of order.
-            ring_total = total
-        else:
-            ring_total = 0
         rc = self._read_chunks
         if rc is not None:
             # zero-copy handoff (mem://): the writer's bytes objects
@@ -1920,7 +1684,7 @@ class Socket:
             if total:
                 nreads.add(total)
             return total
-        total = ring_total
+        total = 0
         while not self.failed:
             hint = self._read_hint
             try:
@@ -1938,7 +1702,7 @@ class Socket:
                 # — an EAGAIN rearm mid-pause would defeat the pause and
                 # let the fd re-fire hot for the rest of the busy period
                 if not self._level_triggered:
-                    resume = getattr(self.conn, "resume_read_events", None)
+                    resume = self.conn.resume_read_events
                     if resume is not None:
                         resume()
                 break
@@ -1951,6 +1715,10 @@ class Socket:
             if n >= hint:
                 # jump straight to the big recyclable size: intermediate
                 # sizes would allocate non-poolable buffers
+                # graftlint: disable=guarded-by -- _read_hint belongs
+                # to whoever holds the input (the _nevent 0->1 winner
+                # or the plucker that claimed it): one drain at a time,
+                # on whichever thread that context runs.
                 self._read_hint = _BIG_BLOCK_SIZE
             elif n < 4096:
                 self._read_hint = DEFAULT_BLOCK_SIZE
@@ -1978,7 +1746,7 @@ class Socket:
         return total
 
     def take_device_payload(self):
-        take = getattr(self.conn, "take_device_payload", None)
+        take = self.conn.take_device_payload
         if take is None:
             return None
         _ds = _device_stats
@@ -1990,7 +1758,7 @@ class Socket:
             return None
         dur_us = (time.monotonic_ns() - t0) / 1e3
         conn = self.conn
-        kind = getattr(conn, "lane_kind", None) or \
+        kind = conn.lane_kind or \
             getattr(conn.remote_endpoint, "scheme", "device")
         cached = self.__dict__.get("_dev_recv")
         if cached is None or cached[0] != kind:

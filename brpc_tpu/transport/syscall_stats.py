@@ -1,20 +1,16 @@
 """Syscall accounting floor: recv/writev/accept counted at the native
 boundary, merged into the /vars ``syscalls_per_rpc`` derived key.
 
-Two stamp sites, one per boundary kind (ISSUE 15 satellite — "not
-strace"):
+Two stamp sites, one per boundary kind ("not strace"):
 
-* **Native loops** (ring.cc ticks, fastcore's pluck_scan/serve_drain
-  fd loops) bump process-wide C atomics at the actual recv/writev/
-  accept/poll call sites; ``_brpc_fastcore.syscall_counts()`` reads
-  them.
+* **Native loops** (fastcore's pluck_scan/serve_drain fd loops) bump
+  process-wide C atomics in fastcore.cc at the actual recv/poll call
+  sites; ``_brpc_fastcore.syscall_counts()`` reads them.
 * **Python conns** (transport/tcp.py) bump the Adders below at the
-  conn-method boundary — the Python→libc crossing the ring lane
-  exists to batch away.
+  conn-method boundary, the Python→libc crossing.
 
-Both lanes stamp at the same altitude, so the bench's ring-vs-selector
-``syscalls_per_rpc`` ratio is honest: the selector lane's native echo
-loops count exactly like the ring lane's ticks.
+Both stamp at the same altitude, so a call served by a native loop
+and one served through the conn count alike.
 
 The denominator (``rpc_messages``) is stamped by the two dispatch
 authorities: ``input_messenger.record_dispatch_batch`` (classic +
@@ -94,8 +90,8 @@ def snapshot() -> dict:
 
 
 def syscalls_per_rpc() -> float:
-    """Cumulative (recv + writev + accept) per dispatched RPC message —
-    the ring-lane gate's cost metric. Poll/epoll wakeups are excluded:
+    """Cumulative (recv + writev + accept) per dispatched RPC message.
+    Poll/epoll wakeups are excluded:
     they amortize over whole ticks and would reward busy-waiting."""
     s = snapshot()
     denom = s["rpc_msgs"]

@@ -9,7 +9,6 @@ default (RPC latency over Nagle throughput).
 from __future__ import annotations
 
 import errno
-import os
 import socket as pysocket
 import threading
 from typing import Callable, Optional
@@ -20,8 +19,7 @@ from brpc_tpu.bvar.reducer import Adder
 from brpc_tpu.transport.base import Conn, Listener, Transport
 from brpc_tpu.transport.event_dispatcher import global_dispatcher
 # conn-boundary syscall floor (ISSUE 15): every Python->libc socket
-# crossing below stamps one of these — the selector lane's cost the
-# ring lane exists to batch away, counted where it's paid
+# crossing below stamps one of these, counted where it's paid
 from brpc_tpu.transport.syscall_stats import (py_accept as _c_accept,
                                               py_recv as _c_recv,
                                               py_writev as _c_writev)
@@ -47,17 +45,6 @@ class TcpConn(Conn):
     # cut_into_writer absorbs EAGAIN (partial frames hand off to the
     # keep_write fiber with the writing flag held).
     inline_write_ok = True
-
-    # ring lane (transport/ring_lane.py): Socket offers its completion
-    # sink before start_events; registration decides there whether the
-    # dispatcher tick owns this fd's recv (ring-native) or readiness
-    # fires the classic callback. Plain TCP is the only ring-native
-    # conn — ssl buffers decrypted bytes above the fd and chaos conns
-    # must keep every byte crossing their fault script.
-    supports_ring_sink = True
-    ring_sink = None             # set per-instance by Socket
-    ring_attached = False        # stamped by start_events
-    ring_pluck_ok = True         # batch backend: sync plucks can fence
 
     def __init__(self, sock: pysocket.socket, local: EndPoint, remote: EndPoint):
         sock.setblocking(False)
@@ -152,9 +139,9 @@ class TcpConn(Conn):
 
     # The same fd, for those that read or write the byte stream on it
     # directly: the native loops on a pinned dup (pluck_scan,
-    # serve_drain), the ring lane's gather write, the async big-write
-    # routing. Only this conn says it: on every conn layered over one
-    # (ici://, tpud://) the fd's bytes are that conn's frames.
+    # serve_drain) and the async big-write routing. Only this conn
+    # says it: on every conn layered over one (ici://, tpud://) the
+    # fd's bytes are that conn's frames.
     stream_fd = pluck_fd
 
     def peek_closed(self) -> bool:
@@ -180,18 +167,6 @@ class TcpConn(Conn):
 
     def start_events(self, on_readable, on_writable) -> None:
         self._on_writable = on_writable
-        d = global_dispatcher()
-        sink = self.ring_sink
-        if sink is not None and getattr(d, "ring_native", False):
-            # ring-native: the dispatcher tick recvs this fd inside its
-            # one GIL-released native pass and delivers bytes through
-            # the sink (Socket.ring_input); the classic callback stays
-            # registered for readiness the ring cannot consume
-            d.add_consumer(self._sock.fileno(), on_readable,
-                           oneshot_read=False, ring_recv=sink)
-            self.ring_attached = True
-            self.ring_pluck_ok = d.backend == "batch"
-            return
         # LEVEL-triggered: with inline processing the drain runs on the
         # dispatcher thread itself, so by the time the callback returns
         # the kernel buffer is empty and the level trigger is silent —
@@ -199,15 +174,8 @@ class TcpConn(Conn):
         # pauses read interest explicitly for the rare busy period
         # (handler suspended with data still arriving), which is where
         # one-shot arming paid a disarm+rearm syscall PER MESSAGE.
-        d.add_consumer(self._sock.fileno(), on_readable,
-                       oneshot_read=False)
-
-    def ring_read_barrier(self) -> None:
-        """Fence the in-flight ring tick (Socket.pluck_claim): past the
-        return, the native pass can no longer consume this fd."""
-        rb = getattr(global_dispatcher(), "read_barrier", None)
-        if rb is not None:
-            rb()
+        global_dispatcher().add_consumer(self._sock.fileno(), on_readable,
+                                         oneshot_read=False)
 
     def pause_read_events(self) -> None:
         global_dispatcher().pause_read(self._sock.fileno())
@@ -235,44 +203,7 @@ class _TcpListener(Listener):
         self._on_new_conn = on_new_conn
         self._stopped = False
         sock.setblocking(False)
-        d = global_dispatcher()
-        if getattr(d, "ring_native", False):
-            # ring-native listener: the tick's accept burst runs in the
-            # native pass; fds arrive pre-made (nonblocking, cloexec)
-            d.add_consumer(sock.fileno(), self._on_acceptable,
-                           ring_accept=self._on_ring_accept)
-        else:
-            d.add_consumer(sock.fileno(), self._on_acceptable)
-
-    def _on_ring_accept(self, res: int) -> None:
-        """Ring completion sink: one accepted fd (or -errno) per call.
-        The fd is already nonblocking+cloexec — wrap and hand off."""
-        if res < 0:
-            if -res in (errno.EMFILE, errno.ENFILE, errno.ENOMEM):
-                # same fd-exhaustion discipline as the classic loop: the
-                # kernel backlog would re-fire every tick — pause accept
-                # interest and let the timer resume it
-                self._pause_accept()
-            return
-        if self._stopped:
-            os.close(res)                # raced stop: never leak the fd
-            return
-        try:
-            s = pysocket.socket(fileno=res)
-        except OSError:
-            os.close(res)
-            return
-        try:
-            addr = s.getpeername()
-        except OSError:
-            try:
-                s.close()                # peer already gone (RST in backlog)
-            except OSError:
-                pass
-            return
-        local = self._ep
-        remote = str2endpoint(f"tcp://{addr[0]}:{addr[1]}")
-        self._on_new_conn(TcpConn(s, local, remote))
+        global_dispatcher().add_consumer(sock.fileno(), self._on_acceptable)
 
     def _on_acceptable(self):
         # accept-until-EAGAIN (acceptor.cpp:253 OnNewConnectionsUntilEAGAIN)

@@ -234,7 +234,7 @@ class TpudConn(Conn):
         self._inner.request_writable_event()
 
     def resume_read_events(self) -> None:
-        resume = getattr(self._inner, "resume_read_events", None)
+        resume = self._inner.resume_read_events
         if resume is not None:
             resume()
 

@@ -684,19 +684,16 @@ class TestLockModelSnapshot:
     # -> _ReducerBase._lock x2. DeviceCell._lock is a LOCK_ORDER leaf,
     # see racelane.py)
     #
-    # 44 -> 40 with the ring lane (ISSUE 15): return-annotation
-    # receiver typing keeps global_dispatcher().pause_read() resolving
-    # to EventDispatcher once RingDispatcher duck-types the same
-    # methods (the unique-method fallback would have silently DROPPED
-    # the four Socket._nevent_lock / SslConn._ssl_lock -> dispatcher
-    # edges), and blocklisting notify/notify_all from the fallback
-    # removed four edges that were never real: stdlib
+    # 44 -> 40 (ISSUE 15): return-annotation receiver typing resolves
+    # global_dispatcher().pause_read() to EventDispatcher by its
+    # declared return type, not by a unique method name (the four
+    # Socket._nevent_lock / SslConn._ssl_lock -> dispatcher edges),
+    # and blocklisting notify/notify_all from the unique-method
+    # fallback removed four edges that were never real: stdlib
     # threading.Condition notifies in fiber/timer.py and
     # fiber/scheduler.py had been misresolved to FiberCondition,
     # fabricating Butex/timer chains under PeriodicTask._lock,
-    # Controller._arb_lock and Butex._lock. RingDispatcher._lock
-    # itself adds no edges: only native ring calls run under it
-    # (LOCK_ORDER row 24).
+    # Controller._arb_lock and Butex._lock.
     #
     # 40 -> 42 with guardlint (ISSUE 16): fluent-chain receiver
     # typing (`ndropped_queue = Adder().expose(...)` now types the
@@ -706,6 +703,12 @@ class TestLockModelSnapshot:
     # _ReducerBase._lock leaf edges that were always executed but
     # previously invisible. _ReducerBase._lock is an acquire-last
     # leaf everywhere, so no LOCK_ORDER change.
+    #
+    # 42 stays 42 without the ring lane (ISSUE 28): its dispatcher's
+    # lock was a leaf (only native calls ran under it), so deleting
+    # the lane removed one lock (134 -> 133 discovered) and no edge;
+    # the socket -> EventDispatcher edges were already typed by
+    # annotation, not by the twin's absence.
     PINNED_EDGE_COUNT = 42
 
     def _model(self):
@@ -866,83 +869,6 @@ class TestCallbackUnderLock:
         sf_ok, ctx_ok = _ctx_for(path, "brpc_tpu/serving/batcher.py",
                                  src)
         assert list(CallbackUnderLockRule().finalize(ctx_ok)) == []
-
-
-class TestRingCompletion:
-    """ISSUE 15: the ring lane's completion entrypoints are event-thread
-    code — fiber-blocking treats ring_lane.py as a context module and
-    the Socket-side sinks (ring_input / ring_settle_write /
-    ring_collect_writes) as roots, and the completion drain must fire
-    callbacks only after releasing the registry lock."""
-
-    def test_seeded_violations(self):
-        active, _ = _lint("bad_ring_completion.py")
-        rules = sorted(f.rule for f in active)
-        assert rules == ["callback-under-lock"] + \
-            ["fiber-blocking"] * 3, [f.format() for f in active]
-        msgs = " | ".join(f.message for f in active)
-        # all three completion sinks are roots, including the
-        # forward-edge helper reached from ring_settle_write
-        assert "ring_input" in msgs
-        assert "ring_collect_writes" in msgs
-        assert "RingSocketish.ring_settle_write -> _settle_slowly" in msgs
-        # the drain firing cb() under the registry lock
-        assert "while holding RingDrain._lock" in msgs
-
-    def test_good_fixture_zero_false_positives(self):
-        active, waived = _lint("good_ring_completion.py")
-        assert active == [] and waived == [], \
-            [f.format() for f in active + waived]
-
-    def test_mutation_sleep_in_real_ring_input(self):
-        """Mutation pin on the REAL socket: a time.sleep dropped into
-        Socket.ring_input (the ring tick's recv sink) must fire
-        fiber-blocking — the sink runs on the dispatcher thread and a
-        block there stalls every fd in the batch."""
-        from brpc_tpu.analysis.rules.fiber_blocking import (
-            FiberBlockingRule,
-        )
-        path = os.path.join(REPO_ROOT, "brpc_tpu", "transport",
-                            "socket.py")
-        src = open(path).read()
-        anchor = ("    def ring_input(self, data, eof: bool = False, "
-                  "err: int = 0) -> None:\n")
-        assert anchor in src
-        mutated = src.replace(anchor,
-                              anchor + "        time.sleep(0.001)\n", 1)
-        sf, ctx = _ctx_for(path, "brpc_tpu/transport/socket.py",
-                           mutated)
-        found = list(FiberBlockingRule().check(sf, ctx))
-        assert any(f.rule == "fiber-blocking"
-                   and "ring_input" in f.message for f in found), \
-            [f.format() for f in found]
-        sf_ok, ctx_ok = _ctx_for(path, "brpc_tpu/transport/socket.py",
-                                 src)
-        assert list(FiberBlockingRule().check(sf_ok, ctx_ok)) == []
-
-    def test_mutation_sleep_in_real_completion_drain(self):
-        """Mutation pin on the REAL ring lane: ring_lane.py is a
-        context module, so a block anywhere in the completion drain
-        (_dispatch_completion) fires without needing a named root."""
-        from brpc_tpu.analysis.rules.fiber_blocking import (
-            FiberBlockingRule,
-        )
-        path = os.path.join(REPO_ROOT, "brpc_tpu", "transport",
-                            "ring_lane.py")
-        src = open(path).read()
-        anchor = "    def _dispatch_completion(self, comp) -> None:\n"
-        assert anchor in src
-        mutated = src.replace(anchor,
-                              anchor + "        time.sleep(0.001)\n", 1)
-        sf, ctx = _ctx_for(path, "brpc_tpu/transport/ring_lane.py",
-                           mutated)
-        found = list(FiberBlockingRule().check(sf, ctx))
-        assert any(f.rule == "fiber-blocking"
-                   and "_dispatch_completion" in f.message
-                   for f in found), [f.format() for f in found]
-        sf_ok, ctx_ok = _ctx_for(path, "brpc_tpu/transport/ring_lane.py",
-                                 src)
-        assert list(FiberBlockingRule().check(sf_ok, ctx_ok)) == []
 
 
 class TestBlockingUnderLock:
@@ -1181,7 +1107,7 @@ class TestDeviceObsLint:
     def test_device_cell_lock_ranked_after_ici_locks(self):
         """DeviceCell._lock is a declared LEAF acquired under the ici
         flush/pump holds (BatchTracker settle paths): it must rank
-        AFTER every IciConn lock in LOCK_ORDER + docs table row 29."""
+        AFTER every IciConn lock in LOCK_ORDER + docs table row 28."""
         from brpc_tpu.analysis.racelane import LOCK_ORDER
         names = [n for n, _ in LOCK_ORDER]
         assert "DeviceCell._lock" in names

@@ -102,7 +102,7 @@ class TestRealTree:
         ).run([os.path.join(REPO_ROOT, "brpc_tpu")])
         assert active == [], [f.format() for f in active]
         # the waivers that triage left behind: single-owner corpus
-        # files, IOBuf ownership transfer, ring-thread confinement,
+        # files, IOBuf ownership transfer, input-owner confinement,
         # approximate accounting — all reasoned
         assert len(waived) >= 8
         assert all(f.reason for f in waived), \
@@ -180,6 +180,39 @@ class TestMutations:
                    and "TaskControl._threads" in f.message
                    for f in found), [f.format() for f in found]
 
+    def test_dispatcher_role_follows_the_selector_into_socket(self):
+        # the selector fires a Socket's stored callbacks, which the
+        # call graph cannot follow: threadmodel seeds the two by name,
+        # and the scheduler mutation above fires only because the
+        # dispatcher role reaches TaskControl.spawn through them
+        from brpc_tpu.analysis.threadmodel import get_thread_model
+        tm = get_thread_model(Context(_tree_files()))
+        seeded = {fkey.split("::")[-1] for fkey, role in tm.seeds.items()
+                  if role == "dispatcher"}
+        assert seeded == {"EventDispatcher._run",
+                          "Socket._on_readable_event",
+                          "Socket._on_writable_event"}
+        reached = {fkey.split("::")[-1] for fkey, roles in tm.roles.items()
+                   if "dispatcher" in roles}
+        assert {"Socket._drain_readable", "Socket._drain_writes_inline",
+                "TaskControl.spawn",
+                "IOPortal.append_from_reader"} <= reached
+
+    def test_stripping_busy_rearm_lock_fires(self):
+        # the one-shot re-arm flag is taken under the lock the busy
+        # period ends under (ISSUE 28, found by the seeds above); a
+        # bare store from the dispatcher races _finish_input_cycle
+        found = _lint_mutated(
+            "brpc_tpu/transport/socket.py",
+            "                    with self._nevent_lock:\n"
+            "                        rearm = not self._busy_rearmed\n",
+            "                    if True:\n"
+            "                        rearm = not self._busy_rearmed\n")
+        assert any("[CONFIRMED]" in f.message
+                   and "Socket._busy_rearmed" in f.message
+                   and "dispatcher" in f.message
+                   for f in found), [f.format() for f in found]
+
     def test_stripping_single_role_write_stays_silent(self):
         # negative control: DeviceCell.note_open's lock guards against
         # the poller/external pair ONLY through the rest of the class —
@@ -234,15 +267,26 @@ class TestRacelaneReproducer:
             made.append(tc)
             return tc
 
+        starts_over = threading.Event()
+
         def starter(tc):
             import time
-            for _ in range(6):
-                tc.start()
-                time.sleep(0)
+            try:
+                for _ in range(6):
+                    tc.start()
+                    time.sleep(0)
+            finally:
+                starts_over.set()
 
         def stopper(tc):
-            for _ in range(6):
+            # stops for as long as the starter starts (and once more):
+            # six stops of an idle pool are over before the first
+            # start() has spawned a thread, and then nothing can race
+            for _ in range(400):
+                over = starts_over.is_set()
                 tc.stop_and_join(timeout=2.0)
+                if over:
+                    break
 
         def check(tc):
             with tc._start_lock:
@@ -275,10 +319,14 @@ class TestRacelaneReproducer:
     def test_prefix_teardown_races(self):
         # the buggy twin loses the race: the stopper claims the list
         # mid-start and joins a Thread that start() appended but had
-        # not yet started. Which seeds hit the window shifts with OS
-        # scheduling under box load, so scan seeds until two distinct
-        # ones reproduce — the fixed class (test below) survives the
-        # same storm at every seed, which is the discriminating pair
+        # not yet started. The replay forces it: a yield of the
+        # starter between the append and t.start() hands the stopper
+        # up to a whole stop_and_join of its own lines
+        # (racelane._make_tracer), and the stopper outlasts the
+        # starter (_storm). 46 of 48 seeds raced with twelve busy
+        # loops on eight cores; two of the first twelve is the bar.
+        # The fixed class (test below) survives the same storm at
+        # every seed, which is the discriminating pair
         _, buggy = self._twin()
         raced = []
         for seed in range(12):

@@ -230,7 +230,6 @@ def test_no_raw_stream_reader_starts_on_an_ici_socket(pair):
             assert sock.fast_drain in (None, hook)
         assert sock._pin_cell == [None]       # no dup was ever made
         assert sock._async_write_min == 0
-        assert not sock._ring_attached
         assert sock._level_triggered
 
 

@@ -8,16 +8,16 @@ import time
 
 from brpc_tpu.butil.endpoint import str2endpoint
 from brpc_tpu.butil.iobuf import IOBuf
+from brpc_tpu.transport.base import Conn
 from brpc_tpu.transport.socket import Socket
 
 
-class ThrottledConn:
+class ThrottledConn(Conn):
     """A conn that accepts only ``accept`` bytes per write() and then
     raises BlockingIOError until fed a writable event — the minimal
     harness for the mid-frame parking protocol."""
 
     inline_write_ok = True
-    supports_device_lane = False
 
     def __init__(self, accept: int = 4):
         self.accept = accept

@@ -357,12 +357,21 @@ class TestCapture:
             set_flag("rpc_dump_dir", "")
             ch.call_sync("T", "Echo", b"post")   # notices the clear
             assert not rec.capturing()
-            # load_dump reads the corpus through the old API
+            # load_dump reads the corpus through the old API. The
+            # clear was noticed on the dispatch path, which stops the
+            # recorder without waiting (flush_s=0): the writer thread
+            # drains the queue on its own, so give it its moment
             from brpc_tpu.rpc.rpc_dump import load_dump
-            got = []
-            for p in corpus_files(str(tmp_path)):
-                got.extend(load_dump(p))
-            payloads = {g[2] for g in got}
+            deadline = time.monotonic() + 5.0
+            while True:
+                got = []
+                for p in corpus_files(str(tmp_path)):
+                    got.extend(load_dump(p))
+                payloads = {g[2] for g in got}
+                if {b"l0", b"l1", b"l2"} <= payloads \
+                        or time.monotonic() > deadline:
+                    break
+                time.sleep(0.02)
             assert {b"l0", b"l1", b"l2"} <= payloads
             assert all(g[0] == "T" and g[1] == "Echo" for g in got)
             ch.close()

@@ -426,35 +426,6 @@ def gate_shard_smoke() -> dict:
     return out
 
 
-def gate_ring_lane() -> dict:
-    """The ring lane's probe + parity gate (tools/ring_smoke.py
-    --smoke): native backend probe (auto verdict + forced-uring
-    ENOSYS/EPERM fallback proof on kernels without io_uring),
-    ring-dispatcher bring-up in a lane subprocess, and byte-for-byte
-    framed-echo parity ring vs selector. Subprocesses so a wedged lane
-    cannot hang the gate."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "tools",
-                                      "ring_smoke.py"), "--smoke"],
-        cwd=REPO_ROOT, capture_output=True, text=True, timeout=300)
-    out: dict = {"ok": proc.returncode == 0}
-    try:
-        report = json.loads(proc.stdout)
-        out["backend"] = report.get("auto_backend")
-        out["uring_native"] = report.get("uring_native")
-        if report.get("enosys_fallback_proven"):
-            out["enosys_fallback_proven"] = True
-        if proc.returncode == 0:
-            out["parity"] = report.get("parity")
-            out["parity_calls"] = report.get("parity_calls")
-        else:
-            out["error"] = report.get("error")
-    except (ValueError, KeyError):
-        out["ok"] = False
-        out["error"] = (proc.stdout + proc.stderr)[-500:]
-    return out
-
-
 def gate_chaos_smoke() -> dict:
     """One seeded fault storm over mem:// (tools/chaos.py --smoke,
     ~10s budget): deadline shedding >= 99%, every call reaches a
@@ -955,7 +926,6 @@ def run_gate() -> int:
                      ("guard_lint", gate_guard_lint),
                      ("racelane", gate_racelane),
                      ("sanitizer_smoke", gate_sanitizer_smoke),
-                     ("ring_lane", gate_ring_lane),
                      ("chaos_smoke", gate_chaos_smoke),
                      ("trace_smoke", gate_trace_smoke),
                      ("shard_smoke", gate_shard_smoke),
