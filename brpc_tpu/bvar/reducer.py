@@ -13,6 +13,7 @@ from __future__ import annotations
 import threading
 from typing import Callable
 
+from brpc_tpu.butil import thread_cpu
 from brpc_tpu.bvar.variable import Variable
 
 
@@ -42,6 +43,9 @@ class _ReducerBase(Variable):
         if ag is None:
             ag = _Agent(self._identity)
             self._tls.agent = ag
+            # a thread's first write: an application thread that ends
+            # keeps its CPU in thread_cpu's ``caller`` total
+            thread_cpu.watch_exit()
             tid = threading.get_ident()
             with self._lock:
                 stale = self._agents.get(tid)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, List, Optional, Tuple
 
+from brpc_tpu.butil import thread_cpu
 from brpc_tpu.fiber.scheduler import Fiber, SchedAwaitable
 # device-thread labels for the flight recorder: the pump thread and the
 # per-wait PjRt waiter threads run OUTSIDE any fiber, so without these
@@ -75,6 +76,9 @@ class DeviceEventPoller:
                     self._active_waiters += 1
             if can_wait:
                 def wait_and_fire():
+                    # the thread dies with its wait: its CPU stays in
+                    # the role's total (thread_cpu's exit watch)
+                    thread_cpu.set_role("device_wait")
                     stamp_device_thread("device:wait")
                     try:
                         block()       # parks in PjRt's future (GIL freed)
@@ -120,6 +124,7 @@ class DeviceEventPoller:
         # objects) belong to the device lane on /hotspots; the unstamp
         # rides a finally — a pump killed by a throwing is_ready must
         # not leave a stale label for the OS to hand a reused tid
+        thread_cpu.set_role("device_wait")
         stamp_device_thread(f"device:{self._name}")
         try:
             self._run_inner(time)

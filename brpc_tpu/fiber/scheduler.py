@@ -32,6 +32,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Deque, List, Optional
 
+from brpc_tpu.butil import thread_cpu
 from brpc_tpu.butil.fast_rand import fast_rand_less_than
 from brpc_tpu.bvar.reducer import Adder, Maxer, PassiveStatus
 
@@ -513,6 +514,7 @@ class TaskControl:
     # ------------------------------------------------------------- worker
     def _worker(self, group: TaskGroup) -> None:
         from brpc_tpu.fiber import worker_module
+        thread_cpu.set_role("worker")
         _tls.group = group
         worker_module.notify_start(group.index)
         while not self._stop:

@@ -10,6 +10,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional
 
+from brpc_tpu.butil import thread_cpu
 from brpc_tpu.fiber.scheduler import Fiber, SchedAwaitable
 
 
@@ -77,6 +78,7 @@ class TimerThread:
                     self._ndead = 0
 
     def _run(self) -> None:
+        thread_cpu.set_role("timer")
         while not self._stop:
             with self._cond:
                 now = time.monotonic()

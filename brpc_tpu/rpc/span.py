@@ -27,6 +27,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, List, Optional, Tuple
 
+from brpc_tpu.butil import interp_probe
 from brpc_tpu.butil.fast_rand import fast_rand
 from brpc_tpu.butil.flags import flag
 
@@ -69,7 +70,10 @@ def _find_profile_state():
 def recording() -> bool:
     """Whether spans are being recorded: while a JAX profile runs in this
     process, or while ``rpcz_enabled`` is set. Off, it costs a global
-    read, an attribute read and a flag lookup; no lock, no import."""
+    read, an attribute read and a flag lookup; no lock, no import. On,
+    the first yes starts the probe of the wait for the interpreter
+    (``butil/interp_probe.py``), which asks here before every sleep and
+    ends with the first no."""
     global _clock_session
     state = _profile_state
     if state is None:
@@ -80,10 +84,14 @@ def recording() -> bool:
             if session is not _clock_session:
                 _clock_session = session
                 _emit_clock()
+            interp_probe.ensure_running(recording)
             return True
         if _clock_session is not None:
             _clock_session = None
-    return flag("rpcz_enabled")
+    if flag("rpcz_enabled"):
+        interp_probe.ensure_running(recording)
+        return True
+    return False
 
 
 def _emit_clock() -> None:

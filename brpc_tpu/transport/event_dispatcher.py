@@ -16,6 +16,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
 
+from brpc_tpu.butil import thread_cpu
 from brpc_tpu.bvar.reducer import Adder, Maxer, PassiveStatus
 
 # event-loop stall instrumentation (the flight recorder's watchdog
@@ -209,6 +210,7 @@ class EventDispatcher:
         self._wakeup()
 
     def _run(self):
+        thread_cpu.set_role("dispatcher")
         while not self._stop:
             try:
                 events = self._selector.select(timeout=0.5)

@@ -21,6 +21,7 @@ all-C echo loops served without ever crossing the interpreter).
 
 from __future__ import annotations
 
+from brpc_tpu.butil import interp_probe, thread_cpu
 from brpc_tpu.bvar.reducer import Adder, PassiveStatus
 
 # Python-side conn-boundary counters (tcp.py stamps these)
@@ -65,9 +66,9 @@ def _native_counts():
     return fn()
 
 
-def snapshot() -> dict:
-    """Merged totals since process start — the bench lanes window-delta
-    this around their measurement to derive per-RPC costs."""
+def _io_totals() -> dict:
+    """The always-on counts alone: what the ``/vars`` readers of this
+    module need, without a walk of the threads."""
     nrecv, nsend, naccept, npoll = _native_counts()
     # claims of writership that sent in place / spawned a keep_write
     # fiber (socket.py imports this module, hence the late import)
@@ -89,21 +90,40 @@ def snapshot() -> dict:
     }
 
 
+def snapshot() -> dict:
+    """Merged totals since process start — the bench lanes window-delta
+    this around their measurement to derive per-RPC costs."""
+    return {
+        **_io_totals(),
+        # CPU us by thread role (cpu_us_<role>, cpu_us_python: their
+        # sum), read off the live threads' clocks now; no key where the
+        # host lets no thread read another's clock
+        **thread_cpu.snapshot(),
+        # the probe of the wait for the interpreter: moves only while
+        # spans record
+        **interp_probe.snapshot(),
+    }
+
+
 def syscalls_per_rpc() -> float:
     """Cumulative (recv + writev + accept) per dispatched RPC message.
     Poll/epoll wakeups are excluded:
     they amortize over whole ticks and would reward busy-waiting."""
-    s = snapshot()
+    s = _io_totals()
     denom = s["rpc_msgs"]
     if not denom:
         return 0.0
     return round((s["recv"] + s["writev"] + s["accept"]) / denom, 3)
 
 
-_recv_var = PassiveStatus(lambda: snapshot()["recv"])
-_writev_var = PassiveStatus(lambda: snapshot()["writev"])
-_accept_var = PassiveStatus(lambda: snapshot()["accept"])
+_recv_var = PassiveStatus(lambda: _io_totals()["recv"])
+_writev_var = PassiveStatus(lambda: _io_totals()["writev"])
+_accept_var = PassiveStatus(lambda: _io_totals()["accept"])
 _ratio_var = PassiveStatus(syscalls_per_rpc)
+_role_cpu_vars = {
+    role: PassiveStatus(
+        lambda role=role: thread_cpu.by_role_recent().get(role, 0))
+    for role in thread_cpu.ROLES}
 
 
 def expose_syscall_vars() -> None:
@@ -114,6 +134,12 @@ def expose_syscall_vars() -> None:
     _writev_var.expose("syscalls_writev")
     _accept_var.expose("syscalls_accept")
     _ratio_var.expose("syscalls_per_rpc")
+    for role, var in _role_cpu_vars.items():
+        var.expose(f"thread_cpu_us_{role}")
+    interp_probe.probe_n.expose("interp_probe_n")
+    interp_probe.probe_wait_us.expose("interp_probe_wait_us")
+    interp_probe.probe_over_1ms.expose("interp_probe_over_1ms")
+    interp_probe.probe_over_4ms.expose("interp_probe_over_4ms")
 
 
 expose_syscall_vars()
