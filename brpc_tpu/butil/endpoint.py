@@ -36,6 +36,13 @@ class EndPoint:
         d = self.extra("device")
         return int(d) if d is not None else None
 
+    @property
+    def reply_device(self) -> Optional[int]:
+        """``#reply_device=K``: the dialer's local device that replies'
+        device arrays land on (None: it names none, which is device 0)."""
+        d = self.extra("reply_device")
+        return int(d) if d else None
+
     def with_extras(self, **kv) -> "EndPoint":
         merged: Dict[str, str] = dict(self.extras)
         merged.update({k: str(v) for k, v in kv.items()})

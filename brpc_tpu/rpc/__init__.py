@@ -9,7 +9,7 @@ from brpc_tpu.rpc.service import Method, Service, service_from_object
 from brpc_tpu.rpc.cluster_channel import ClusterChannel
 from brpc_tpu.rpc.combo_channels import (
     CallMapper, ParallelChannel, PartitionChannel, PartitionParser,
-    ResponseMerger, SelectiveChannel, SubCall,
+    ResponseMerger, RowScatterMapper, SelectiveChannel, SubCall, SumMerger,
 )
 from brpc_tpu.rpc.load_balancer import LoadBalancer, new_load_balancer
 from brpc_tpu.rpc.naming import NamingService, NamingServiceThread, register_naming_service
@@ -26,7 +26,8 @@ __all__ = [
     "errno_codes", "Controller", "Channel", "ChannelOptions",
     "Server", "ServerOptions", "Method", "Service", "service_from_object",
     "ClusterChannel", "CallMapper", "ParallelChannel", "PartitionChannel",
-    "PartitionParser", "ResponseMerger", "SelectiveChannel", "SubCall",
+    "PartitionParser", "ResponseMerger", "RowScatterMapper",
+    "SelectiveChannel", "SubCall", "SumMerger",
     "LoadBalancer", "new_load_balancer",
     "NamingService", "NamingServiceThread", "register_naming_service",
     "AuthContext", "AuthError", "Authenticator", "InterceptorError",

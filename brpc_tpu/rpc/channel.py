@@ -367,6 +367,14 @@ class Channel:
             kind = conn.lane_kind
         return kind
 
+    def reply_device(self):
+        """The local device this channel's replies' device arrays land
+        on: the endpoint's ``#reply_device=K``, device 0 where it names
+        none (what the ``ici://`` and ``tpu://`` dials read)."""
+        from brpc_tpu.butil.jax_runtime import local_device
+        return local_device(self._endpoint.reply_device,
+                            f"{self._endpoint} #reply_device")
+
     def close(self) -> None:
         """Release the connection(s); the channel may be re-used (it will
         reconnect lazily)."""

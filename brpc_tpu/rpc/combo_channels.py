@@ -9,18 +9,24 @@
                      its own server group (partition_channel.h:46-136).
 
 These are host-side fan-outs over arbitrary transports. When every
-sub-target is a device on one mesh, prefer parallel/collective.py which
-lowers the same shape onto XLA collectives instead of N point-to-point
-calls.
+sub-target is a device on one mesh, ``ParallelChannel.attach_collective``
+lowers a call whose mapper and merger say what they do
+(``RowScatterMapper`` with ``SumMerger`` or with the collecting default)
+onto ONE XLA collective program (parallel/collective.py) instead of N
+point-to-point calls.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from brpc_tpu.bvar.reducer import Adder
 from brpc_tpu.rpc import errno_codes as berr
+from brpc_tpu.rpc import span as _span
 from brpc_tpu.rpc.channel import Channel
 from brpc_tpu.rpc.controller import Controller
 from brpc_tpu.rpc.load_balancer import LoadBalancer, new_load_balancer
@@ -70,6 +76,111 @@ class ResponseMerger:
                 sub_cntl.response_device_arrays
 
 
+@functools.lru_cache(maxsize=None)
+def _row_block(sub_index: int, nsub: int) -> Callable:
+    """The jitted slice of block ``sub_index`` of ``nsub`` of a leading
+    dimension: one program a shape, as a caller's own slicing would be."""
+    import jax
+
+    def row_block(big):
+        rows = big.shape[0] // nsub
+        return jax.lax.slice_in_dim(big, sub_index * rows,
+                                    (sub_index + 1) * rows, axis=0)
+    return jax.jit(row_block)
+
+
+@functools.lru_cache(maxsize=None)
+def _sum_parts() -> Callable:
+    import jax
+
+    def sum_parts(*parts):
+        return functools.reduce(lambda a, b: a + b, parts)
+    return jax.jit(sum_parts)
+
+
+class RowScatterMapper(CallMapper):
+    """Block i of the leading dimension of the call's ONE request array
+    to sub i (the rows must divide by the sub count); the host bytes go
+    to every sub as they are. With ``SumMerger`` the fan-out is an
+    allreduce, and the pair is what ``attach_collective`` can lower."""
+
+    def map(self, sub_index: int, nsub: int, service: str, method: str,
+            request: Any, cntl: Controller) -> SubCall:
+        arrs = cntl.request_device_arrays
+        if not arrs or len(arrs) != 1:
+            raise ValueError("RowScatterMapper maps a call with one "
+                             f"request device array, not {len(arrs or ())}")
+        big = arrs[0]
+        if not big.shape or big.shape[0] % nsub:
+            raise ValueError(f"a request of shape {big.shape} does not "
+                             f"scatter over {nsub} sub channels: its "
+                             "leading dimension must divide")
+        return SubCall(service, method, request,
+                       device_arrays=[_row_block(sub_index, nsub)(big)])
+
+
+class SumMerger(ResponseMerger):
+    """Adds the sub replies' arrays (one a reply) where the first sub's
+    reply landed, the caller's reply device, in the order of the subs,
+    once the last one is in: the sum is ``cntl.response_device_arrays``.
+    The replies' bytes stay in ``sub_responses``, as the default keeps
+    them; the replies' arrays are given up for the sum
+    (``sub_device_arrays`` stays unfilled). A call that lost a sub has
+    no sum."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+
+    def merge(self, final_cntl: Controller, sub_index: int,
+              sub_cntl: Controller) -> None:
+        parts = sub_cntl.response_device_arrays
+        if not parts or len(parts) != 1:
+            raise ValueError(f"sub {sub_index} answered {len(parts or ())} "
+                             "device arrays; SumMerger adds one a sub")
+        final_cntl.sub_responses[sub_index] = (
+            sub_cntl.response_payload.to_bytes()
+            if sub_cntl.response_payload is not None else None)
+        with self._lock:
+            # kept and looked at in one step: only the merge that brings
+            # the last reply sees them all
+            held = final_cntl.__dict__.setdefault(
+                "_sum_parts", [None] * len(final_cntl.sub_responses))
+            held[sub_index] = parts[0]
+            if any(p is None for p in held):
+                return
+            del final_cntl.__dict__["_sum_parts"]
+        import jax
+        (device,) = held[0].devices()
+        held = [p if p.devices() == {device} else jax.device_put(p, device)
+                for p in held]
+        final_cntl.response_device_arrays = [_sum_parts()(*held)]
+
+
+# the lane of the /device cell a lowered call is a transfer of
+COLLECTIVE_LANE = "collective"
+
+# process-wide, on /vars (docs/observability.md): calls lowered to one
+# collective, lowerings that were tried and raised, request bytes lowered
+_fused_var = Adder(0)
+_fallbacks_var = Adder(0)
+_bytes_var = Adder(0)
+
+
+def expose_collective_vars() -> None:
+    """(Re-)expose the three: from ``attach_collective``, so a process
+    that lowers nothing lists none of them."""
+    _fused_var.expose("parallel_collective_fused")
+    _fallbacks_var.expose("parallel_collective_fallbacks")
+    _bytes_var.expose("parallel_collective_bytes")
+
+
+def collective_counters() -> dict:
+    """The three process-wide sums since start."""
+    return {"fused": _fused_var.get_value(),
+            "fallbacks": _fallbacks_var.get_value(),
+            "bytes": _bytes_var.get_value()}
+
+
 class ParallelChannel:
     def __init__(self, fail_limit: Optional[int] = None,
                  call_mapper: Optional[CallMapper] = None,
@@ -85,6 +196,8 @@ class ParallelChannel:
         self._collective = None
         self._collective_fns: Dict[Any, Callable] = {}
         self._lane_verdict: Optional[bool] = None
+        self._reply_devices: List[Any] = []
+        self._collective_peer = ""
         self.collective_fused = 0
         self.collective_fallbacks = 0
 
@@ -92,37 +205,83 @@ class ParallelChannel:
                           service_fns: Dict[Any, Callable]) -> None:
         """Arm collective lowering: ``collective`` is a
         parallel.collective.CollectiveChannel over the mesh whose
-        devices back the sub-channels; ``service_fns`` maps
-        ``(service, method)`` to the jax-traceable per-shard function
-        equivalent to what that RPC method computes. A device-array
-        call to a mapped method then lowers to ONE XLA collective
-        (scatter over the shard axis + on-device merge) instead of N
-        point-to-point lane RPCs — the fan-out and merge become ICI
-        traffic inside one compiled program. Calls that don't qualify
-        (host payloads, unmapped methods, a non-device-lane sub) fan
-        out exactly as before."""
+        devices back the sub-channels (shard i of the mesh is sub i's
+        server); ``service_fns`` maps ``(service, method)`` to the
+        jax-traceable per-shard function equivalent to what that RPC
+        method computes on its request array. A call to a mapped method
+        with ONE request device array then runs as ONE XLA program
+        (``jit_collective_<service>_<method>``) instead of N lane RPCs,
+        where the channel's mapper and merger are a pair the program
+        can express, and the merge is taken from the merger:
+
+          RowScatterMapper + SumMerger       -> scatter, fn, ``psum``;
+              the sum is one array on the channel's reply device (sub
+              0's ``#reply_device``) in ``cntl.response_device_arrays``
+          RowScatterMapper + ResponseMerger  -> scatter, fn, the blocks
+              unmerged (``concat``): block i is one array on sub i's
+              reply device in ``cntl.sub_device_arrays[i]``
+
+        which is where, and on which device, the fan-out of the same
+        pair leaves them, and ``response_device_arrays`` is filled by
+        the sum alone. The one field a lowered call cannot fill is
+        ``sub_responses``: the request's host bytes reach no handler,
+        so no reply bytes exist and every entry stays None.
+
+        Exactly these classes: a subclass, the broadcasting
+        ``CallMapper`` and any other pair fan out. So do a call with
+        host bytes only or several arrays, rows that do not divide by
+        the sub count, an unmapped method, a sub off the device lane, a
+        sub count other than the mesh's shards: none of them counts as
+        anything. A lowering that was tried and raised is logged once,
+        counted (``collective_fallbacks`` here, ``parallel_collective_
+        fallbacks`` on /vars, one failed transfer in the ``collective``
+        cell of /device) and the call fans out. The call completes
+        when the program is dispatched; its arrays are ready later, as
+        any reply's are. While spans record, a lowered call leaves one
+        client span (docs/observability.md)."""
         self._collective = collective
         self._collective_fns = dict(service_fns)
         self._lane_verdict = None
+        expose_collective_vars()
 
     def _all_device_lane(self) -> bool:
         """One probe per sub-channel generation: every sub must expose
         a device lane for the fused program to be equivalent (a plain
-        TCP sub would silently drop out of a collective)."""
+        TCP sub would silently drop out of a collective). The subs'
+        reply devices are learned with it."""
         if self._lane_verdict is None:
             try:
                 self._lane_verdict = bool(self._subs) and all(
                     sub.device_lane_kind() is not None
                     for sub in self._subs)
+                if self._lane_verdict:
+                    self._reply_devices = [sub.reply_device()
+                                           for sub in self._subs]
+                    mesh = self._collective.mesh
+                    self._collective_peer = "mesh://" + "x".join(
+                        f"{name}{size}" for name, size in mesh.shape.items())
             except Exception:
                 self._lane_verdict = False
         return self._lane_verdict
 
+    def _lowered_merge(self) -> Optional[str]:
+        """The collective that this channel's mapper and merger add up
+        to, or None. By class and not by isinstance: a subclass may do
+        anything."""
+        if type(self.call_mapper) is not RowScatterMapper:
+            return None
+        merger = type(self.response_merger)
+        if merger is SumMerger:
+            return "sum"
+        if merger is ResponseMerger:
+            return "concat"
+        return None
+
     def _maybe_collective(self, service: str, method: str,
                           cntl: Controller) -> bool:
-        """Try the fused path; True means the call completed there.
-        Any lowering failure falls back to the per-sub fan-out — the
-        optimization must never change call semantics."""
+        """Try the fused path; True means the call completed there. A
+        call that does not qualify, or whose lowering raises, takes the
+        per-sub fan-out, which gives the same answer."""
         coll = self._collective
         if coll is None:
             return False
@@ -132,15 +291,65 @@ class ParallelChannel:
         arrs = cntl.request_device_arrays
         if not arrs or len(arrs) != 1:
             return False
-        if type(self.call_mapper) is not CallMapper:
-            # a custom mapper rewrites per-sub requests; the collective
-            # can only express the stock scatter shape
+        merge = self._lowered_merge()
+        if merge is None:
             return False
-        if len(self._subs) != coll.n_shards or not self._all_device_lane():
+        nsub = len(self._subs)
+        if nsub != coll.n_shards or not self._all_device_lane():
             return False
+        request = arrs[0]
+        if not request.shape or request.shape[0] % nsub:
+            return False        # the mapper refuses it, with the reason
+        from brpc_tpu.parallel.collective import ready_waiter
+        from brpc_tpu.transport import device_stats
+
+        span = None
+        if _span.recording():
+            span = _span.start_client_span(cntl, service, method)
+            span.remote_side = self._collective_peer
+            span.request_size = request.nbytes
+            span.annotate(f"collective lowered: scatter + {merge} over "
+                          f"{nsub} shards, no sub call")
+        tracker = device_stats.open_transfer(
+            self._collective_peer, COLLECTIVE_LANE, request.nbytes)
+
+        def on_ready(err) -> None:
+            """The waiter's thread: the result is on the reply device
+            (or the wait for it raised ``err``)."""
+            if tracker is not None:
+                if err is None:
+                    tracker.lane_acked()
+                else:
+                    tracker.lane_failed(f"result not ready: {err}")
+            if span is not None:
+                # the lowered call's first byte, and the span's end
+                span.first_byte_us = time.monotonic_ns() // 1000
+                if err is not None:
+                    span.annotate(f"result not ready: {err}"[:200])
+                _span.finish_span(span, cntl)
+
         try:
-            out = coll.call(fn, arrs[0])
-        except Exception:
+            placed, src = coll.scatter(request)
+            if tracker is not None:
+                tracker.lane_encoded()
+            if span is not None:
+                span.write_done_us = time.monotonic_ns() // 1000
+            out = coll.run(fn, placed, src, merge,
+                           name=f"collective_{service}_{method}")
+            if tracker is not None:
+                tracker.lane_flushed()
+            if span is not None:
+                span.dispatch_us = time.monotonic_ns() // 1000
+            # the arrays where the merger's fan-out leaves them: the sum
+            # as the one response array on sub 0's reply device, the
+            # collected blocks one a sub on that sub's
+            if merge == "sum":
+                out = [coll.replica_on(out, self._reply_devices[0])]
+                cntl.response_device_arrays = out
+            else:
+                out = coll.blocks_on(out, self._reply_devices)
+                cntl.sub_device_arrays = [[block] for block in out]
+        except Exception as e:
             if not self.collective_fallbacks:
                 # once per channel: the fan-out below keeps the call's
                 # semantics, but a lowering that never works must not
@@ -150,10 +359,20 @@ class ParallelChannel:
                     "to the per-sub fan-out (collective_fallbacks "
                     "counts the rest)", service, method)
             self.collective_fallbacks += 1
+            _fallbacks_var.add(1)
+            if tracker is not None:
+                tracker.lane_failed(f"lowering raised: {e}")
+            if span is not None:
+                # the call has not failed: it fans out from here
+                span.annotate(f"lowering raised, fanned out: {e}"[:200])
+                _span.finish_span(span, cntl)
             return False
         self.collective_fused += 1
+        _fused_var.add(1)
+        _bytes_var.add(request.nbytes)
+        if tracker is not None or span is not None:
+            ready_waiter().watch(out, on_ready)
         cntl.collective_lowered = True
-        cntl.response_device_arrays = [out]
         cntl._complete()
         return True
 
@@ -187,11 +406,19 @@ class ParallelChannel:
         state = {"pending": 0, "failed": 0, "done": False}
         lock = threading.Lock()
         sub_calls = []
-        for i, sub in enumerate(subs):
-            sc = self.call_mapper.map(i, nsub, service, method, request, cntl)
-            if sc is None or sc.skip:
-                continue
-            sub_calls.append((i, sub, sc))
+        try:
+            for i, sub in enumerate(subs):
+                sc = self.call_mapper.map(i, nsub, service, method, request,
+                                          cntl)
+                if sc is None or sc.skip:
+                    continue
+                sub_calls.append((i, sub, sc))
+        except Exception as e:
+            # upstream's SubCall::Bad(): a request the mapper cannot map
+            # fails the call, before any sub call is issued
+            cntl.set_failed(berr.EREQUEST, f"call mapper failed: {e}")
+            cntl._complete()
+            return cntl
         if not sub_calls:
             cntl.set_failed(berr.EREQUEST, "call mapper skipped every sub call")
             cntl._complete()
@@ -200,7 +427,19 @@ class ParallelChannel:
 
         def on_sub_done(i):
             def _cb(sub_cntl):
-                finish = False
+                # merge() first, THEN count the sub call as done: the
+                # thread that counts the last one finds every merge
+                # finished, so a call never completes ahead of a merge
+                merge_error = None
+                if not sub_cntl.failed():
+                    with lock:
+                        late = state["done"]
+                    if late:
+                        return
+                    try:
+                        self.response_merger.merge(cntl, i, sub_cntl)
+                    except Exception as e:
+                        merge_error = (berr.ERESPONSE, f"merger failed: {e}")
                 with lock:
                     if state["done"]:
                         return
@@ -208,23 +447,20 @@ class ParallelChannel:
                         state["failed"] += 1
                         cntl.sub_errors[i] = (sub_cntl.error_code,
                                               sub_cntl.error_text)
+                    elif merge_error is not None:
+                        state["failed"] += 1
+                        cntl.sub_errors[i] = merge_error
                     state["pending"] -= 1
-                    if state["failed"] >= fail_limit or state["pending"] == 0:
+                    finish = (state["failed"] >= fail_limit
+                              or state["pending"] == 0)
+                    if finish:
                         state["done"] = True
-                        finish = True
-                if not sub_cntl.failed():
-                    try:
-                        self.response_merger.merge(cntl, i, sub_cntl)
-                    except Exception as e:
-                        with lock:
-                            state["failed"] += 1
-                        cntl.sub_errors[i] = (berr.ERESPONSE,
-                                              f"merger failed: {e}")
+                        failed = state["failed"]
                 if finish:
-                    if state["failed"] >= fail_limit:
+                    if failed >= fail_limit:
                         cntl.set_failed(
                             berr.ETOOMANYFAILS,
-                            f"{state['failed']}/{len(sub_calls)} sub calls failed")
+                            f"{failed}/{len(sub_calls)} sub calls failed")
                     cntl._complete()
             return _cb
 

@@ -139,6 +139,13 @@ class Span:
     # Client side:
     write_done_us: int = 0      # request write completed (on_done)
     first_byte_us: int = 0      # response frame seen by the client
+    # A ParallelChannel call lowered to one collective (combo_channels.
+    # _maybe_collective) leaves ONE client span and no server span:
+    # write_done_us = the scatter handed off, dispatch_us = the program
+    # dispatched (jit returned; start_us -> dispatch_us is the host's
+    # whole cost of the call), first_byte_us = the result ready on the
+    # reply device, end_us right after; an annotation says "collective
+    # lowered", remote_side names the mesh.
     annotations: List[Tuple[int, str]] = field(default_factory=list)
     # response-flush delegation latch (server side): when the response
     # write's completion callback owns the flush stamp, finish_span may

@@ -428,6 +428,9 @@ _thread_labels: Dict[int, str] = {}
 
 
 def stamp_device_thread(label: str, tid: Optional[int] = None) -> None:
+    # graftlint: disable=guarded-by -- every thread writes the entry of
+    # its own ident only (the poller's pump, the collective waiter), and
+    # a dict store is one GIL-atomic operation: no two writers of a key
     _thread_labels[tid if tid is not None
                    else threading.get_ident()] = label
 

@@ -1726,7 +1726,7 @@ class IciTransport(Transport):
         return _IciListener(inner, bound)
 
     def connect(self, ep: EndPoint) -> Conn:
-        reply = int(ep.extra("reply_device") or 0)
+        reply = ep.reply_device or 0
         local_device(reply, f"{ep} #reply_device")  # out of range: refuse
         tcp_ep = EndPoint("tcp", ep.host, ep.port, ep.extras)
         inner = self._tcp.connect(tcp_ep)

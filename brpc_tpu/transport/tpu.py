@@ -100,12 +100,11 @@ class TpuTransport(Transport):
         client_ep = EndPoint("tpu", f"client-{id(a2b):x}", 0)
         # requests land on the server's device; responses land on the
         # client's reply device (the `reply_device` extra, default dev 0)
-        reply = ep.extra("reply_device")
         client = TpuConn(rx=b2a, tx=a2b, local=client_ep, remote=ep,
                          peer_device_ordinal=ep.device,
                          what=f"{ep} #device")
         server = TpuConn(rx=a2b, tx=b2a, local=server_ep, remote=client_ep,
-                         peer_device_ordinal=int(reply) if reply else None,
+                         peer_device_ordinal=ep.reply_device,
                          what=f"{ep} #reply_device")
         client.peer = server
         server.peer = client

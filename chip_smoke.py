@@ -554,22 +554,21 @@ def phase_kernel(smoke: Smoke) -> dict:
 
 def phase_four_chips(smoke: Smoke) -> dict:
     """Four ici://...#device=i servers in the one process; nothing may
-    collapse onto chip 0. Stock ParallelChannel fan-out, then the same
-    channel lowered to one collective, then the collective pipeline at
-    real shapes."""
+    collapse onto chip 0. Stock ParallelChannel fan-out, then a
+    scattering, summing channel over the same connections, fanned out
+    and lowered to one collective, then the collective pipeline at real
+    shapes."""
     import numpy as np
 
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
 
     import __graft_entry__ as graft
-    from brpc_tpu.parallel import (SHARD_AXIS, CollectiveChannel,
-                                   make_rpc_mesh)
-    from brpc_tpu.parallel.mesh import shard_map
+    from brpc_tpu.parallel import CollectiveChannel, make_rpc_mesh
     from brpc_tpu.rpc import (Channel, ChannelOptions, Controller, Server,
                               ServerOptions, Service)
-    from brpc_tpu.rpc.combo_channels import ParallelChannel
+    from brpc_tpu.rpc.combo_channels import (ParallelChannel,
+                                             RowScatterMapper, SumMerger)
 
     n = 4
     devs = jax.devices()[:n]
@@ -577,14 +576,18 @@ def phase_four_chips(smoke: Smoke) -> dict:
     misplaced: list = []
     servers, subs = [], []
     pch = ParallelChannel()
+    # block i of the request's rows to shard i, the replies summed where
+    # sub 0 replies (chip 0): the pair attach_collective lowers
+    reduce_ch = ParallelChannel(call_mapper=RowScatterMapper(),
+                                response_merger=SumMerger())
 
-    def make_shard(idx):
+    def make_shard(idx, factor):
         def Shard(cntl, request):
             arrs = cntl.request_device_arrays
             for a in arrs:
                 if a.devices() != {devs[idx]}:
                     misplaced.append(f"shard {idx}: {a.devices()}")
-            cntl.response_device_arrays = [a * (idx + 1) for a in arrs]
+            cntl.response_device_arrays = [a * factor for a in arrs]
             return f"shard-{idx}".encode()
         return Shard
 
@@ -592,7 +595,8 @@ def phase_four_chips(smoke: Smoke) -> dict:
         for i in range(n):
             srv = Server(ServerOptions(enable_builtin_services=False))
             svc = Service("Mesh")
-            svc.register_method("Shard", make_shard(i))
+            svc.register_method("Shard", make_shard(i, i + 1))
+            svc.register_method("Double", make_shard(i, 2))
             srv.add_service(svc)
             servers.append(srv)
             ep = srv.start(f"ici://127.0.0.1:0#device={i}")
@@ -600,6 +604,7 @@ def phase_four_chips(smoke: Smoke) -> dict:
                           ChannelOptions(timeout_ms=120000))
             subs.append(sub)
             pch.add_sub_channel(sub)
+            reduce_ch.add_sub_channel(sub)
 
         # small integers: every product and 4-way sum is exact in bf16
         block = jax.random.randint(jax.random.PRNGKey(3), (rows, cols),
@@ -626,35 +631,42 @@ def phase_four_chips(smoke: Smoke) -> dict:
             landed.append(str(devs[i]))
         check(not misplaced, f"requests off their chip: {misplaced[:4]}")
 
-        # 2) the same channel lowered to ONE collective: the request's
-        # leading dim scatters over the shard axis (4 MB a shard), the
-        # merge is a psum on the devices
-        mesh = make_rpc_mesh(1, n, devices=devs)
-        coll = CollectiveChannel(mesh, merge="sum")
-        pch.attach_collective(coll, {("Mesh", "Shard"): lambda s: s * 2})
-        big = jnp.concatenate([block * (i + 1) for i in range(n)], axis=0)
+        # 2) an allreduce over the same connections: a 16 MB request
+        # committed to chip 0, block i (4 MB) to shard i, every shard
+        # answers its block times 2, the sum on chip 0. Once through the
+        # fan-out, then lowered to ONE collective: the same call, the
+        # same answer, no message sent
+        big = jax.device_put(
+            jnp.concatenate([block * (i + 1) for i in range(n)], axis=0),
+            devs[0])
         want = sum(block_np * (i + 1) * 2 for i in range(n))
-        plain = jax.jit(shard_map(
-            lambda s: jax.lax.psum(s * 2, SHARD_AXIS), mesh=mesh,
-            in_specs=P(SHARD_AXIS), out_specs=P()))(big)
-        calls = 3
-        for _ in range(calls):
+
+        def reduce_call(lowered: bool):
             cntl = Controller()
             cntl.request_device_arrays = [big]
-            cntl = pch.call("Mesh", "Shard", b"go", cntl=cntl)
-            check(cntl.join(120) and not cntl.failed(), cntl.error_text)
-            check(getattr(cntl, "collective_lowered", False),
-                  "call was not lowered to the collective")
+            cntl = reduce_ch.call("Mesh", "Double", b"go", cntl=cntl)
+            check(cntl.join(120) and not cntl.failed(),
+                  f"allreduce: {cntl.error_text} {cntl.sub_errors}")
+            check(getattr(cntl, "collective_lowered", False) == lowered,
+                  f"call lowered={not lowered}, wanted {lowered}")
             out = cntl.response_device_arrays[0]
-            check(len(out.devices()) == n,
-                  f"collective result on {out.devices()}")
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+            check(out.devices() == {devs[0]},
+                  f"allreduce result on {out.devices()}, wanted {devs[0]}")
             np.testing.assert_array_equal(
                 np.asarray(out).astype(np.float32), want)
-        check(pch.collective_fused == calls and
-              pch.collective_fallbacks == 0,
-              f"collective_fused={pch.collective_fused} "
-              f"fallbacks={pch.collective_fallbacks}")
+
+        reduce_call(lowered=False)
+        check(not misplaced, f"blocks off their chip: {misplaced[:4]}")
+        mesh = make_rpc_mesh(1, n, devices=devs)
+        reduce_ch.attach_collective(CollectiveChannel(mesh),
+                                    {("Mesh", "Double"): lambda s: s * 2})
+        calls = 3
+        for _ in range(calls):
+            reduce_call(lowered=True)
+        check(reduce_ch.collective_fused == calls and
+              reduce_ch.collective_fallbacks == 0,
+              f"collective_fused={reduce_ch.collective_fused} "
+              f"fallbacks={reduce_ch.collective_fallbacks}")
     finally:
         for sub in subs:
             sub.close()
@@ -670,8 +682,8 @@ def phase_four_chips(smoke: Smoke) -> dict:
           f"ring attention ran on {steps['devices']}")
     return {"responses_landed_on": landed,
             "shard_block_bytes": rows * cols * 2,
-            "collective_fused": pch.collective_fused,
-            "collective_fallbacks": pch.collective_fallbacks,
+            "collective_fused": reduce_ch.collective_fused,
+            "collective_fallbacks": reduce_ch.collective_fallbacks,
             "collective_steps": steps}
 
 

@@ -1,10 +1,14 @@
-"""ParallelChannel 8-shard allreduce (BASELINE.md's new combo-channel
-bench), shown both ways:
+"""ParallelChannel allreduce over the devices of one host (BASELINE.md's
+combo-channel bench), the same call both ways:
 
-  host path   — ParallelChannel fans one request out to 8 servers, each
-                reduces its shard, the merger sums on the host
-  device path — CollectiveChannel lowers the same dataflow to one SPMD
-                psum over the mesh (the TPU-native answer)
+  fan-out  — one server a device on ``ici://``; the stock
+             ``RowScatterMapper`` sends block i of the request's rows to
+             sub i through the device lane, every shard reduces its
+             block, the stock ``SumMerger`` adds the replies on the
+             caller's reply device
+  lowered  — ``attach_collective`` on the SAME channel: the call becomes
+             one XLA program (scatter, the shard function, ``psum``)
+             over the mesh, and no message is sent at all
 """
 
 import sys
@@ -12,69 +16,81 @@ import time
 
 sys.path.insert(0, __file__.rsplit("/examples", 1)[0])
 
-import numpy as np
+
+def shard_sum(s):
+    """What one shard computes on its block of rows (and the function
+    the lowered program runs per shard: one stable object, the compiled
+    program is cached by it)."""
+    return s.sum(axis=1)
 
 
 def main(n_shards: int = 8, dim: int = 1 << 16) -> None:
     n_shards, dim = int(n_shards), int(dim)
 
-    # ---------------- host path: 8 real servers + ParallelChannel
-    from brpc_tpu.rpc import (Channel, ParallelChannel, ResponseMerger, Server,
-                              ServerOptions, Service, SubCall, CallMapper,
-                              Controller)
-
-    servers = []
-    for i in range(n_shards):
-        s = Server(ServerOptions(enable_builtin_services=False))
-        svc = Service("Reduce")
-
-        def Sum(cntl, request, _i=i):
-            arr = np.frombuffer(request, dtype=np.float32)
-            return np.array([arr.sum()], dtype=np.float32).tobytes()
-        svc.register_method("Sum", Sum)
-        s.add_service(svc)
-        servers.append((s, s.start(f"mem://allreduce-{i}")))
-
-    class ShardMapper(CallMapper):
-        def map(self, i, n, service, method, request, cntl):
-            shard = request[i * len(request) // n: (i + 1) * len(request) // n]
-            return SubCall(service, method, shard)
-
-    pch = ParallelChannel(call_mapper=ShardMapper())
-    for _, ep in servers:
-        pch.add_sub_channel(Channel(str(ep)))
-
-    data = np.ones(dim, dtype=np.float32)
-    t0 = time.perf_counter()
-    cntl = pch.call_sync("Reduce", "Sum", data.tobytes())
-    host_ms = (time.perf_counter() - t0) * 1e3
-    total = sum(np.frombuffer(r, np.float32)[0] for r in cntl.sub_responses)
-    print(f"host ParallelChannel: sum={total:.0f} (expect {dim}) in {host_ms:.2f}ms")
-    for s, _ in servers:
-        s.stop(); s.join(2)
-
-    # ---------------- device path: one psum over the mesh
     import jax
     import jax.numpy as jnp
+
     from brpc_tpu.parallel import CollectiveChannel, make_rpc_mesh
+    from brpc_tpu.rpc import (Channel, Controller, ParallelChannel,
+                              RowScatterMapper, Server, ServerOptions,
+                              Service, SumMerger)
 
-    # the mesh shrinks to the devices there are; the result line says so
-    n_dev = min(n_shards, len(jax.devices()))
-    mesh = make_rpc_mesh(n_replicas=1, n_shards=n_dev,
-                         devices=jax.devices()[:n_dev])
-    cc = CollectiveChannel(mesh)
-    x = jnp.ones((n_dev, dim // n_dev), jnp.float32)
+    # the mesh shrinks to the devices there are; the result lines say so
+    n = min(n_shards, len(jax.devices()))
+    devices = jax.devices()[:n]
+    jitted = jax.jit(shard_sum)
 
-    def shard_sum(s):  # one stable fn: cc.call caches the compilation by it
-        return s.sum()[None]
+    servers, subs = [], []
+    pch = ParallelChannel(call_mapper=RowScatterMapper(),
+                          response_merger=SumMerger())
+    for i in range(n):
+        srv = Server(ServerOptions(enable_builtin_services=False))
+        svc = Service("Reduce")
 
-    out = cc.call(shard_sum, x, merge="sum")  # warm compile
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(cc.call(shard_sum, x, merge="sum"))
-    dev_ms = (time.perf_counter() - t0) * 1e3
-    print(f"device CollectiveChannel psum: sum={float(out[0]):.0f} in {dev_ms:.2f}ms "
-          f"on {n_dev} of {n_shards} requested {jax.devices()[0].platform} "
-          f"device(s)")
+        def Sum(cntl, request):
+            cntl.response_device_arrays = [
+                jitted(cntl.request_device_arrays[0])]
+            return b""
+        svc.register_method("Sum", Sum)
+        srv.add_service(svc)
+        ep = srv.start(f"ici://127.0.0.1:0#device={i}")
+        servers.append(srv)
+        subs.append(Channel(f"ici://127.0.0.1:{ep.port}#reply_device=0"))
+        pch.add_sub_channel(subs[-1])
+
+    # one row a shard, committed to device 0 as a caller's tensor is
+    x = jax.device_put(jnp.ones((n, dim // n), jnp.float32), devices[0])
+
+    def call():
+        cntl = Controller()
+        cntl.request_device_arrays = [x]
+        t0 = time.perf_counter()
+        cntl = pch.call("Reduce", "Sum", b"", cntl=cntl)
+        assert cntl.join(30) and not cntl.failed(), cntl.error_text
+        out = jax.block_until_ready(cntl.response_device_arrays[0])
+        return cntl, float(out[0]), (time.perf_counter() - t0) * 1e3
+
+    try:
+        call()                                  # warm: dials, compiles
+        _, total, ms = call()
+        print(f"fan-out ParallelChannel: sum={total:.0f} (expect "
+              f"{n * (dim // n)}) in {ms:.2f}ms over {n} lane RPCs")
+
+        mesh = make_rpc_mesh(n_replicas=1, n_shards=n, devices=devices)
+        pch.attach_collective(CollectiveChannel(mesh),
+                              {("Reduce", "Sum"): shard_sum})
+        call()                                  # warm: the one compile
+        cntl, total, ms = call()
+        assert cntl.collective_lowered and pch.collective_fallbacks == 0
+        print(f"lowered to one collective: sum={total:.0f} in {ms:.2f}ms "
+              f"on {n} of {n_shards} requested "
+              f"{devices[0].platform} device(s)")
+    finally:
+        for sub in subs:
+            sub.close()
+        for srv in servers:
+            srv.stop()
+            srv.join(2)
 
 
 if __name__ == "__main__":

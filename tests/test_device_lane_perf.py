@@ -342,7 +342,8 @@ class TestCollectiveLoweredParallelChannel:
     def test_device_fanout_lowers_to_one_collective(self):
         import jax.numpy as jnp
         from brpc_tpu.parallel import CollectiveChannel, make_rpc_mesh
-        from brpc_tpu.rpc.combo_channels import ParallelChannel
+        from brpc_tpu.rpc.combo_channels import (ParallelChannel,
+                                                 RowScatterMapper)
         from brpc_tpu.rpc.controller import Controller
 
         server, ep = _make_server("ici://127.0.0.1:0#device=0")
@@ -350,7 +351,11 @@ class TestCollectiveLoweredParallelChannel:
         try:
             mesh = make_rpc_mesh(n_replicas=1, n_shards=8)
             coll = CollectiveChannel(mesh, merge="concat")
-            pc = ParallelChannel()
+            # the scattering mapper with the collecting merger: the one
+            # fan-out that computes what a scatter + concat computes
+            # (the stock CallMapper sends the WHOLE array to every sub
+            # and is not lowered)
+            pc = ParallelChannel(call_mapper=RowScatterMapper())
             for _ in range(8):
                 sub = Channel(f"ici://127.0.0.1:{ep.port}")
                 subs.append(sub)
@@ -366,15 +371,28 @@ class TestCollectiveLoweredParallelChannel:
             assert not cntl.failed(), cntl.error_text
             assert getattr(cntl, "collective_lowered", False)
             assert pc.collective_fused == 1
-            np.testing.assert_allclose(
-                np.asarray(cntl.response_device_arrays[0]),
-                np.arange(16.0) * 3)
+            # block i where the fan-out's collecting merger keeps it,
+            # and nothing where it keeps nothing
+            assert cntl.response_device_arrays == []
+            for i in range(8):
+                np.testing.assert_allclose(
+                    np.asarray(cntl.sub_device_arrays[i][0]),
+                    np.arange(2 * i, 2 * i + 2) * 3.0)
 
-            # host-payload calls still fan out over every sub
-            c2 = pc.call_sync("DevSvc", "EchoDevice", b"host")
+            # an unmapped method on the same channel fans out: block i
+            # of the request reaches sub i through the lane
+            c2 = Controller()
+            c2.request_device_arrays = [jnp.arange(16.0)]
+            pc.call("DevSvc", "EchoDevice", b"", cntl=c2)
+            c2.join(10.0)
             assert not c2.failed(), c2.error_text
             assert pc.collective_fused == 1    # unchanged
+            assert pc.collective_fallbacks == 0
             assert c2.sub_responses.count(b"dev") == 8
+            for i in range(8):
+                np.testing.assert_allclose(
+                    np.asarray(c2.sub_device_arrays[i][0]),
+                    np.arange(2 * i, 2 * i + 2))
         finally:
             for s in subs:
                 s.close()
