@@ -14,7 +14,7 @@ import selectors
 import socket as pysocket
 import threading
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Hashable, Optional, Tuple
 
 from brpc_tpu.butil import thread_cpu
 from brpc_tpu.bvar.reducer import Adder, Maxer, PassiveStatus
@@ -60,6 +60,7 @@ def stall_ms_max_10s() -> float:
 
 
 _stall_var = PassiveStatus(stall_ms_max_10s)
+_quiet_wakes_var = PassiveStatus(lambda: dispatcher_quiet_wakes())
 
 
 def expose_stall_vars() -> None:
@@ -68,6 +69,7 @@ def expose_stall_vars() -> None:
     the other socket/scheduler counters."""
     nstalls.expose("dispatcher_stalls")
     _stall_var.expose("dispatcher_stall_ms_max_10s")
+    _quiet_wakes_var.expose("dispatcher_quiet_wakes")
 
 
 expose_stall_vars()
@@ -76,6 +78,13 @@ expose_stall_vars()
 def note_stall(ms: float) -> None:
     """Record an in-progress tick overrun observed by the sampler."""
     _tick_ms_max.update(ms)
+
+
+# the longest the loop sleeps in one select()
+_MAX_SLEEP_S = 0.5
+# _sleep_until while the loop sleeps with no duty's deadline in its
+# timeout: a duty armed from another thread has to wake it
+_NO_DEADLINE = float("inf")
 
 
 class EventDispatcher:
@@ -104,6 +113,20 @@ class EventDispatcher:
         # selectors snapshot their fd set per call and DO need the kick.
         self._rearm_needs_wakeup = not isinstance(
             self._selector, getattr(selectors, "EpollSelector", ()))
+        # quiet duties: key -> (deadline, callback), work a consumer
+        # owes once its deadline passes (the lane's idle ACK), run on
+        # the event thread at the end of the first tick after the
+        # deadline or, when the loop has nothing else to do, by a
+        # select() whose timeout is the nearest deadline. _duties and
+        # _duty_next (the nearest deadline) under _lock. _sleep_until,
+        # written by the loop alone: monotonic seconds at which the
+        # select() it sits in ends at the latest, 0.0 while it is not
+        # in one (it looks at the duties before it sleeps again)
+        self._duties: Dict[Hashable, Tuple[float, Callable[[], None]]] = {}
+        self._duty_next = _NO_DEADLINE
+        self._sleep_until = 0.0
+        self._loop_ident: Optional[int] = None
+        self._quiet_wakes = 0
 
     def _ensure_thread(self):
         if self._thread is None or not self._thread.is_alive():
@@ -209,13 +232,82 @@ class EventDispatcher:
                 pass
         self._wakeup()
 
+    def arm_quiet_duty(self, key: Hashable, deadline: float,
+                       callback: Callable[[], None]) -> None:
+        """Have ``callback`` run once on the event thread, at the end of
+        the first tick after ``deadline`` (time.monotonic() seconds), or
+        by a select() timeout when no fd event comes before it. One duty
+        a key: arming again replaces it. Armed from the event thread
+        (inside an fd callback) this is a dict store: the loop looks at
+        the duties before it sleeps. From another thread the loop is
+        woken only if the select() it sits in would outlast the
+        deadline."""
+        on_loop = threading.get_ident() == self._loop_ident
+        with self._lock:
+            self._duties[key] = (deadline, callback)
+            if deadline < self._duty_next:
+                self._duty_next = deadline
+            if on_loop:
+                return
+            wake = deadline < self._sleep_until
+            self._ensure_thread()     # a loop that starts now sees it
+        if wake:
+            self._wakeup()
+
+    def drop_quiet_duty(self, key: Hashable) -> None:
+        """Forget ``key``'s duty (its owner closed). A loop that sleeps
+        for its deadline wakes once for nothing."""
+        with self._lock:
+            self._duties.pop(key, None)
+
+    def _sleep_for_duties(self) -> float:
+        """The next select()'s timeout, published as _sleep_until in the
+        same hold that read the duties: an arm from another thread
+        either came first and is counted here, or reads what this
+        wrote."""
+        with self._lock:
+            now = time.monotonic()
+            timeout = min(_MAX_SLEEP_S, max(0.0, self._duty_next - now))
+            self._sleep_until = now + timeout
+        return timeout
+
+    def _run_due_duties(self) -> None:
+        now = time.monotonic()
+        if now < self._duty_next:
+            return
+        with self._lock:
+            due = [(key, cb) for key, (deadline, cb) in self._duties.items()
+                   if deadline <= now]
+            for key, _ in due:
+                del self._duties[key]
+            self._duty_next = min(
+                (deadline for deadline, _ in self._duties.values()),
+                default=_NO_DEADLINE)
+        for _, cb in due:
+            try:
+                cb()
+            except Exception:
+                import logging
+                logging.getLogger("brpc_tpu.transport").exception(
+                    "quiet duty failed")
+
     def _run(self):
         thread_cpu.set_role("dispatcher")
+        self._loop_ident = threading.get_ident()
         while not self._stop:
+            # written BEFORE the test of _duties: an arm from another
+            # thread stores its duty and then reads this, so it either
+            # stored in time for the test or sees that it has to wake us
+            self._sleep_until = _NO_DEADLINE
+            timeout = self._sleep_for_duties() if self._duties \
+                else _MAX_SLEEP_S
             try:
-                events = self._selector.select(timeout=0.5)
+                events = self._selector.select(timeout=timeout)
             except OSError:
                 continue
+            self._sleep_until = 0.0
+            if not events and timeout < _MAX_SLEEP_S:
+                self._quiet_wakes += 1
             # resolve the WHOLE event batch under one lock hold (a
             # deep wakeup used to pay one acquire/release per ready
             # fd), then fire callbacks outside the lock in event order
@@ -261,25 +353,30 @@ class EventDispatcher:
                         fired.append((fd, on_readable))
                     if on_writable is not None:
                         fired.append((fd, on_writable))
-            if not fired:
-                continue
-            self._tick_seq += 1
-            self._tick_start_ns = time.monotonic_ns()
-            try:
-                for fd, cb in fired:
-                    try:
-                        cb()
-                    except Exception:
-                        import logging
-                        logging.getLogger("brpc_tpu.transport").exception(
-                            "event callback failed for fd %d", fd)
-            finally:
-                dur_ms = (time.monotonic_ns() - self._tick_start_ns) / 1e6
-                self._tick_start_ns = 0
-                if dur_ms > 1.0:
-                    # sub-ms ticks are the normal case and not worth a
-                    # Maxer lock; anything longer feeds the stall gauge
-                    _tick_ms_max.update(dur_ms)
+            if fired:
+                self._fire(fired)
+            if self._duties:
+                self._run_due_duties()
+
+    def _fire(self, fired) -> None:
+        """One tick: this wakeup's fd callbacks, in event order."""
+        self._tick_seq += 1
+        self._tick_start_ns = time.monotonic_ns()
+        try:
+            for fd, cb in fired:
+                try:
+                    cb()
+                except Exception:
+                    import logging
+                    logging.getLogger("brpc_tpu.transport").exception(
+                        "event callback failed for fd %d", fd)
+        finally:
+            dur_ms = (time.monotonic_ns() - self._tick_start_ns) / 1e6
+            self._tick_start_ns = 0
+            if dur_ms > 1.0:
+                # sub-ms ticks are the normal case and not worth a
+                # Maxer lock; anything longer feeds the stall gauge
+                _tick_ms_max.update(dur_ms)
 
     def stop(self):
         self._stop = True
@@ -311,6 +408,13 @@ def dispatcher_ticks() -> int:
     carries it; a level-triggered fd nobody pauses shows here)."""
     d = _global
     return d._tick_seq if d is not None else 0
+
+
+def dispatcher_quiet_wakes() -> int:
+    """select() timeouts the event thread took for a quiet duty's
+    deadline (no fd event came first): the wakes the duties cost."""
+    d = _global
+    return d._quiet_wakes if d is not None else 0
 
 
 def _postfork_reset() -> None:

@@ -59,6 +59,7 @@ import logging
 import os
 import struct
 import threading
+import time
 import uuid as uuidlib
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -70,7 +71,10 @@ logger = logging.getLogger("brpc_tpu.ici")
 from brpc_tpu.butil.endpoint import EndPoint
 from brpc_tpu.butil.jax_runtime import local_device
 from brpc_tpu.transport import device_stats as _dev_stats
+from brpc_tpu.transport import syscall_stats as _syscall_stats
 from brpc_tpu.transport.base import Conn, Listener, Transport
+from brpc_tpu.transport.event_dispatcher import (global_dispatcher,
+                                                 peek_dispatcher)
 from brpc_tpu.transport.tcp import TcpConn, TcpTransport
 from brpc_tpu.transport.tpud import (_decode_device_batch,
                                      _encode_device_batch, _np_dtype)
@@ -394,12 +398,12 @@ _define_flag("ici_reclaim_grace_s", 30.0,
              "entries linger before reclaim (peer may still take them)")
 
 # --- device-lane speed-run knobs (docs/performance.md "Device lane
-# tuning"): the idle-ACK timer closes the "cells only balance after
+# tuning"): the idle ACK closes the "cells only balance after
 # close" gap, coalescing collapses bursts of tiny batches into one
 # frame/registration/reservation, and the adaptive grant lets a
 # receiver with headroom deepen the sender's pipeline.
 _define_flag("ici_idle_ack_ms", 2.0,
-             "idle-ACK timer: a conn that consumed batches but has no "
+             "idle ACK: a conn that consumed batches but has no "
              "reverse traffic sends a bare ACK after this many ms so "
              "the sender's window reopens (and its /device cells "
              "balance) without waiting for close; <=0 disables")
@@ -436,8 +440,7 @@ def _sweep_reclaim(now: Optional[float] = None) -> None:
     opportunistically from lane activity and close). Reclaimed bytes
     are counted (ici_reclaimed_bytes) so /device can show how much of
     the leaked estimate actually came back."""
-    import time as _time
-    now = _time.monotonic() if now is None else now
+    now = time.monotonic() if now is None else now
     freed = 0
     with _local_lock:
         while _reclaim_queue and _reclaim_queue[0][0] <= now:
@@ -680,7 +683,7 @@ class IciConn(Conn):
         # adaptive window: last grant the peer rode on a bare ACK
         # (0 = none yet; effective window stays the hello window)
         self._peer_grant = 0
-        # idle-ACK timer state (under _fc_lock) + lane counters
+        # idle-ACK duty pending (under _fc_lock) + lane counters
         self._idle_ack_armed = False
         self._idle_acks = 0
         self._coalesced_frames = 0
@@ -1264,12 +1267,17 @@ class IciConn(Conn):
             self._flush()
 
     def _arm_idle_ack(self) -> None:
-        """Eager-ACK timer: a quiescent conn must not leave its last
-        consumed batches un-ACKed until close (acks normally piggyback
-        on reverse traffic or fire at half-window). Armed from the take
-        path; fires once, the next take re-arms. This is what lets the
-        sender's /device cells balance WITHOUT a close(), and what
-        reopens a ping-pong sender's window inside the same RTT."""
+        """Eager ACK: a quiescent conn must not leave its last consumed
+        batches un-ACKed until close (acks normally piggyback on reverse
+        traffic or fire at half-window). Armed from the take path as a
+        quiet duty of the event dispatcher that owns this conn's fd: it
+        comes due once, ici_idle_ack_ms later, and the next take re-arms.
+        A take on the event thread (a server's request, the reply of a
+        done= call) pays a dict store and wakes nobody; the event thread
+        looks at what is due at the end of every tick and sleeps no
+        longer than the nearest deadline. This is what lets the sender's
+        /device cells balance WITHOUT a close(), and what reopens a
+        ping-pong sender's window inside the same RTT."""
         if self._closed or self._consumed <= self._acked_sent:
             return
         delay = float(_flag("ici_idle_ack_ms")) / 1000.0
@@ -1279,27 +1287,30 @@ class IciConn(Conn):
             if self._idle_ack_armed:
                 return
             self._idle_ack_armed = True
-        try:
-            from brpc_tpu.fiber.timer import global_timer
-            global_timer().schedule_after(delay, self._idle_ack_fire)
-        except Exception:
-            with self._fc_lock:
-                self._idle_ack_armed = False
+        _syscall_stats.idle_ack_armed.add(1)
+        global_dispatcher().arm_quiet_duty(
+            self, time.monotonic() + delay, self._idle_ack_due)
 
-    def _idle_ack_fire(self) -> None:
+    def _idle_ack_due(self) -> None:
+        """The duty, on the event thread: nothing if a reverse frame
+        carried the ACK meanwhile, else the bare ACK with its grant."""
         with self._fc_lock:
             self._idle_ack_armed = False
-        if self._closed or self._consumed <= self._acked_sent:
-            return          # a frame already carried the ack
+        if self._closed:
+            return
+        if self._consumed <= self._acked_sent:
+            _syscall_stats.idle_ack_carried.add(1)
+            return
         try:
             self._enqueue(("ctrl", F_ACK, self._ack_grant_payload()))
         except (BlockingIOError, ConnectionError):
             return
         self._idle_acks += 1
+        _syscall_stats.idle_ack_sent.add(1)
         try:
             self._flush()
         except Exception:
-            pass            # conn poisoned/torn down under the timer
+            pass            # conn poisoned/torn down under the duty
 
     def _sharding_for(self, target):
         if self._recv_sharding is None:
@@ -1487,6 +1498,9 @@ class IciConn(Conn):
             if self._closed:
                 return
             self._closed = True
+        d = peek_dispatcher()
+        if d is not None:
+            d.drop_quiet_duty(self)
         # best-effort flush: Socket's keep_write reported success for
         # frames that may still sit in _outq/_wirebuf behind a window
         # gate or TCP backpressure — don't silently drop them on close
@@ -1503,9 +1517,8 @@ class IciConn(Conn):
         # the un-ACKed pull-registered batches are counted (an upper
         # bound: pulled-but-unacked ones are included) at
         # /vars ici_unpulled_registrations instead of pinning silently.
-        import time as _time
         grace = _reclaim_grace_s()
-        deadline = _time.monotonic() + grace
+        deadline = time.monotonic() + grace
         queued = False
         grace_bytes = 0
         with _local_lock:

@@ -38,6 +38,14 @@ rpc_msgs = Adder()
 join_plucked = Adder()
 join_waited = Adder()
 
+# the ici:// lane's idle ACK (IciConn._arm_idle_ack stamps these): quiet
+# duties armed, duties that came due and found the ACK already carried
+# by a reverse frame, and bare ACKs a duty sent (armed - carried - sent:
+# still pending, or dropped by a close)
+idle_ack_armed = Adder()
+idle_ack_carried = Adder()
+idle_ack_sent = Adder()
+
 
 def note_rpc_messages(n: int) -> None:
     rpc_msgs.add(n)
@@ -72,7 +80,8 @@ def _io_totals() -> dict:
     nrecv, nsend, naccept, npoll = _native_counts()
     # claims of writership that sent in place / spawned a keep_write
     # fiber (socket.py imports this module, hence the late import)
-    from brpc_tpu.transport.event_dispatcher import dispatcher_ticks
+    from brpc_tpu.transport.event_dispatcher import (dispatcher_quiet_wakes,
+                                                     dispatcher_ticks)
     from brpc_tpu.transport.socket import write_mode_totals
     inplace, fibers = write_mode_totals()
     return {
@@ -85,6 +94,12 @@ def _io_totals() -> dict:
         "write_fiber_spawns": fibers,
         # wakeups of the event thread that fired a callback
         "dispatcher_ticks": dispatcher_ticks(),
+        # select() timeouts it took for a quiet duty (the lane's idle
+        # ACK), and that ACK's counters: armed, carried, sent
+        "dispatcher_quiet_wakes": dispatcher_quiet_wakes(),
+        "ici_idle_ack_armed": idle_ack_armed.get_value() or 0,
+        "ici_idle_ack_carried": idle_ack_carried.get_value() or 0,
+        "ici_idle_ack_sent": idle_ack_sent.get_value() or 0,
         "join_plucked": join_plucked.get_value() or 0,
         "join_waited": join_waited.get_value() or 0,
     }
@@ -136,6 +151,9 @@ def expose_syscall_vars() -> None:
     _ratio_var.expose("syscalls_per_rpc")
     for role, var in _role_cpu_vars.items():
         var.expose(f"thread_cpu_us_{role}")
+    idle_ack_armed.expose("ici_idle_ack_armed")
+    idle_ack_carried.expose("ici_idle_ack_carried")
+    idle_ack_sent.expose("ici_idle_ack_sent")
     interp_probe.probe_n.expose("interp_probe_n")
     interp_probe.probe_wait_us.expose("interp_probe_wait_us")
     interp_probe.probe_over_1ms.expose("interp_probe_over_1ms")

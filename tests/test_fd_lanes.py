@@ -873,6 +873,15 @@ class TestPluckProtocol:
         assert not s.pluck_preclaim()          # and it owns the socket
         hold.set()
         wire.wait_seen(b"slow")
+        # b"slow" was seen before the hold: wait for the suspended pass
+        # to end its cycle, which is what hands the reads back
+        def owed():                  # under the lock the cycle ends in
+            with s._nevent_lock:
+                return s._nevent
+        deadline = time.monotonic() + 3
+        while owed() and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert owed() == 0
         assert not s._busy_paused and not s._pluck_sticky
         wire.send(b"+next")
         wire.wait_seen(b"slow+next")
