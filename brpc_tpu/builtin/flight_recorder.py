@@ -187,7 +187,8 @@ class FlightRecorder:
         self._done: deque = deque(maxlen=16)
         self._job: Optional[_Job] = None
         self._next_cont = 0.0
-        self._annotated_tick = -1
+        self._counted_tick = -1     # the last tick counted as a stall
+        self._annotated_tick = -1   # ... and the last whose span was told
         self.started_mono = time.monotonic()
 
     # ----------------------------------------------------------- lifecycle
@@ -381,12 +382,15 @@ class FlightRecorder:
         if stall_ms < float(flag("dispatcher_stall_ms")):
             return
         seq = d._tick_seq
+        if seq != self._counted_tick:
+            self._counted_tick = seq    # an overrun counts once a tick
+            ed.nstalls.add(1)
         if seq == self._annotated_tick:
-            return                      # this overrun already flagged
-        self._annotated_tick = seq
-        ed.nstalls.add(1)
+            return                      # its span has been told already
         # name the culprit: the rpcz span of the request whose handler
-        # is monopolizing the event thread right now (inline dispatch)
+        # is monopolizing the event thread right now (inline dispatch).
+        # A tick that overran before its request had a span (a slow read
+        # or parse, a first request's imports) is told on a later pass
         t = d._thread
         if t is None or t.ident is None or _thread_current_fiber is None:
             return
@@ -399,6 +403,7 @@ class FlightRecorder:
             if span is not None and hasattr(span, "annotate"):
                 span.annotate(f"dispatcher_stall {stall_ms:.1f}ms "
                               "(handler held the event thread)")
+                self._annotated_tick = seq
         except Exception:
             pass
 

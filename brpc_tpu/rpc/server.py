@@ -365,6 +365,8 @@ class Server:
             from brpc_tpu.transport.syscall_stats import (
                 expose_syscall_vars)
             expose_syscall_vars()
+            from brpc_tpu.rpc.usercode import expose_usercode_vars
+            expose_usercode_vars()
             # the process-wide stream_* sums: same survival rule
             from brpc_tpu.rpc.stream import expose_stream_vars
             expose_stream_vars()

@@ -24,6 +24,7 @@ from brpc_tpu.protocol.tpu_std import (
 from brpc_tpu.rpc import errno_codes as berr
 from brpc_tpu.rpc.controller import Controller
 from brpc_tpu.rpc.span import recording
+from brpc_tpu.rpc.usercode import note_held, run_usercode
 
 
 _UNSET = object()
@@ -135,6 +136,19 @@ class _HopToWorker(SchedAwaitable):
         fiber.control.schedule(fiber, None)
 
 
+def _call_handler(method, cntl, request):
+    """The user's handler on the calling thread. A sync one holds that
+    thread (a fiber worker) until it returns, and how long is counted
+    (``usercode_held_us``); an async one only makes its coroutine."""
+    if method.is_coroutine:
+        return method.handler(cntl, request)
+    held0 = time.monotonic_ns()
+    try:
+        return method.handler(cntl, request)
+    finally:
+        note_held(time.monotonic_ns() - held0)
+
+
 def _track_pending(socket) -> bool:
     """Whether this socket maintains the pending_responses gate at all:
     only sockets serving a native-echo-capable server can ever enter
@@ -156,13 +170,13 @@ def _settle_pending(socket) -> None:
 # Claim ownership (the cut-through gate's correctness contract): each
 # +1 on socket.pending_responses has exactly ONE owner with a
 # try/finally settle —
-#   * counted_spawn's wrapper (queue-time claim for every spawned
-#     message, held until its coroutine completes), and
+#   * counted_spawn's and counted_run_inline's wrapper (queue-time
+#     claim for every spawned message and for a cycle's last message,
+#     processed in place, held until its coroutine completes), and
 #   * process_request_fast's claim for turbo-driven requests, settled
-#     by _drive_fast's finally (a suspended turbo handler lets the
-#     input loop continue scanning, so the claim must outlive it).
-# In-place classic processing needs NO claim: _input_async_tail awaits
-# it before the input cycle continues, so nothing can interleave.
+#     by _drive_fast's finally.
+# On both lanes a suspended handler lets the input loop continue
+# reading its connection, so the claim must outlive the suspension.
 async def process_request(proto, msg: RpcMessage, socket) -> None:
     server = socket.user_data.get("server")
     meta = msg.meta
@@ -316,6 +330,9 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
         span.received_us = arrival_us
         span.start_us = arrival_us
         span.dispatch_us = t0 // 1000
+        if current_group() is not None:
+            # spilled to a fiber worker: it has had one since dispatch
+            span.worker_us = span.dispatch_us
     else:
         span = _NULL_SPAN
         finish_span = _null_finish_span
@@ -450,6 +467,8 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
             # Async handlers stay inline: suspension converts them to a
             # normal fiber at their first real await.
             await _HopToWorker()
+            if rz:
+                span.worker_us = time.monotonic_ns() // 1000
         r = None
         if budget_ms > 0 and time.monotonic_ns() >= d["_deadline_ns"]:
             # the hop parked this request behind busy workers long
@@ -476,10 +495,9 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
                     not method.is_coroutine:
                 # blocking user code runs on the backup pthread pool;
                 # this fiber (and its worker) stays free to pump IO
-                from brpc_tpu.rpc.usercode import run_usercode
                 r = await run_usercode(method.handler, cntl, request)
             else:
-                r = method.handler(cntl, request)
+                r = _call_handler(method, cntl, request)
         if inspect.isawaitable(r):
             r = await r
         response = r
@@ -780,7 +798,7 @@ async def _drive_fast_inner(proto, socket, server, method, method_key: str,
                         "queue delay over shed budget before handler "
                         "entry (server overloaded)")
             return
-        r = method.handler(cntl, request)
+        r = _call_handler(method, cntl, request)
         if inspect.isawaitable(r):
             r = await r
         response = r

@@ -132,6 +132,10 @@ class Span:
     dispatch_us: int = 0        # dispatch context entered (queue exit)
     parse_done_us: int = 0      # request payload decoded (server) /
     #                             response payload decoded (client)
+    worker_us: int = 0          # the request's fiber first ran on a fiber
+    #                             worker (after the hop off the event
+    #                             thread, or from dispatch on where it was
+    #                             spilled to one); 0: it never did
     handler_start_us: int = 0   # user handler entered
     handler_end_us: int = 0     # user handler returned/raised
     serialized_us: int = 0      # response frame packed
@@ -214,6 +218,7 @@ class Span:
             "received_us": self.received_us,
             "dispatch_us": self.dispatch_us,
             "parse_done_us": self.parse_done_us,
+            "worker_us": self.worker_us,
             "handler_start_us": self.handler_start_us,
             "handler_end_us": self.handler_end_us,
             "serialized_us": self.serialized_us,

@@ -171,16 +171,18 @@ class InputMessenger:
 
     async def on_new_messages(self, socket: Socket):
         """The socket's input callback: parse-loop the portal, dispatch."""
-        r = self.on_new_messages_sync(socket)
-        if r is not None:
-            await r
+        self.on_new_messages_sync(socket)
 
-    def on_new_messages_sync(self, socket: Socket):
+    def on_new_messages_sync(self, socket: Socket) -> None:
         """Sync twin of on_new_messages: parses and dispatches entirely
-        on the calling context; returns a pending coroutine only when
-        the LAST message's processing is async (the caller decides how
-        to run it — Socket's sync input path run_inlines it, the async
-        wrapper above awaits it). A fully-sync cycle (the client
+        on the calling context. The LAST message is processed in place
+        (``_process_last``): its processing runs right here until it
+        first suspends, and from there it is a fiber of its own, so the
+        connection's input goes on meanwhile, as on the turbo lane (a
+        request whose sync handler holds a worker must not keep the
+        requests behind it on this connection unread:
+        input_messenger.cpp runs its last message after it has given
+        the socket's read events up). A fully-sync cycle (the client
         response path, pure stream frames) touches no coroutine or
         fiber machinery at all."""
         protocols = self.protocols()
@@ -250,7 +252,9 @@ class InputMessenger:
                     record_dispatch_batch(len(all_recs))
                     tail = proto.turbo_dispatch(all_recs, socket)
                     if not socket.input_portal:
-                        return tail
+                        if tail is not None:
+                            self._process_last(socket, proto, tail)
+                        return None
                     if tail is not None:
                         # leftover (slow) bytes still need the classic
                         # loop below; the fallback tail becomes a fiber
@@ -268,9 +272,8 @@ class InputMessenger:
             if status == PARSE_OK and not socket.input_portal:
                 record_dispatch_batch(1)
                 if not proto.process_inline(msg, socket):
-                    r = proto.process(msg, socket)
-                    if r is not None and hasattr(r, "__await__"):
-                        return r
+                    self._process_last(socket, proto,
+                                       proto.process(msg, socket))
                 return None
             if status == PARSE_NOT_ENOUGH_DATA:
                 return None
@@ -368,10 +371,17 @@ class InputMessenger:
                     (lambda p=proto, m=msg: p.process(m, socket)),
                     name=f"process_{proto.name}")
         proto, msg = msgs[-1]
-        r = proto.process(msg, socket)
-        if hasattr(r, "__await__"):
-            return r
+        self._process_last(socket, proto, proto.process(msg, socket))
         return None
+
+    def _process_last(self, socket: Socket, proto, r) -> None:
+        """``r`` is what ``process`` returned for a cycle's last message:
+        nothing where the processing was sync and is done, else its
+        coroutine, stepped here until it finishes or first suspends,
+        under its pending claim like any other queued message."""
+        if r is not None and hasattr(r, "__await__"):
+            counted_run_inline(self._control, socket, r,
+                               name=f"process_{proto.name}")
 
 
 def process_in_parse_order(socket: Socket, key: str, item,

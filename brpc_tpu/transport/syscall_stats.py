@@ -105,11 +105,32 @@ def _io_totals() -> dict:
     }
 
 
+def _worker_totals() -> dict:
+    """The worker pool and what sync handlers took of it. Late imports:
+    the scheduler and the dispatcher import bvar, which this module is
+    loaded beside; a scrape builds no TaskControl."""
+    from brpc_tpu.fiber import scheduler
+    from brpc_tpu.rpc import usercode
+    from brpc_tpu.transport.event_dispatcher import nstalls
+    control = scheduler._global_control
+    groups = control.groups if control is not None else ()
+    return {
+        **usercode.counters(),
+        "fiber_workers": len(groups),
+        "fiber_steals": sum(g.nsteals for g in groups),
+        "dispatcher_stalls": nstalls.get_value() or 0,
+    }
+
+
 def snapshot() -> dict:
     """Merged totals since process start — the bench lanes window-delta
     this around their measurement to derive per-RPC costs."""
     return {
         **_io_totals(),
+        # sync handlers' hold of their threads, and what stands ready
+        # to take a request meanwhile: usercode_held_us|runs|over_1ms,
+        # fiber_workers, fiber_steals; ticks the stall watchdog flagged
+        **_worker_totals(),
         # CPU us by thread role (cpu_us_<role>, cpu_us_python: their
         # sum), read off the live threads' clocks now; no key where the
         # host lets no thread read another's clock

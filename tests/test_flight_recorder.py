@@ -66,11 +66,22 @@ def server(flags_guard):
         return bytes(request)
 
     @svc.method()
-    async def InlineSleep(cntl, request):
+    async def InlineHold(cntl, request):
         # DELIBERATELY bad user code: an async handler that blocks
-        # synchronously — with inline processing it monopolizes the
-        # event thread, which is exactly what the watchdog must catch
-        time.sleep(float(bytes(request) or b"0.1"))
+        # synchronously, so with inline processing it monopolizes the
+        # event thread, which is what the watchdog must catch. Held
+        # until the watchdog has counted a stall beyond the count the
+        # request names (on a loaded host its thread may wait long for
+        # its turn, and only a LIVE tick can be flagged: waiting after
+        # the call is too late) and a little beyond, for the annotation
+        # that follows the count; at most the request's seconds
+        from brpc_tpu.transport.event_dispatcher import nstalls
+        limit_s, before = bytes(request).split()
+        t_end = time.monotonic() + float(limit_s)
+        while time.monotonic() < t_end and \
+                nstalls.get_value() <= int(before):
+            time.sleep(0.01)
+        time.sleep(0.3)
         return b"done"
 
     server.add_service(svc)
@@ -268,19 +279,22 @@ class TestStallWatchdog:
                 nstalls, stall_ms_max_10s)
             before = nstalls.get_value()
             ch = Channel(f"tcp://{ep.host}:{ep.port}",
-                         ChannelOptions(timeout_ms=5000))
-            c = ch.call_sync("Bench", "InlineSleep", b"0.25")
+                         ChannelOptions(timeout_ms=30000))
+            c = ch.call_sync("Bench", "InlineHold", b"20 %d" % before)
             ch.close()
             assert not c.failed(), c.error_text
-            deadline = time.monotonic() + 3.0
-            while time.monotonic() < deadline and \
-                    nstalls.get_value() == before:
-                time.sleep(0.05)
             assert nstalls.get_value() > before
             assert stall_ms_max_10s() >= 40.0
-            spans = [s for s in global_collector.recent(50)
-                     if s.method == "InlineSleep"]
-            assert spans, "InlineSleep span missing from rpcz"
+            # the server's span is submitted when its response write
+            # completes, which may be after the client has its reply
+            deadline = time.monotonic() + 10.0
+            while True:
+                spans = [s for s in global_collector.recent(50)
+                         if s.method == "InlineHold" and s.side == "server"]
+                if spans or time.monotonic() >= deadline:
+                    break
+                time.sleep(0.05)
+            assert spans, "InlineHold span missing from rpcz"
             notes = [t for s in spans for _, t in s.annotations]
             assert any("dispatcher_stall" in t for t in notes), notes
         finally:

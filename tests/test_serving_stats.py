@@ -89,6 +89,11 @@ class TestStageSpans:
             global_collector.clear()
             for i, n in enumerate((4, 24, 8, 16)):
                 assert not _gen(ch, f"p{i}", n).failed()
+            # a serving span is submitted when the lane has emitted, which
+            # on a loaded host can be a beat after the client has its reply
+            deadline = time.monotonic() + 5
+            while len(_serving_spans()) < 4 and time.monotonic() < deadline:
+                time.sleep(0.02)
             spans = _serving_spans()
             assert len(spans) >= 4, [s.side for s in
                                      global_collector.recent(50)]
