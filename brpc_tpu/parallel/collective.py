@@ -18,12 +18,16 @@ A request that lives whole on ONE device of the mesh (what a caller has
 after ``jax.device_put(x, dev)``, and what every array off the device
 lane is) is scattered INSIDE the lowered program: the caller's buffer
 is the program's argument on its own device, the other devices get a
-resident stand-in of the same shape, and block j travels to shard j as
-a ``collective-permute`` from the source, ahead of the service function
-and the merge. Nothing is copied outside the program and the whole
-call is one launch. A request anywhere else (off the mesh, spread over
-several devices, or a mesh with more than one replica) is handed to
-``NamedSharding(mesh, P('shard'))`` by ``jax.device_put`` first.
+resident stand-in of the same shape, and ONE ``all-to-all`` carries
+block j of every buffer to shard j, ahead of the service function and
+the merge: the source's blocks are one operation's transfers, the
+stand-ins' zeros travel beside them and are never read. It is a move
+and nothing else (no slice copied out first, no select after), so every
+bit pattern arrives as it was written. Nothing is copied outside the
+program and the whole call is one launch. A request anywhere else (off
+the mesh, spread over several devices, or a mesh with more than one
+replica) is handed to ``NamedSharding(mesh, P('shard'))`` by
+``jax.device_put`` first.
 """
 
 from __future__ import annotations
@@ -71,19 +75,14 @@ class CollectiveChannel:
 
         def take_block(x):
             """This shard's block of a request that lives on shard
-            ``src``: its own slice there, a collective-permute from
-            ``src`` everywhere else (a device outside a permute's one
-            pair receives zeros, which the select drops)."""
+            ``src``: ONE all-to-all in which block j of every shard's
+            buffer goes to shard j; what arrives from ``src`` is this
+            shard's, and the stand-ins' zeros beside it are never
+            read."""
             rows = x.shape[0] // n
-            me = jax.lax.axis_index(SHARD_AXIS)
-            mine = jax.lax.slice_in_dim(x, src * rows, (src + 1) * rows)
-            for j in range(n):
-                if j != src:
-                    got = jax.lax.ppermute(
-                        jax.lax.slice_in_dim(x, j * rows, (j + 1) * rows),
-                        SHARD_AXIS, perm=[(src, j)])
-                    mine = jnp.where(me == j, got, mine)
-            return mine
+            got = jax.lax.all_to_all(x, SHARD_AXIS, split_axis=0,
+                                     concat_axis=0, tiled=True)
+            return jax.lax.slice_in_dim(got, src * rows, (src + 1) * rows)
 
         def per_shard(x):
             y = service_fn(x if src is None else take_block(x))
@@ -150,6 +149,13 @@ class CollectiveChannel:
             (n * request.shape[0],) + request.shape[1:],
             NamedSharding(self.mesh, P(SHARD_AXIS)), parts)
         return placed, src
+
+    @staticmethod
+    def scatter_form(src: Optional[int]) -> str:
+        """What ``scatter`` and the program do with a request, in a few
+        words for a span's annotation: ``src`` as ``scatter`` gave it."""
+        return ("scatter by device_put" if src is None
+                else "all-to-all scatter in the program")
 
     def run(self, service_fn: Callable, placed, src: Optional[int] = None,
             merge: Optional[str] = None, name: str = "per_shard"):

@@ -308,8 +308,6 @@ class ParallelChannel:
             span = _span.start_client_span(cntl, service, method)
             span.remote_side = self._collective_peer
             span.request_size = request.nbytes
-            span.annotate(f"collective lowered: scatter + {merge} over "
-                          f"{nsub} shards, no sub call")
         tracker = device_stats.open_transfer(
             self._collective_peer, COLLECTIVE_LANE, request.nbytes)
 
@@ -334,6 +332,9 @@ class ParallelChannel:
                 tracker.lane_encoded()
             if span is not None:
                 span.write_done_us = time.monotonic_ns() // 1000
+                # which program the call runs: the scatter's form
+                span.annotate(f"collective lowered: {coll.scatter_form(src)}"
+                              f" + {merge} over {nsub} shards, no sub call")
             out = coll.run(fn, placed, src, merge,
                            name=f"collective_{service}_{method}")
             if tracker is not None:

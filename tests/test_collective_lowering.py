@@ -271,6 +271,124 @@ def test_the_lowered_program_is_named_after_service_and_method(fabric):
     assert f.collective._compiled[key]._cache_size() == 1
 
 
+# ------------------------------------------- the scatter in the program
+
+def _lowered(f, request):
+    """The one program a fabric's collective holds after a lowered call
+    of ``request``, with the argument it ran on."""
+    (key,) = f.collective._compiled
+    placed, src = f.collective.scatter(request)
+    assert src == key[3]
+    return key, f.collective._compiled[key], placed
+
+
+def _collectives(text: str, op: str) -> int:
+    """How many instructions of the compiled module are ``op`` (or its
+    async start)."""
+    return sum(1 for line in text.splitlines()
+               if f" {op}(" in line or f" {op}-start(" in line)
+
+
+@pytest.mark.parametrize("src", [0, 2])
+@pytest.mark.parametrize("merger", ["sum", "collect"])
+def test_the_scatter_is_one_collective_and_no_collective_permute(
+        fabric, merger, src):
+    """ISSUE 34: the scatter is ONE all-to-all; the three
+    collective-permutes from the source and their selects are gone,
+    for any source and for both pairs that lower."""
+    f = fabric(RowScatterMapper(), SumMerger() if merger == "sum" else None)
+    f.arm()
+    request = _request(20 + src, f.devices[src])
+    assert f.call(request).collective_lowered
+    key, fn, placed = _lowered(f, request)
+    assert key[1] == ("sum" if merger == "sum" else "concat")
+    assert key[3] == src                # scattered inside the program
+    text = fn.lower(placed).compile().as_text()
+    assert _collectives(text, "all-to-all") == 1
+    assert "collective-permute" not in text
+    assert "ragged-all-to-all" not in text
+    # the merge is what it was: one all-reduce for the sum, none for
+    # the blocks the out-spec stitches
+    assert _collectives(text, "all-reduce") == (1 if merger == "sum" else 0)
+
+
+@pytest.mark.parametrize("src", [0, 2])
+@pytest.mark.parametrize("merger", ["sum", "collect"])
+def test_one_program_a_shape_named_after_service_and_method(fabric, merger,
+                                                            src):
+    f = fabric(RowScatterMapper(), SumMerger() if merger == "sum" else None)
+    f.arm()
+    for seed in (30, 31, 32):           # same shape, same source
+        assert f.call(_request(seed, f.devices[src])).collective_lowered
+    key, fn, placed = _lowered(f, _request(30, f.devices[src]))
+    assert key[2] == "collective_Mesh_Shard"
+    assert "@jit_collective_Mesh_Shard" in fn.lower(placed).as_text()
+    assert fn._cache_size() == 1        # compiled once for the shape
+    # a second shape is a second entry of the same program, not a new one
+    assert f.call(_request(33, f.devices[src],
+                           rows=2 * N * ROWS)).collective_lowered
+    assert list(f.collective._compiled) == [key]
+    assert fn._cache_size() == 2
+
+
+# patterns a move must not touch: -0.0, +-inf, NaNs of both signs, the
+# smallest subnormal, 1.0. XLA:CPU widens a bf16 collective to float32
+# and back, whatever the collective, which keeps every value and a
+# NaN's sign and quiets its payload: the bf16 NaNs here are the two
+# quiet ones, and the float32 case, which no backend widens, carries a
+# quiet and a signalling NaN with payload bits. On the chip (builder's
+# run, PR 34; PERF.md section 6) the all-to-all brings bf16 payloads
+# through too, where a select gives 0x7FC0 for every NaN and 0 for a
+# subnormal
+_PATTERNS = {
+    "bfloat16": (np.uint16, [0x8000, 0x7F80, 0xFF80, 0x7FC0, 0xFFC0,
+                             0x0001, 0x3F80, 0xBF80]),
+    "float32": (np.uint32, [0x80000000, 0x7F800000, 0xFF800000, 0x7FC00001,
+                            0xFFA5A5A5, 0x7F800001, 0x00000001, 0x3F800000]),
+}
+
+
+@pytest.mark.parametrize("src", [0, 2])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_scatter_is_a_move_every_bit_pattern_arrives_unchanged(
+        fabric, dtype, src):
+    """Through a ``concat`` call with the identity as the shard's body:
+    block j of the request is on shard j's device with every bit
+    pattern as the caller wrote it (nothing added in from the
+    stand-ins, no other type on the wire)."""
+    import jax
+    import jax.numpy as jnp
+
+    f = fabric(RowScatterMapper(), None)
+    f.arm(fn=lambda s: s)
+    uint, patterns = _PATTERNS[dtype]
+    bits = np.resize(np.array(patterns, dtype=uint), (N * ROWS, COLS)).copy()
+    # every row its own rotation, so no two blocks are alike
+    for r in range(N * ROWS):
+        bits[r] = np.roll(bits[r], r)
+    request = jax.device_put(bits.view(jnp.dtype(dtype)), f.devices[src])
+    assert request.dtype == jnp.dtype(dtype)
+
+    def bits_of(a):     # the buffer's bytes, no device op in between
+        return np.asarray(a).view(uint)
+
+    np.testing.assert_array_equal(bits_of(request), bits)
+    cntl = f.call(request)
+    assert cntl.collective_lowered
+    for i, (block,) in enumerate(cntl.sub_device_arrays):
+        assert block.dtype == request.dtype
+        np.testing.assert_array_equal(bits_of(block),
+                                      bits[i * ROWS:(i + 1) * ROWS])
+    # and before the channel brought them to the reply device: block j
+    # is the piece the program left on shard j's own device
+    out = f.collective.call(lambda s: s, request, merge="concat")
+    for shard in out.addressable_shards:
+        j = f.devices.index(shard.device)
+        assert shard.index[0] == slice(j * ROWS, (j + 1) * ROWS)
+        np.testing.assert_array_equal(bits_of(shard.data),
+                                      bits[j * ROWS:(j + 1) * ROWS])
+
+
 # ------------------------------------------------------ what fans out
 
 class _MyScatter(RowScatterMapper):
@@ -429,6 +547,10 @@ def test_span_device_cell_and_counters_of_lowered_calls(fabric):
     assert len(span_mod.global_collector.recent(1 << 30)) \
         == ring_before + calls              # and no other span
     for s in spans:
+        # the annotation names the program's scatter (ISSUE 34)
+        assert [t for _us, t in s.annotations] == [
+            "collective lowered: all-to-all scatter in the program + sum "
+            "over 4 shards, no sub call"]
         assert s.method == "Shard" and s.request_size == request.nbytes
         assert s.remote_side.startswith("mesh://")
         assert 0 < s.start_us <= s.write_done_us <= s.dispatch_us \
