@@ -28,6 +28,7 @@ from brpc_tpu.protocol.registry import (
     PARSE_NOT_ENOUGH_DATA, PARSE_OK, PARSE_TRY_OTHERS, Protocol,
     register_protocol,
 )
+from brpc_tpu.transport import event_dispatcher as _event_dispatcher
 
 MAGIC = b"TRPC"
 HEADER_SIZE = 12
@@ -140,7 +141,7 @@ class RpcMessage:
     """One parsed tpu_std message."""
 
     __slots__ = ("meta", "payload", "attachment", "device_arrays",
-                 "arrival_ns", "device_recv")
+                 "arrival_ns", "device_recv", "wake")
 
     def __init__(self, meta: pb.RpcMeta, payload: IOBuf, attachment: IOBuf,
                  device_arrays: Optional[List] = None):
@@ -158,6 +159,14 @@ class RpcMessage:
         # (the reference stamps received_us in InputMessenger the same
         # way; pre-cut kernel/portal buffering is invisible to both)
         self.arrival_ns = time.monotonic_ns()
+        # cut inside a callback of the event loop while spans record:
+        # the tick's three stamps (event_dispatcher.wake_stamps), which
+        # the message's span carries beside the cut's own. Left unset
+        # otherwise (readers ask with getattr): a frame a plucking
+        # joiner or a fiber cut has no tick. A message the scan lane's
+        # record is rebuilt into is made in the callback that scanned it
+        if _event_dispatcher.stamping is not None:
+            self.wake = _event_dispatcher.wake_stamps()
 
 
 def cut_message(meta: pb.RpcMeta, payload: IOBuf, attachment: IOBuf,
@@ -677,6 +686,12 @@ class TpuStdProtocol(Protocol):
         if (meta.HasField("stream_settings") and not meta.HasField("request")
                 and not meta.HasField("response") and not meta.correlation_id):
             from brpc_tpu.rpc.stream import process_stream_frame
+            # processed here, in parse order, inside the input pass's
+            # cut: the event loop's sums are told (as the pass tells
+            # them of every other message's processing)
+            loop = _event_dispatcher.stamping
+            if loop is not None:
+                loop.lap(_event_dispatcher.PROCESS)
             process_stream_frame(msg, socket)
             return True
         return False

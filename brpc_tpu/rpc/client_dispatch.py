@@ -9,7 +9,8 @@ from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.protocol.tpu_std import RpcMessage, unpack_inline_device_arrays
 from brpc_tpu.rpc import errno_codes as berr
 from brpc_tpu.rpc.controller import address_call, take_call
-from brpc_tpu.rpc.span import stamp_first_byte
+from brpc_tpu.rpc.span import copy_wake, stamp_first_byte
+from brpc_tpu.transport.event_dispatcher import wake_stamps
 from brpc_tpu.transport.syscall_stats import (note_rpc_messages as
                                               _note_rpc_messages)
 
@@ -135,7 +136,10 @@ def process_response_fast(cid: int, err_code: int, err_text, payload: bytes,
     cntl.__dict__["_bs_resp_bytes"] = len(payload) + len(att)
     span = cntl.__dict__.get("_client_span")
     if span is not None:
+        # the scan lane's record has no message: its cut was in this
+        # callback, if the event loop made it
         stamp_first_byte(span, time.monotonic_ns() // 1000)
+        copy_wake(span, wake_stamps())
     try:
         cntl.response_payload = PayloadBytes(payload)
         if cntl.response_msg is not None:
@@ -214,6 +218,7 @@ def process_response(proto, msg: RpcMessage, socket) -> None:
         # response byte" the classic path has (span.h received_us)
         stamp_first_byte(span, (getattr(msg, "arrival_ns", 0)
                                 or time.monotonic_ns()) // 1000)
+        copy_wake(span, getattr(msg, "wake", None))
     try:
         _fill_response(cntl, msg, socket)
     except Exception as e:

@@ -314,7 +314,8 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
     socket.last_method = method_key
     rz = recording()
     if rz:
-        from brpc_tpu.rpc.span import finish_span, start_server_span
+        from brpc_tpu.rpc.span import (copy_wake, finish_span,
+                                       start_server_span)
         span = start_server_span(cntl, req_meta.service_name,
                                  req_meta.method_name)
         # the flight recorder's stall watchdog reaches the ACTIVE span
@@ -328,6 +329,7 @@ async def _process_request_body(proto, msg: RpcMessage, socket, server,
         # flat start/end span could never show (span.h received_us)
         arrival_us = (getattr(msg, "arrival_ns", 0) or t0) // 1000
         span.received_us = arrival_us
+        copy_wake(span, getattr(msg, "wake", None))
         span.start_us = arrival_us
         span.dispatch_us = t0 // 1000
         if current_group() is not None:

@@ -143,6 +143,18 @@ class Span:
     # Client side:
     write_done_us: int = 0      # request write completed (on_done)
     first_byte_us: int = 0      # response frame seen by the client
+    # The wake that ended in this span's frame cut (received_us on a
+    # server span or a frame's receiving half: the request's or frame's
+    # wake; first_byte_us on a client span: the response's), as the
+    # event loop stamped it (transport/event_dispatcher.py), in us: it
+    # last went to sleep, it woke with something to fire, this socket's
+    # callback began; read as wake_sleep_us, wake_tick_us,
+    # wake_callback_us. All 0: the frame was cut off the loop (a
+    # plucking joiner, a fiber's pass). ONE attribute: a thirtieth
+    # costs every span its own attribute dict (CPython keeps an
+    # instance's values inline only below 30 names), 16,384 more
+    # objects for the collector to walk with the ring full
+    wake_us: Tuple[int, int, int] = (0, 0, 0)
     # A ParallelChannel call lowered to one collective (combo_channels.
     # _maybe_collective) leaves ONE client span and no server span:
     # write_done_us = the scatter handed off, dispatch_us = the program
@@ -163,6 +175,18 @@ class Span:
 
     def annotate(self, text: str) -> None:
         self.annotations.append((time.monotonic_ns() // 1000, text))
+
+    @property
+    def wake_sleep_us(self) -> int:
+        return self.wake_us[0]
+
+    @property
+    def wake_tick_us(self) -> int:
+        return self.wake_us[1]
+
+    @property
+    def wake_callback_us(self) -> int:
+        return self.wake_us[2]
 
     @property
     def latency_us(self) -> int:
@@ -225,6 +249,9 @@ class Span:
             "flushed_us": self.flushed_us,
             "write_done_us": self.write_done_us,
             "first_byte_us": self.first_byte_us,
+            "wake_sleep_us": self.wake_sleep_us,
+            "wake_tick_us": self.wake_tick_us,
+            "wake_callback_us": self.wake_callback_us,
             "queue_us": queue_us,
             "handle_us": handle_us,
             "write_us": write_us,
@@ -674,10 +701,19 @@ def start_frame_span(service: str, stream_id: int = 0, frame_seq: int = 0,
     if msg is not None:
         half.received_us = half.start_us = \
             getattr(msg, "arrival_ns", now) // 1000
+        copy_wake(half, getattr(msg, "wake", None))
         dr = getattr(msg, "device_recv", None)
         if dr is not None:
             submit_device_recv_span(half, dr)
     return half
+
+
+def copy_wake(span: Span, wake) -> None:
+    """The event loop's three stamps of the tick that cut the span's
+    frame (``event_dispatcher.wake_stamps()`` as the message carries
+    it; None where the frame was cut off the loop), onto the span."""
+    if wake is not None:
+        span.wake_us = (wake[0] // 1000, wake[1] // 1000, wake[2] // 1000)
 
 
 def stamp_first_byte(span: Span, us: int) -> None:

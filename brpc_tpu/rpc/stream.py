@@ -40,6 +40,7 @@ from brpc_tpu.fiber.butex import Butex, WAIT_TIMEOUT
 from brpc_tpu.protocol.proto import tpu_rpc_meta_pb2 as pb
 from brpc_tpu.protocol.tpu_std import (_HDR, MAGIC, _varint, pack_message)
 from brpc_tpu.rpc import span as _span
+from brpc_tpu.transport import event_dispatcher as _event_dispatcher
 
 _stream_pool: ResourcePool = ResourcePool()
 _stream_pool.insert(None)  # stream id 0 = invalid (proto3 zero default)
@@ -515,7 +516,7 @@ class FastStreamMsg:
     faithful (fastcore.cc walk_stream_meta; pinned by
     test_stream.py::TestScannerLaneParity)."""
 
-    __slots__ = ("payload", "attachment", "device_arrays", "_ss")
+    __slots__ = ("payload", "attachment", "device_arrays", "_ss", "wake")
 
     def __init__(self, payload, attachment, sid: int, seq: int,
                  credits: int = 0, close: int = 0):
@@ -532,6 +533,9 @@ class FastStreamMsg:
         # (the scanner defers them), so this lane's is empty by contract
         self.device_arrays: list = []
         self._ss = (sid, seq, credits, close)
+        # as RpcMessage: the event loop's stamps where it cut this frame
+        if _event_dispatcher.stamping is not None:
+            self.wake = _event_dispatcher.wake_stamps()
 
     @property
     def meta(self):

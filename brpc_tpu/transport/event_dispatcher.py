@@ -1,17 +1,41 @@
 """EventDispatcher: readiness poller for fd-based transports
 (brpc/event_dispatcher.h:32 — epoll/kqueue there, selectors here).
 
-One thread runs the selector; callbacks fire on it and must be cheap —
-they schedule fibers and return (the reference's edge-trigger handlers do
-the same: StartInputEvent only bumps an atomic and maybe spawns a bthread).
-Write-readiness registrations are one-shot (epollout for blocked writers).
+One thread runs the selector and fires the callbacks on it. A socket's
+read callback is its whole input pass (``Socket._on_readable_event`` ->
+``_process_input_entry``): it reads until the fd is drained (for
+``ici://`` that pumps the lane too), cuts the frames and processes each
+message in place until it first suspends: a request up to its hop to a
+fiber worker, a response through ``_fill_response``, the completion
+hooks and a ``done=`` callback with whatever that issues. So one slow
+callback holds every other socket's input (the stall watchdog below);
+the reference's edge-trigger handlers only bump an atomic and maybe
+spawn a bthread. Write-readiness registrations are one-shot (epollout
+for blocked writers).
+
+**The tick from inside.** The loop publishes three readings of
+``time.monotonic_ns`` as plain attributes it alone writes: ``sleep_ns``
+(it last finished what it had to do and went to ``select``), ``tick_ns``
+(the first reading after ``select`` returned with something to fire)
+and, only while spans record (``rpc/span.recording()``, asked once a
+wake), ``callback_ns`` (read before each callback; 0 outside one).
+A frame cut on the loop's thread inside a callback copies the three
+(``wake_stamps``), and its span carries them next to the cut's own
+stamp. While spans record the loop also sums its wall time: asleep and
+awake (its ticks, first reading to last, and the duties that ran), and
+of awake what the input pass spent reading, cutting and processing
+(``Socket``, ``InputMessenger`` and ``tpu_std`` call ``lap``):
+``loop_sums``. The sums live on the readings the loop takes anyway (a
+tick's first and last) plus two a pass (the cut's start, the
+processing's start) and one a callback after a tick's first. Spans off,
+nothing of this reads a clock.
 """
 
 from __future__ import annotations
 
-import os
 import selectors
 import socket as pysocket
+import sys
 import threading
 import time
 from typing import Callable, Dict, Hashable, Optional, Tuple
@@ -59,8 +83,14 @@ def stall_ms_max_10s() -> float:
     return round(max(win, live), 3)
 
 
+LOOP_SUMS = ("dispatcher_loop_us", "dispatcher_awake_us",
+             "dispatcher_read_us", "dispatcher_cut_us",
+             "dispatcher_process_us")
+
 _stall_var = PassiveStatus(stall_ms_max_10s)
 _quiet_wakes_var = PassiveStatus(lambda: dispatcher_quiet_wakes())
+_loop_sum_vars = {name: PassiveStatus(lambda name=name: loop_sums()[name])
+                  for name in LOOP_SUMS}
 
 
 def expose_stall_vars() -> None:
@@ -70,6 +100,8 @@ def expose_stall_vars() -> None:
     nstalls.expose("dispatcher_stalls")
     _stall_var.expose("dispatcher_stall_ms_max_10s")
     _quiet_wakes_var.expose("dispatcher_quiet_wakes")
+    for name, var in _loop_sum_vars.items():
+        var.expose(name)
 
 
 expose_stall_vars()
@@ -78,6 +110,41 @@ expose_stall_vars()
 def note_stall(ms: float) -> None:
     """Record an in-progress tick overrun observed by the sampler."""
     _tick_ms_max.update(ms)
+
+
+# the phases of the loop's awake time that are summed (``lap``): the
+# input pass's read, cut and process; REST is a callback that has not
+# said yet what it does (a writable callback never does), and the
+# remainder of awake (those, the duties) is never stamped:
+# awake - read - cut - process
+REST, READ, CUT, PROCESS = range(4)
+
+# the dispatcher whose loop is inside a tick while spans record, else
+# None: the ONE test the cut and the input pass make with spans off
+stamping: Optional["EventDispatcher"] = None
+
+
+def _recording() -> bool:
+    """``rpc.span.recording`` once that module is loaded (rpc imports
+    this one; a process that never loaded it records no span). Looked
+    up through sys.modules so the loop's thread imports nothing."""
+    global _recording
+    fn = getattr(sys.modules.get("brpc_tpu.rpc.span"), "recording", None)
+    if fn is None:
+        return False
+    _recording = fn
+    return fn()
+
+
+def wake_stamps() -> Optional[Tuple[int, int, int]]:
+    """``(sleep_ns, tick_ns, callback_ns)`` of the tick whose callback
+    the calling thread is in, while spans record; else None: a frame a
+    plucking joiner cut on its own thread, or a fiber's pass, has no
+    tick."""
+    d = stamping
+    if d is None or threading.get_ident() != d._loop_ident:
+        return None
+    return d.sleep_ns, d.tick_ns, d.callback_ns
 
 
 # the longest the loop sleeps in one select()
@@ -106,6 +173,19 @@ class EventDispatcher:
         # watchdog annotates each overrun once
         self._tick_start_ns = 0
         self._tick_seq = 0
+        # the tick from inside (module docstring): the three stamps a
+        # cut copies, and the sums, all written by the loop alone
+        self.sleep_ns = 0
+        self.tick_ns = 0
+        self.callback_ns = 0
+        self._loop_ns = 0
+        self._awake_ns = 0
+        self._phase_ns = [0, 0, 0, 0]   # by phase; REST's is never read
+        self._phase = REST
+        self._lap_ns = 0
+        # end of the last loop iteration that was summed; 0 where spans
+        # did not record then, so its sleep is of unknown length
+        self._summed_to_ns = 0
         # epoll interest changes take effect while another thread sits
         # in epoll_wait — pause/resume need no wakeup-pipe kick there
         # (one write + one dispatcher wake per call otherwise; the
@@ -271,7 +351,7 @@ class EventDispatcher:
             self._sleep_until = now + timeout
         return timeout
 
-    def _run_due_duties(self) -> None:
+    def _run_due_duties(self, rec: bool = False) -> None:
         now = time.monotonic()
         if now < self._duty_next:
             return
@@ -290,6 +370,10 @@ class EventDispatcher:
                 import logging
                 logging.getLogger("brpc_tpu.transport").exception(
                     "quiet duty failed")
+        if rec and due:
+            # duties ran (the lane's bare ACK is a write): awake time,
+            # and the loop goes to sleep after them
+            self._sum_awake(int(now * 1e9), time.monotonic_ns())
 
     def _run(self):
         thread_cpu.set_role("dispatcher")
@@ -306,6 +390,9 @@ class EventDispatcher:
             except OSError:
                 continue
             self._sleep_until = 0.0
+            rec = _recording()
+            if not rec and self._summed_to_ns:
+                self._summed_to_ns = 0      # the next sleep: unknown
             if not events and timeout < _MAX_SLEEP_S:
                 self._quiet_wakes += 1
             # resolve the WHOLE event batch under one lock hold (a
@@ -354,16 +441,27 @@ class EventDispatcher:
                     if on_writable is not None:
                         fired.append((fd, on_writable))
             if fired:
-                self._fire(fired)
+                self._fire(fired, rec)
             if self._duties:
-                self._run_due_duties()
+                self._run_due_duties(rec)
 
-    def _fire(self, fired) -> None:
-        """One tick: this wakeup's fd callbacks, in event order."""
+    def _fire(self, fired, rec: bool = False) -> None:
+        """One tick: this wakeup's fd callbacks, in event order. ``rec``:
+        spans record, so each callback's start is stamped."""
+        global stamping
         self._tick_seq += 1
-        self._tick_start_ns = time.monotonic_ns()
+        self.tick_ns = self._tick_start_ns = now = time.monotonic_ns()
+        if rec:
+            stamping = self
         try:
             for fd, cb in fired:
+                if rec:
+                    # the tick's first callback begins with the tick;
+                    # a later one's reading also ends the lap the one
+                    # before it left open
+                    if self.callback_ns:
+                        now = time.monotonic_ns()
+                    self._begin_callback(now)
                 try:
                     cb()
                 except Exception:
@@ -371,12 +469,60 @@ class EventDispatcher:
                     logging.getLogger("brpc_tpu.transport").exception(
                         "event callback failed for fd %d", fd)
         finally:
-            dur_ms = (time.monotonic_ns() - self._tick_start_ns) / 1e6
+            # the tick's end: where no duty follows, the loop sleeps now
+            now = time.monotonic_ns()
+            if rec:
+                stamping = None
+                self._begin_callback(now)       # ends the last lap
+                self.callback_ns = 0
+                self._sum_awake(self._tick_start_ns, now)
+            self.sleep_ns = now
+            dur_ms = (now - self._tick_start_ns) / 1e6
             self._tick_start_ns = 0
             if dur_ms > 1.0:
                 # sub-ms ticks are the normal case and not worth a
                 # Maxer lock; anything longer feeds the stall gauge
                 _tick_ms_max.update(dur_ms)
+
+    def _begin_callback(self, now: int) -> None:
+        """``now`` starts a callback (or ends the tick): the lap the
+        callback before it left open ends here, on a reading the loop
+        takes anyway."""
+        # graftlint: disable=guarded-by -- the lap state is the loop's
+        # thread's alone: here it is the loop, and lap() returns at
+        # once on any other thread (an ident test the rule cannot
+        # see); plain ints, and loop_sums() reads whole values
+        self._phase_ns[self._phase] += now - self._lap_ns
+        # graftlint: disable=guarded-by -- as above: the loop's alone
+        self.callback_ns = self._lap_ns = now
+        # graftlint: disable=guarded-by -- as above: the loop's alone
+        self._phase = REST
+
+    def _sum_awake(self, start_ns: int, end_ns: int) -> None:
+        """[start_ns, end_ns] was awake time; the loop slept from the
+        end of what was summed last, where spans recorded then too (a
+        sleep entered with spans off is of unknown length)."""
+        self._awake_ns += end_ns - start_ns
+        self._loop_ns += end_ns - (self._summed_to_ns or start_ns)
+        self._summed_to_ns = self.sleep_ns = end_ns
+
+    def lap(self, phase: int) -> None:
+        """The input pass enters ``phase``: the time since the last lap
+        goes to the phase that was running, and ``phase`` runs on until
+        the next lap, the next callback or the tick's end. A callback's
+        first lap (out of REST) reads no clock: its phase began with the
+        callback; nor does a lap into the phase that runs. Only on the
+        loop's thread (a pass on a fiber worker or a
+        plucking joiner's, met while ``stamping`` is set, is not the
+        loop's time)."""
+        if phase == self._phase \
+                or threading.get_ident() != self._loop_ident:
+            return
+        if self._phase != REST:
+            now = time.monotonic_ns()
+            self._phase_ns[self._phase] += now - self._lap_ns
+            self._lap_ns = now
+        self._phase = phase
 
     def stop(self):
         self._stop = True
@@ -417,6 +563,24 @@ def dispatcher_quiet_wakes() -> int:
     return d._quiet_wakes if d is not None else 0
 
 
+def loop_sums() -> dict:
+    """The event thread's wall time in us, summed only while spans
+    record: ``dispatcher_loop_us`` (asleep + awake), ``dispatcher_awake_us``
+    (each tick from its first reading to its last, and the duties that
+    ran) and, of awake, what the input pass spent in ``_drain_readable``
+    (read: from the callback's start), in the protocol's parse or scan
+    (cut) and in ``process`` (from its start to the callback's end, or
+    to where the pass cuts or reads on). Plain ints the loop alone
+    writes."""
+    d = _global
+    if d is None:
+        return dict.fromkeys(LOOP_SUMS, 0)
+    phase = d._phase_ns
+    return dict(zip(LOOP_SUMS, (
+        d._loop_ns // 1000, d._awake_ns // 1000, phase[READ] // 1000,
+        phase[CUT] // 1000, phase[PROCESS] // 1000)))
+
+
 def _postfork_reset() -> None:
     """Fork hygiene: the dispatcher thread exists only in the parent,
     and the inherited epoll fd is the parent's kernel object — any
@@ -424,8 +588,9 @@ def _postfork_reset() -> None:
     Abandon the instance (closing only the child's fd copies; close(2)
     never mutates the shared interest list) so the first post-fork
     consumer builds a private dispatcher with its own thread."""
-    global _global, _glock, _stall_win, _stall_win_lock
+    global _global, _glock, _stall_win, _stall_win_lock, stamping
     d, _global = _global, None
+    stamping = None      # the parent's loop may have been inside a tick
     _glock = threading.Lock()
     _stall_win = None    # the Window rode the parent's sampler series
     _stall_win_lock = threading.Lock()

@@ -20,6 +20,7 @@ from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.bvar.reducer import Adder, Maxer, PassiveStatus
 from brpc_tpu.fiber import TaskControl, global_control
 from brpc_tpu.protocol.registry import PARSE_OK, PARSE_NOT_ENOUGH_DATA, PARSE_TRY_OTHERS, get_protocols
+from brpc_tpu.transport import event_dispatcher as _event_dispatcher
 from brpc_tpu.transport import syscall_stats as _syscall_stats
 from brpc_tpu.transport.socket import Socket
 
@@ -184,7 +185,15 @@ class InputMessenger:
         input_messenger.cpp runs its last message after it has given
         the socket's read events up). A fully-sync cycle (the client
         response path, pure stream frames) touches no coroutine or
-        fiber machinery at all."""
+        fiber machinery at all.
+
+        On the event loop's thread while spans record (``loop`` below)
+        the pass, which the socket entered cutting, marks where it
+        turns to processing (and back, where it cuts on) for the loop's
+        sums of its awake time; a lap into the phase that runs reads no
+        clock. A stream frame is processed inside ``process_inline``,
+        which marks it itself."""
+        loop = _event_dispatcher.stamping
         protocols = self.protocols()
         # mid-frame short-circuit: the previous cycle's parse told us
         # how many bytes the frame needs — until they're here, nothing
@@ -250,6 +259,8 @@ class InputMessenger:
                     return None
                 if all_recs:
                     record_dispatch_batch(len(all_recs))
+                    if loop is not None:
+                        loop.lap(_event_dispatcher.PROCESS)
                     tail = proto.turbo_dispatch(all_recs, socket)
                     if not socket.input_portal:
                         if tail is not None:
@@ -260,6 +271,8 @@ class InputMessenger:
                         # loop below; the fallback tail becomes a fiber
                         counted_spawn(self._control, socket, tail,
                                       "process_tpu_std")
+                    if loop is not None:
+                        loop.lap(_event_dispatcher.CUT)
         # single-message fast path: a connection already claimed by a
         # protocol, one complete frame waiting (the overwhelmingly common
         # non-pipelined case) — parse and process directly, skipping the
@@ -272,6 +285,8 @@ class InputMessenger:
             if status == PARSE_OK and not socket.input_portal:
                 record_dispatch_batch(1)
                 if not proto.process_inline(msg, socket):
+                    if loop is not None:
+                        loop.lap(_event_dispatcher.PROCESS)
                     self._process_last(socket, proto,
                                        proto.process(msg, socket))
                 return None
@@ -287,6 +302,8 @@ class InputMessenger:
         else:
             msgs = []
         while socket.input_portal:
+            if loop is not None:
+                loop.lap(_event_dispatcher.CUT)     # after a stream frame
             idx = socket.preferred_protocol
             if 0 <= idx < len(protocols):
                 # burst fast path: a protocol already claimed this
@@ -340,6 +357,8 @@ class InputMessenger:
         if not msgs:
             return None
         record_dispatch_batch(len(msgs))
+        if loop is not None:
+            loop.lap(_event_dispatcher.PROCESS)
         if len(msgs) > 1:
             # bounded run-to-completion for the burst: RESPONSE
             # messages (no user handler — pure completion work) process

@@ -37,6 +37,7 @@ from brpc_tpu.fiber import TaskControl, global_control
 from brpc_tpu.fiber.butex import Butex
 from brpc_tpu.transport.base import Conn, get_transport
 from brpc_tpu.transport import device_stats as _device_stats
+from brpc_tpu.transport import event_dispatcher as _event_dispatcher
 from brpc_tpu.transport import syscall_stats as _syscall_stats
 
 define_flag("socket_inline_process", True,
@@ -1619,8 +1620,18 @@ class Socket:
                     if not self._finish_input_cycle(pending):
                         return
                     continue
+            # the event loop while it fires callbacks and spans record:
+            # what this pass takes of its awake time is summed there.
+            # The read begins with the callback (no clock read then);
+            # the input callback starts with a cut and marks where it
+            # processes; the last phase runs to the callback's end
+            loop = _event_dispatcher.stamping
+            if loop is not None:
+                loop.lap(_event_dispatcher.READ)
             self._drain_readable()
             if self.input_portal or self.failed:
+                if loop is not None:
+                    loop.lap(_event_dispatcher.CUT)
                 r = None
                 try:
                     r = self._on_input_sync(self)

@@ -138,6 +138,23 @@ def snapshot() -> dict:
         # the probe of the wait for the interpreter: moves only while
         # spans record
         **interp_probe.snapshot(),
+        # the event thread's wall time, asleep and awake, and what the
+        # input pass took of awake (dispatcher_loop_us|awake_us|read_us|
+        # cut_us|process_us): they too move only while spans record.
+        # Beside them the always-on counts of input passes that
+        # dispatched something and of the messages they dispatched
+        **_input_pass_totals(),
+    }
+
+
+def _input_pass_totals() -> dict:
+    # late imports: the messenger imports this module
+    from brpc_tpu.transport import input_messenger
+    from brpc_tpu.transport.event_dispatcher import loop_sums
+    return {
+        **loop_sums(),
+        "dispatch_batches": input_messenger._batch_cycles.get_value() or 0,
+        "dispatch_batch_msgs": input_messenger._batch_msgs.get_value() or 0,
     }
 
 
