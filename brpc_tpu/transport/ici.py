@@ -110,6 +110,61 @@ def _jax():
     return _jax_mod
 
 
+_d2d_put_fn = False      # unresolved; None: the public call copies
+
+
+def _d2d_put(probe_from, probe_to):
+    """The copy of one single-device jax.Array onto another device with
+    jax.device_put's Python layers taken off: for such an array
+    ``jax.device_put(x, device)`` comes down to
+    ``pxla.batched_device_put(x.aval, SingleDeviceSharding(device), [x],
+    [device])`` (jax/_src/dispatch.py, _device_put_sharding_impl), and
+    what lies above it (the pytree flatten, the primitive's bind, the
+    abstractify, a new sharding object a call) costs more on a slow
+    host than the launch of the copy itself: the take runs once a
+    frame on the thread that cuts it, on four chips the one event
+    thread (PERF.md section 6, PR 36). The name is private to jax, so
+    it is resolved once and PROVED before it is trusted: a few numbers
+    go from ``probe_from`` to ``probe_to`` (the first real copy's own
+    two devices) through either call, and unless the private call gives what the
+    public one gives (values, dtype, committed sharding, device) this
+    returns None for good and every take makes the public call. Which
+    of the two copied is counted: ``ici_d2d_copies_direct|public``."""
+    global _d2d_put_fn
+    if _d2d_put_fn is False:
+        _d2d_put_fn = _proved_d2d_put(probe_from, probe_to)
+    return _d2d_put_fn
+
+
+def _private_batched_put():
+    from jax._src.interpreters.pxla import batched_device_put
+    return batched_device_put
+
+
+def _proved_d2d_put(src, dst):
+    import numpy as np
+    jax = _jax()
+    try:
+        batched_device_put = _private_batched_put()
+        x = jax.device_put(np.arange(8, dtype=np.float32), src)
+        want = jax.device_put(x, dst)
+        got = batched_device_put(
+            x.aval, jax.sharding.SingleDeviceSharding(dst), [x], [dst])
+        same = (type(got) is type(want) and got.committed
+                and got.sharding == want.sharding
+                and got.devices() == want.devices() == {dst}
+                and got.dtype == want.dtype and got.shape == want.shape
+                and np.array_equal(np.asarray(got), np.asarray(want)))
+    except Exception:
+        same = False
+    if not same:
+        logging.getLogger("brpc_tpu.transport").warning(
+            "ici: jax %s has no usable pxla.batched_device_put; device "
+            "batches are copied through jax.device_put", jax.__version__)
+        return None
+    return batched_device_put
+
+
 def _stager():
     """The process-wide pinned H2D stager (plain device_put when the
     native pinned arena or jax transfer runtime is absent)."""
@@ -1320,8 +1375,8 @@ class IciConn(Conn):
 
     def _take_local(self, uid: int, target) -> list:
         """Same-process take: pop the exchange entry, credit a grace-
-        queued uid as DELIVERED, and device_put (the D2D/ICI hop)."""
-        jax = _jax()
+        queued uid as DELIVERED, and copy what is not on ``target`` yet
+        onto it (the D2D/ICI hop)."""
         with _local_lock:
             arrays = _local_exchange.pop(uid, None)
             # a grace-queued entry (sender closed) that the peer
@@ -1336,8 +1391,24 @@ class IciConn(Conn):
                 "ici: same-process batch no longer available "
                 "(sender closed and its registration was "
                 "reclaimed)")
-        return [a if (hasattr(a, "devices") and target in a.devices())
-                else jax.device_put(a, target) for a in arrays]
+        out = []
+        for a in arrays:
+            devs = a.devices() if hasattr(a, "devices") else ()
+            out.append(a if target in devs
+                       else self._copy_onto(a, devs, target))
+        return out
+
+    def _copy_onto(self, a, devs, target):
+        """One array, now on ``devs``, onto ``target``: ``_d2d_put`` for
+        a single-device jax.Array, the public call for anything else."""
+        if len(devs) == 1:
+            put = _d2d_put(next(iter(devs)), target)
+            if put is not None:
+                _syscall_stats.d2d_copies_direct.add(1)
+                return put(a.aval, self._sharding_for(target), [a],
+                           [target])
+        _syscall_stats.d2d_copies_public.add(1)
+        return _jax().device_put(a, target)
 
     def _pull_arrays(self, uid: int, specs: List[dict], target) -> list:
         """Cross-process take: PjRt pull straight onto our device."""

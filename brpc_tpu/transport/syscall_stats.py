@@ -46,6 +46,12 @@ idle_ack_armed = Adder()
 idle_ack_carried = Adder()
 idle_ack_sent = Adder()
 
+# arrays the ici:// lane copied from another device of this process onto
+# the receiving one, by the call that copied: jax's own batched put,
+# proved once against the public call (ici._d2d_put), or jax.device_put
+d2d_copies_direct = Adder()
+d2d_copies_public = Adder()
+
 
 def note_rpc_messages(n: int) -> None:
     rpc_msgs.add(n)
@@ -100,6 +106,8 @@ def _io_totals() -> dict:
         "ici_idle_ack_armed": idle_ack_armed.get_value() or 0,
         "ici_idle_ack_carried": idle_ack_carried.get_value() or 0,
         "ici_idle_ack_sent": idle_ack_sent.get_value() or 0,
+        "ici_d2d_copies_direct": d2d_copies_direct.get_value() or 0,
+        "ici_d2d_copies_public": d2d_copies_public.get_value() or 0,
         "join_plucked": join_plucked.get_value() or 0,
         "join_waited": join_waited.get_value() or 0,
     }
@@ -192,6 +200,8 @@ def expose_syscall_vars() -> None:
     idle_ack_armed.expose("ici_idle_ack_armed")
     idle_ack_carried.expose("ici_idle_ack_carried")
     idle_ack_sent.expose("ici_idle_ack_sent")
+    d2d_copies_direct.expose("ici_d2d_copies_direct")
+    d2d_copies_public.expose("ici_d2d_copies_public")
     interp_probe.probe_n.expose("interp_probe_n")
     interp_probe.probe_wait_us.expose("interp_probe_wait_us")
     interp_probe.probe_over_1ms.expose("interp_probe_over_1ms")
