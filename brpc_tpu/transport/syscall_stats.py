@@ -52,6 +52,18 @@ idle_ack_sent = Adder()
 d2d_copies_direct = Adder()
 d2d_copies_public = Adder()
 
+# the tpud:// lane's staged batches (TpudConn stamps these), each way:
+# batches and their encoded bytes; us inside the encode (the wait for
+# the device, D2H, tobytes, join), the decode and the device_put calls;
+# frames the full out-buffer refused; device_puts that raised (the
+# connection then fails: never numpy handed over in silence)
+TPUD_COUNTERS = ("tpud_batches_out", "tpud_batches_in", "tpud_bytes_out",
+                 "tpud_bytes_in", "tpud_encode_us", "tpud_decode_us",
+                 "tpud_put_us", "tpud_out_full", "tpud_put_fallbacks")
+(tpud_batches_out, tpud_batches_in, tpud_bytes_out, tpud_bytes_in,
+ tpud_encode_us, tpud_decode_us, tpud_put_us, tpud_out_full,
+ tpud_put_fallbacks) = _tpud_adders = tuple(Adder() for _ in TPUD_COUNTERS)
+
 
 def note_rpc_messages(n: int) -> None:
     rpc_msgs.add(n)
@@ -108,6 +120,8 @@ def _io_totals() -> dict:
         "ici_idle_ack_sent": idle_ack_sent.get_value() or 0,
         "ici_d2d_copies_direct": d2d_copies_direct.get_value() or 0,
         "ici_d2d_copies_public": d2d_copies_public.get_value() or 0,
+        **{name: var.get_value() or 0
+           for name, var in zip(TPUD_COUNTERS, _tpud_adders)},
         "join_plucked": join_plucked.get_value() or 0,
         "join_waited": join_waited.get_value() or 0,
     }
@@ -202,6 +216,8 @@ def expose_syscall_vars() -> None:
     idle_ack_sent.expose("ici_idle_ack_sent")
     d2d_copies_direct.expose("ici_d2d_copies_direct")
     d2d_copies_public.expose("ici_d2d_copies_public")
+    for name, var in zip(TPUD_COUNTERS, _tpud_adders):
+        var.expose(name)
     interp_probe.probe_n.expose("interp_probe_n")
     interp_probe.probe_wait_us.expose("interp_probe_wait_us")
     interp_probe.probe_over_1ms.expose("interp_probe_over_1ms")

@@ -11,10 +11,17 @@ One TCP stream carries enveloped frames:
                               local device count)
 
 Ordering on the single stream guarantees the lane batch a message refers
-to is decoded before the message bytes reach the parser (the sender
+to has arrived before the message bytes reach the parser (the sender
 writes lane-then-frame, exactly like the in-process tpu:// transport).
-Received arrays are materialized with ``jax.device_put`` onto this
-host's target device at take time."""
+A received batch is decoded and, in a process that has loaded jax,
+``jax.device_put`` onto this host's target device at take time, inside
+the take the Socket times (``recv_us_sum`` of the ``staged-dcn`` cell).
+A process that never loaded jax (a host-only client) gets numpy arrays:
+there that is the contract. With jax loaded a ``device_put`` that raises
+is counted (``tpud_put_fallbacks``) and raised, which fails the
+connection: a handler never gets numpy in a device array's place
+unnoticed (docs/tpu_transport.md has the copy chain and the counters).
+"""
 
 from __future__ import annotations
 
@@ -22,12 +29,14 @@ import json
 import struct
 import sys
 import threading
+import time
 from collections import deque
-from typing import Callable, Deque, List, Optional
+from typing import Callable, Deque, List, Optional, Tuple
 
 import numpy as np
 
 from brpc_tpu.butil.endpoint import EndPoint, str2endpoint
+from brpc_tpu.transport import syscall_stats as _stats
 from brpc_tpu.transport.base import Conn, Listener, Transport
 from brpc_tpu.transport.tcp import TcpConn, TcpTransport
 
@@ -97,40 +106,104 @@ def _decode_device_batch(data: bytes) -> List[np.ndarray]:
 
 
 class TpudConn(Conn):
+    """One tpud:// connection. What it says of itself under
+    ``transport/base.py::Conn``, name by name:
+
+    - ``supports_device_lane``, ``supports_device_tracker``: batches go
+      out of band as type-1 frames, and ``write_device_payload`` takes
+      the batch's tracker: ``stage`` ends when the batch is encoded (the
+      wait for the device, D2H, encode), ``wire`` when TCP has taken the
+      frame's last byte; no peer ACK exists, so ``ack`` is 0.
+    - NOT ``inline_write_ok`` and NOT ``flush`` with ``write(mv,
+      flush=False)``, though it could (``write`` only appends to
+      ``_out`` and pushes what TCP takes now): built and measured in
+      PR 37, the gathered write in the claiming context LOST 32% of the
+      rate at 2 MB a batch on the chip (PERF.md section 6). A batch
+      larger than TCP's buffer leaves a remainder in ``_out``, and with
+      no writer fiber to wait for the writable event it was pushed,
+      copies and all, by the event thread, which is the thread that
+      reads. So every claim of writership spawns a ``keep_write`` fiber
+      that waits and pushes on a worker, as before.
+    - ``level_triggered``, ``pause_read_events``, ``resume_read_events``:
+      the inner TCP conn's own answers, handed over: its fd is this
+      conn's event source. ``peek_closed``: the inner conn's, and
+      nothing read but undelivered.
+    - NOT ``stream_fd``: the fd's bytes are enveloped frames, not the
+      application's stream. NOT ``pluck_fd``: nothing is known against
+      it (``read_into`` pumps to EAGAIN and hands all of ``_appbuf``
+      over, as ici:// does), but no test and no chip run has plucked a
+      tpud conn and a joiner would then decode MB batches on the
+      caller's thread: it waits for a measurement. So
+      ``awaits_peer_frame`` (read only beside a pluck) stays off too.
+    - NOT ``short_read_drained``: ``read_into`` hands de-enveloped bytes
+      over, a short read says nothing of the kernel. NOT ``writev`` /
+      ``read_into_v``: every byte has to pass the envelope. NOT
+      ``read_chunks`` / ``pending_bytes`` / ``drain_all_reads``: no
+      notification a write (mem:// alone has one)."""
+
     supports_device_lane = True
+    supports_device_tracker = True
     lane_kind = "staged-dcn"     # /device cell label (device_stats)
 
     def __init__(self, inner: TcpConn, local: EndPoint, remote: EndPoint,
                  device_ordinal: Optional[int]):
         self._inner = inner
+        self.level_triggered = inner.level_triggered
+        self.pause_read_events = inner.pause_read_events
+        self.resume_read_events = inner.resume_read_events
         self._local = local
         self._remote = remote
         self._device_ordinal = device_ordinal
         self._lock = threading.Lock()
         self._flush_lock = threading.Lock()   # single-flight TCP pushes
+        # one pump at a time, and peek_closed looks at what a pump in
+        # flight has read only after it ended
+        self._pump_lock = threading.Lock()
         self._out = bytearray()            # staged enveloped output
+        # flush-stamp bookkeeping (under _lock): bytes TCP has taken, and
+        # (end offset in the stream, tracker) of every device frame whose
+        # last byte TCP has not taken yet
+        self._written = 0
+        self._marks: Deque[Tuple[int, object]] = deque()
         self._inbuf = bytearray()          # raw inbound, pre-envelope
         self._appbuf = bytearray()         # de-enveloped app bytes
-        self._lane: Deque[List] = deque()
+        self._lane: Deque[bytes] = deque()  # inbound batches, encoded
         self._closed_read = False
+        self._closed = False
+        # the device inbound batches are put on, resolved at the first
+        # take of a process that has jax
+        self._recv_dev = None
         self.peer_info: Optional[dict] = None
         self._send_frame(_F_HELLO, _hello_payload(device_ordinal))
 
     # ----------------------------------------------------------- outbound
-    def _send_frame(self, ftype: int, payload: bytes) -> None:
+    def _out_full(self) -> bool:
+        if len(self._out) > _MAX_OUT:
+            _stats.tpud_out_full.add(1)
+            return True
+        return False
+
+    def _send_frame(self, ftype: int, payload: bytes, flush: bool = True,
+                    tracker=None) -> None:
         with self._lock:
-            if len(self._out) > _MAX_OUT:
-                raise BlockingIOError("tpud out-buffer full")
+            if self._closed:
+                raise ConnectionError("tpud conn closed")
             self._out += _HDR.pack(ftype, len(payload))
             self._out += payload
-        self._flush()
+            if tracker is not None:
+                self._marks.append((self._written + len(self._out), tracker))
+        if flush:
+            self._flush()
 
     def _flush(self) -> bool:
         """Push staged bytes into the TCP socket; True if fully drained.
-        Single-flight: two concurrent flushers would snapshot and send
-        the same prefix twice, corrupting the stream."""
-        with self._flush_lock:
-            while True:
+        Single-flight a chunk: two concurrent flushers would snapshot
+        and send the same prefix twice, corrupting the stream. The
+        trackers of the batches a chunk completed are stamped with no
+        lock of this conn held (their cell's lock is a leaf)."""
+        while True:
+            sent = []
+            with self._flush_lock:
                 with self._lock:
                     if not self._out:
                         return True
@@ -142,50 +215,81 @@ class TpudConn(Conn):
                     return False
                 with self._lock:
                     del self._out[:n]
+                    self._written += n
+                    while self._marks and \
+                            self._marks[0][0] <= self._written:
+                        sent.append(self._marks.popleft()[1])
+            for tracker in sent:
+                # TCP has this batch's last byte: wire ends, and with
+                # no peer ACK the transfer completes here
+                tracker.lane_flushed()
+                tracker.lane_acked()
 
     def write(self, mv: memoryview) -> int:
         # accept the whole chunk into the envelope buffer (bounded by
         # _MAX_OUT); partial TCP writes must never split our framing
+        if self._out_full():
+            raise BlockingIOError("tpud out-buffer full")
         data = bytes(mv)
         self._send_frame(_F_BYTES, data)
         return len(data)
 
-    def write_device_payload(self, arrays) -> bool:
-        self._send_frame(_F_DEVICE, _encode_device_batch(arrays))
+    def write_device_payload(self, arrays, tracker=None,
+                             flush: bool = True) -> bool:
+        """Stage a batch: wait for the device, copy to the host, encode,
+        append, push (``flush`` False leaves the push to a later
+        write). ``tracker``: the batch's device_stats timeline (or
+        None). A full out-buffer raises BlockingIOError BEFORE anything
+        is staged, with the tracker still open: the Socket settles it,
+        fails that call and keeps its envelope home, so no batch is left
+        without its envelope."""
+        if self._out_full():
+            raise BlockingIOError("tpud out-buffer full")
+        t0 = time.monotonic_ns()
+        payload = _encode_device_batch(arrays)
+        _stats.tpud_encode_us.add((time.monotonic_ns() - t0) // 1000)
+        if tracker is not None:
+            tracker.lane_encoded()
+        self._send_frame(_F_DEVICE, payload, flush, tracker)
+        _stats.tpud_batches_out.add(1)
+        _stats.tpud_bytes_out.add(len(payload))
         return True
 
     # ------------------------------------------------------------ inbound
     def _pump(self) -> None:
         """Drain the TCP socket and de-envelope complete frames."""
-        buf = bytearray(256 << 10)
-        while True:
-            try:
-                n = self._inner.read_into(memoryview(buf))
-            except BlockingIOError:
-                break
-            if n == 0:
-                self._closed_read = True
-                break
-            self._inbuf += buf[:n]
-        while len(self._inbuf) >= _HDR.size:
-            ftype, length = _HDR.unpack_from(self._inbuf, 0)
-            if length > _MAX_FRAME:
-                raise ConnectionError(f"tpud frame of {length}B exceeds max")
-            if len(self._inbuf) < _HDR.size + length:
-                break
-            payload = bytes(self._inbuf[_HDR.size:_HDR.size + length])
-            del self._inbuf[:_HDR.size + length]
-            if ftype == _F_BYTES:
-                self._appbuf += payload
-            elif ftype == _F_DEVICE:
-                self._lane.append(_decode_device_batch(payload))
-            elif ftype == _F_HELLO:
+        with self._pump_lock:
+            buf = bytearray(256 << 10)
+            while True:
                 try:
-                    self.peer_info = json.loads(payload.decode())
-                except ValueError:
-                    raise ConnectionError("tpud: bad hello")
-            else:
-                raise ConnectionError(f"tpud: unknown frame type {ftype}")
+                    n = self._inner.read_into(memoryview(buf))
+                except BlockingIOError:
+                    break
+                if n == 0:
+                    self._closed_read = True
+                    break
+                self._inbuf += buf[:n]
+            while len(self._inbuf) >= _HDR.size:
+                ftype, length = _HDR.unpack_from(self._inbuf, 0)
+                if length > _MAX_FRAME:
+                    raise ConnectionError(
+                        f"tpud frame of {length}B exceeds max")
+                if len(self._inbuf) < _HDR.size + length:
+                    break
+                payload = bytes(self._inbuf[_HDR.size:_HDR.size + length])
+                del self._inbuf[:_HDR.size + length]
+                if ftype == _F_BYTES:
+                    self._appbuf += payload
+                elif ftype == _F_DEVICE:
+                    # decoded at the take, which the Socket times
+                    self._lane.append(payload)
+                elif ftype == _F_HELLO:
+                    try:
+                        self.peer_info = json.loads(payload.decode())
+                    except ValueError:
+                        raise ConnectionError("tpud: bad hello")
+                else:
+                    raise ConnectionError(f"tpud: unknown frame type {ftype}")
 
     def read_into(self, mv: memoryview) -> int:
         self._pump()
@@ -200,26 +304,50 @@ class TpudConn(Conn):
 
     def take_device_payload(self):
         # no TCP pump: the lane frame precedes its message's byte frames,
-        # so the batch is already decoded by the time the parser asks for
-        # it — and pumping from the parse path would consume the readable
+        # so the batch has arrived by the time the parser asks for it —
+        # and pumping from the parse path would consume the readable
         # edge while leaving de-enveloped bytes nobody ever processes
         if not self._lane:
             return None
-        batch = self._lane.popleft()
+        payload = self._lane.popleft()
+        t0 = time.monotonic_ns()
+        batch = _decode_device_batch(payload)
+        t1 = time.monotonic_ns()
+        _stats.tpud_batches_in.add(1)
+        _stats.tpud_bytes_in.add(len(payload))
+        _stats.tpud_decode_us.add((t1 - t0) // 1000)
         jax = sys.modules.get("jax")
         if jax is None:
-            return batch                    # numpy-only consumer
+            return batch        # a process without jax: numpy, by contract
         try:
-            devs = jax.devices()
-            target = devs[self._device_ordinal or 0] \
-                if (self._device_ordinal or 0) < len(devs) else devs[0]
-            return [jax.device_put(a, target) for a in batch]
+            target = self._recv_dev
+            if target is None:
+                devs = jax.devices()
+                ordinal = self._device_ordinal or 0
+                target = self._recv_dev = \
+                    devs[ordinal] if ordinal < len(devs) else devs[0]
+            out = [jax.device_put(a, target) for a in batch]
         except Exception:
-            return batch
+            # never numpy in a device array's place unnoticed: counted,
+            # and the raise fails the connection (Socket._input_error)
+            _stats.tpud_put_fallbacks.add(1)
+            raise
+        _stats.tpud_put_us.add((time.monotonic_ns() - t1) // 1000)
+        return out
 
     # ----------------------------------------------------------- plumbing
     def close(self) -> None:
+        with self._lock:
+            if self._closed:
+                return
+            self._closed = True
+            unsent = [m[1] for m in self._marks]
+            self._marks.clear()
         self._inner.close()
+        for tracker in unsent:
+            # staged and never wholly taken by TCP: the cell must still
+            # balance
+            tracker.lane_failed("tpud conn closed before the batch left")
 
     def start_events(self, on_readable: Callable[[], None],
                      on_writable: Callable[[], None]) -> None:
@@ -233,10 +361,14 @@ class TpudConn(Conn):
     def request_writable_event(self) -> None:
         self._inner.request_writable_event()
 
-    def resume_read_events(self) -> None:
-        resume = self._inner.resume_read_events
-        if resume is not None:
-            resume()
+    def peek_closed(self) -> bool:
+        """True only when the peer's FIN has arrived, the kernel holds
+        no byte more and nothing this conn has read waits to be
+        delivered (a pump in flight ends first: its lock)."""
+        if not self._inner.peek_closed():
+            return False
+        with self._pump_lock:
+            return not (self._inbuf or self._appbuf or self._lane)
 
     @property
     def local_endpoint(self):

@@ -7,13 +7,24 @@ from brpc_tpu.bvar import (
     Percentile, PerSecond, Sampler, Status, Window,
     dump_exposed, dump_prometheus, unexpose_all,
 )
+from brpc_tpu.bvar.variable import dump_exposed_variables
+# these expose at import, some only at their first use deep in a test:
+# loaded here, the fixture's snapshot below holds their variables
+import brpc_tpu.rpc.usercode  # noqa: F401
+import brpc_tpu.transport.event_dispatcher  # noqa: F401
 
 
 @pytest.fixture(autouse=True)
 def clean_registry():
+    # what the process had exposed before (at import, by an earlier
+    # Server) comes back afterwards: later files of one xdist worker
+    # assert on it (tests/test_longtail_dispatch.py)
+    before = dump_exposed_variables()
     unexpose_all()
     yield
     unexpose_all()
+    for name, var in before:
+        var.expose(name)
 
 
 class TestReducers:

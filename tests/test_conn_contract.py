@@ -14,6 +14,7 @@ import os
 import re
 import socket as pysocket
 import ssl as pyssl
+import time
 
 import pytest
 
@@ -170,6 +171,55 @@ def test_ici_conn_hands_over_its_inner_conns_answer(name, live):
     # and the two it must NOT hand over (PERF.md section 6, PR 27)
     assert conn.stream_fd is None and inner.stream_fd is not None
     assert conn.short_read_drained is False and inner.short_read_drained
+
+
+@pytest.mark.parametrize("name", ["level_triggered", "pause_read_events",
+                                  "resume_read_events"])
+def test_tpud_conn_hands_over_its_inner_conns_answer(name, live):
+    """The fd under a tpud:// conn is its TCP conn's: what that says of
+    it as an event source, this conn says (without it a busy period
+    with data arriving re-fires the level-triggered fd for its whole
+    length, and nothing pauses it)."""
+    conn = live("TpudConn")
+    inner = conn._inner
+    assert isinstance(inner, TcpConn)
+    mine, its = getattr(conn, name), getattr(inner, name)
+    assert mine == its and mine is not None
+
+
+def test_tpud_conn_declares_what_is_true_of_it_and_no_more(live):
+    """(b2) Flag by flag and method by method: the tracker it takes,
+    the fd it may not hand out (its bytes are enveloped and buffered
+    above it), and the gathered write it could take and does not (it
+    lost a third of the rate at 2 MB a batch: PERF.md section 6,
+    PR 37)."""
+    conn = live("TpudConn")
+    assert conn.supports_device_lane and conn.supports_device_tracker
+    assert conn.lane_kind == "staged-dcn"
+    assert callable(conn.take_device_payload)
+    assert {"tracker", "flush"} <= set(
+        inspect.signature(conn.write_device_payload).parameters)
+    assert conn.inline_write_ok is False and conn.flush is None
+    for name in ("stream_fd", "pluck_fd", "awaits_peer_frame", "writev",
+                 "read_into_v", "read_chunks", "pending_bytes"):
+        assert getattr(conn, name) is None, name
+    assert conn.short_read_drained is False and conn.drain_all_reads is False
+    assert conn._inner.stream_fd is not None and conn._inner.pluck_fd
+    assert conn.write(memoryview(b"abc")) == 3
+    # peek_closed: the inner conn's FIN probe, and nothing undelivered
+    assert conn.peek_closed() is False
+    other, far = _tpud()
+    try:
+        far[0].close()
+        for _ in range(500):
+            if other.peek_closed():
+                break
+            time.sleep(0.01)
+        assert other.peek_closed() is True
+        other._appbuf += b"read, not yet delivered"
+        assert other.peek_closed() is False
+    finally:
+        other.close()
 
 
 def test_chaos_conn_lacks_writev_and_passes_the_read_side(live):

@@ -13,6 +13,11 @@ import pytest
 
 from brpc_tpu.bvar import (Adder, LatencyRecorder, Maxer, PassiveStatus,
                            unexpose_all)
+from brpc_tpu.bvar.variable import dump_exposed_variables
+# these expose at import, some only at their first use deep in a test:
+# loaded here, the fixture's snapshot below holds their variables
+import brpc_tpu.rpc.usercode  # noqa: F401
+import brpc_tpu.transport.event_dispatcher  # noqa: F401
 from brpc_tpu.bvar.anomaly import AnomalyWatchdog, global_watchdog
 from brpc_tpu.bvar.series import (SEC_BUCKETS, SeriesCollector,
                                   global_series, merge_timeline_states,
@@ -22,7 +27,10 @@ from brpc_tpu.bvar.series import (SEC_BUCKETS, SeriesCollector,
 @pytest.fixture(autouse=True)
 def _fresh_series(monkeypatch):
     """Every test starts with an empty ring registry and watchdog and
-    leaves nothing exposed behind (the unexpose_all discipline). The
+    leaves nothing of its own exposed behind (the unexpose_all
+    discipline); what the process had exposed before (at import, by an
+    earlier Server) comes back afterwards, because later files of one
+    xdist worker assert on it (tests/test_longtail_dispatch.py). The
     GLOBAL sampler thread (alive in a full-suite process from earlier
     server tests) is unhooked from the series engine for the test's
     duration — a real-clock tick landing between a manual wall_t tick
@@ -31,6 +39,7 @@ def _fresh_series(monkeypatch):
     from brpc_tpu.bvar import window as _window
     monkeypatch.setattr(_window, "series_sample_tick",
                         lambda *a, **k: None)
+    before = dump_exposed_variables()
     unexpose_all()
     global_series().reset()
     global_watchdog().reset()
@@ -38,6 +47,8 @@ def _fresh_series(monkeypatch):
     unexpose_all()
     global_series().reset()
     global_watchdog().reset()
+    for name, var in before:
+        var.expose(name)
 
 
 def _ticks(n, start=1000):
