@@ -54,15 +54,21 @@ d2d_copies_public = Adder()
 
 # the tpud:// lane's staged batches (TpudConn stamps these), each way:
 # batches and their encoded bytes; us inside the encode (the wait for
-# the device, D2H, tobytes, join), the decode and the device_put calls;
+# the device, D2H, the frame's fields), the decode and the device_put calls;
 # frames the full out-buffer refused; device_puts that raised (the
-# connection then fails: never numpy handed over in silence)
+# connection then fails: never numpy handed over in silence); batch
+# bytes the conn copied in user space all the same: out, the snapshots
+# of writeable (or non-contiguous) arrays; in, a frame's head that the
+# read of its header had already taken into the conn's scratch
 TPUD_COUNTERS = ("tpud_batches_out", "tpud_batches_in", "tpud_bytes_out",
                  "tpud_bytes_in", "tpud_encode_us", "tpud_decode_us",
-                 "tpud_put_us", "tpud_out_full", "tpud_put_fallbacks")
+                 "tpud_put_us", "tpud_out_full", "tpud_put_fallbacks",
+                 "tpud_copied_bytes_out", "tpud_copied_bytes_in")
 (tpud_batches_out, tpud_batches_in, tpud_bytes_out, tpud_bytes_in,
  tpud_encode_us, tpud_decode_us, tpud_put_us, tpud_out_full,
- tpud_put_fallbacks) = _tpud_adders = tuple(Adder() for _ in TPUD_COUNTERS)
+ tpud_put_fallbacks, tpud_copied_bytes_out,
+ tpud_copied_bytes_in) = _tpud_adders = tuple(
+    Adder() for _ in TPUD_COUNTERS)
 
 
 def note_rpc_messages(n: int) -> None:

@@ -21,6 +21,12 @@ there that is the contract. With jax loaded a ``device_put`` that raises
 is counted (``tpud_put_fallbacks``) and raised, which fails the
 connection: a handler never gets numpy in a device array's place
 unnoticed (docs/tpu_transport.md has the copy chain and the counters).
+
+``TpudConn`` copies no staged batch in Python: an array goes to
+``sendmsg`` by reference behind its header (one snapshot where it is a
+writeable numpy array, which the caller could change under the send),
+and a received batch is read into a buffer of its own and decoded in
+place (``tpud_copied_bytes_out|in`` count what was copied all the same).
 """
 
 from __future__ import annotations
@@ -31,6 +37,7 @@ import sys
 import threading
 import time
 from collections import deque
+from itertools import islice
 from typing import Callable, Deque, List, Optional, Tuple
 
 import numpy as np
@@ -46,6 +53,8 @@ _F_HELLO = 2
 _HDR = struct.Struct(">BI")
 _MAX_FRAME = 256 << 20
 _MAX_OUT = 64 << 20          # backpressure cap on the staged out-buffer
+_READ = 256 << 10            # one read into the conn's scratch
+_IOV = 64                    # segments a sendmsg hands the kernel at most
 
 
 def _np_dtype(name: str):
@@ -67,39 +76,64 @@ def _hello_payload(device_ordinal: Optional[int]) -> bytes:
     return json.dumps(info).encode()
 
 
-def _encode_device_batch(arrays) -> bytes:
-    parts = [struct.pack(">H", len(arrays))]
+def _batch_segments(arrays, keep: bool = False) -> Tuple[list, int, int]:
+    """One batch in wire order as segments: each array's fields as
+    ``bytes`` (the count ahead of the first), each array's data as a
+    ``uint8`` view of its C-contiguous host copy. The one function that
+    knows the layout: joined, the segments are the encoded batch.
+    Returns the segments, their length and the array bytes copied. A
+    non-contiguous array is made contiguous; with ``keep`` a writeable
+    one is snapshotted too (its owner could change it before the kernel
+    has it), and one that is not writeable goes by reference."""
+    fields = [struct.pack(">H", len(arrays))]
+    segs, length, copied = [], 2, 0
     for arr in arrays:
         host = np.asarray(arr)
+        if not host.flags.c_contiguous or (keep and host.flags.writeable):
+            host = np.array(host, order="C")
+            copied += host.nbytes
         dt = str(host.dtype).encode()
-        parts.append(struct.pack(">B", len(dt)))
-        parts.append(dt)
-        parts.append(struct.pack(">B", host.ndim))
-        parts.append(struct.pack(f">{host.ndim}q", *host.shape)
-                     if host.ndim else b"")
-        raw = host.tobytes()
-        parts.append(struct.pack(">Q", len(raw)))
-        parts.append(raw)
-    return b"".join(parts)
+        fields += [struct.pack(f">B{len(dt)}sB{host.ndim}qQ", len(dt), dt,
+                               host.ndim, *host.shape, host.nbytes)]
+        length += 1 + len(dt) + 1 + 8 * host.ndim + 8 + host.nbytes
+        if host.nbytes:
+            segs += [b"".join(fields),
+                     memoryview(host.reshape(-1).view(np.uint8))]
+            fields = []
+    if fields:
+        segs.append(b"".join(fields))
+    return segs, length, copied
 
 
-def _decode_device_batch(data: bytes) -> List[np.ndarray]:
-    (count,) = struct.unpack_from(">H", data, 0)
+def _encode_device_batch(arrays) -> bytes:
+    return b"".join(_batch_segments(arrays)[0])
+
+
+def _decode_device_batch(data) -> List[np.ndarray]:
+    """The arrays of one encoded batch, as views of ``data`` (``bytes``
+    or any buffer). An array whose offset in ``data`` is not aligned for
+    its dtype (float32 at rank 2 starts at byte 35 of the batch) is
+    copied to an aligned buffer of its own; the caller tells the two
+    apart by ``flags.owndata``."""
+    view = memoryview(data)
+    (count,) = struct.unpack_from(">H", view, 0)
     pos = 2
     out = []
     for _ in range(count):
-        (dtlen,) = struct.unpack_from(">B", data, pos)
+        (dtlen,) = struct.unpack_from(">B", view, pos)
         pos += 1
-        dtype = _np_dtype(data[pos:pos + dtlen].decode())
+        dtype = _np_dtype(bytes(view[pos:pos + dtlen]).decode())
         pos += dtlen
-        (rank,) = struct.unpack_from(">B", data, pos)
+        (rank,) = struct.unpack_from(">B", view, pos)
         pos += 1
-        shape = struct.unpack_from(f">{rank}q", data, pos) if rank else ()
+        shape = struct.unpack_from(f">{rank}q", view, pos) if rank else ()
         pos += 8 * rank
-        (nbytes,) = struct.unpack_from(">Q", data, pos)
+        (nbytes,) = struct.unpack_from(">Q", view, pos)
         pos += 8
-        arr = np.frombuffer(data[pos:pos + nbytes],
+        arr = np.frombuffer(view[pos:pos + nbytes],
                             dtype=dtype).reshape(shape)
+        if not arr.flags.aligned:
+            arr = arr.copy()    # its offset in the frame misaligns it
         pos += nbytes
         out.append(arr)
     return out
@@ -159,15 +193,21 @@ class TpudConn(Conn):
         # one pump at a time, and peek_closed looks at what a pump in
         # flight has read only after it ended
         self._pump_lock = threading.Lock()
-        self._out = bytearray()            # staged enveloped output
+        # staged enveloped output: frames as segments for sendmsg (a
+        # batch's arrays by reference), and their length in bytes
+        self._out: Deque = deque()
+        self._out_bytes = 0
         # flush-stamp bookkeeping (under _lock): bytes TCP has taken, and
         # (end offset in the stream, tracker) of every device frame whose
         # last byte TCP has not taken yet
         self._written = 0
         self._marks: Deque[Tuple[int, object]] = deque()
-        self._inbuf = bytearray()          # raw inbound, pre-envelope
+        self._scratch = memoryview(bytearray(_READ))  # what a read lands in
+        self._inbuf = bytearray()          # a frame's head a read cut off
+        self._frame: Optional[memoryview] = None  # a device frame filling
+        self._frame_pos = 0                # ... and its bytes read so far
         self._appbuf = bytearray()         # de-enveloped app bytes
-        self._lane: Deque[bytes] = deque()  # inbound batches, encoded
+        self._lane: Deque[memoryview] = deque()  # inbound batches, encoded
         self._closed_read = False
         self._closed = False
         # the device inbound batches are put on, resolved at the first
@@ -178,28 +218,32 @@ class TpudConn(Conn):
 
     # ----------------------------------------------------------- outbound
     def _out_full(self) -> bool:
-        if len(self._out) > _MAX_OUT:
+        if self._out_bytes > _MAX_OUT:
             _stats.tpud_out_full.add(1)
             return True
         return False
 
-    def _send_frame(self, ftype: int, payload: bytes, flush: bool = True,
-                    tracker=None) -> None:
+    def _stage(self, segs, nbytes: int, flush: bool, tracker=None) -> None:
         with self._lock:
             if self._closed:
                 raise ConnectionError("tpud conn closed")
-            self._out += _HDR.pack(ftype, len(payload))
-            self._out += payload
+            self._out.extend(segs)
+            self._out_bytes += nbytes
             if tracker is not None:
-                self._marks.append((self._written + len(self._out), tracker))
+                self._marks.append((self._written + self._out_bytes, tracker))
         if flush:
             self._flush()
 
+    def _send_frame(self, ftype: int, payload: bytes) -> None:
+        frame = _HDR.pack(ftype, len(payload)) + payload
+        self._stage((frame,), len(frame), True)
+
     def _flush(self) -> bool:
         """Push staged bytes into the TCP socket; True if fully drained.
-        Single-flight a chunk: two concurrent flushers would snapshot
-        and send the same prefix twice, corrupting the stream. The
-        trackers of the batches a chunk completed are stamped with no
+        Single-flight a ``sendmsg``: two concurrent flushers would send
+        the same segments twice, corrupting the stream. A partial send
+        leaves the rest of its last segment as a view of it. The
+        trackers of the batches a send completed are stamped with no
         lock of this conn held (their cell's lock is a leaf)."""
         while True:
             sent = []
@@ -207,15 +251,21 @@ class TpudConn(Conn):
                 with self._lock:
                     if not self._out:
                         return True
-                    chunk = bytes(self._out[:256 << 10])
+                    segs = list(islice(self._out, _IOV))
                 try:
-                    n = self._inner.write(memoryview(chunk))
+                    n = self._inner.writev(segs)
                 except BlockingIOError:
                     self._inner.request_writable_event()
                     return False
                 with self._lock:
-                    del self._out[:n]
+                    self._out_bytes -= n
                     self._written += n
+                    for seg in segs:
+                        if len(seg) > n:
+                            self._out[0] = memoryview(seg)[n:]
+                            break
+                        n -= len(seg)
+                        self._out.popleft()
                     while self._marks and \
                             self._marks[0][0] <= self._written:
                         sent.append(self._marks.popleft()[1])
@@ -236,60 +286,103 @@ class TpudConn(Conn):
 
     def write_device_payload(self, arrays, tracker=None,
                              flush: bool = True) -> bool:
-        """Stage a batch: wait for the device, copy to the host, encode,
-        append, push (``flush`` False leaves the push to a later
-        write). ``tracker``: the batch's device_stats timeline (or
-        None). A full out-buffer raises BlockingIOError BEFORE anything
-        is staged, with the tracker still open: the Socket settles it,
-        fails that call and keeps its envelope home, so no batch is left
-        without its envelope."""
+        """Stage a batch: wait for the device, copy to the host, queue
+        the frame's segments (the arrays by reference), push (``flush``
+        False leaves the push to a later write). ``tracker``: the
+        batch's device_stats timeline (or None). A full out-buffer
+        raises BlockingIOError BEFORE anything is staged, with the
+        tracker still open: the Socket settles it, fails that call and
+        keeps its envelope home, so no batch is left without its
+        envelope."""
         if self._out_full():
             raise BlockingIOError("tpud out-buffer full")
         t0 = time.monotonic_ns()
-        payload = _encode_device_batch(arrays)
+        segs, length, copied = _batch_segments(arrays, keep=True)
+        segs[0] = _HDR.pack(_F_DEVICE, length) + segs[0]
         _stats.tpud_encode_us.add((time.monotonic_ns() - t0) // 1000)
         if tracker is not None:
             tracker.lane_encoded()
-        self._send_frame(_F_DEVICE, payload, flush, tracker)
+        self._stage(segs, _HDR.size + length, flush, tracker)
         _stats.tpud_batches_out.add(1)
-        _stats.tpud_bytes_out.add(len(payload))
+        _stats.tpud_bytes_out.add(length)
+        _stats.tpud_copied_bytes_out.add(copied)
         return True
 
     # ------------------------------------------------------------ inbound
     def _pump(self) -> None:
-        """Drain the TCP socket and de-envelope complete frames."""
+        """Drain the TCP socket and de-envelope complete frames. Once a
+        device frame's header is parsed the rest of the frame is read
+        straight into a buffer of its own; all else lands in the
+        scratch."""
         with self._pump_lock:
-            buf = bytearray(256 << 10)
             while True:
+                frame = self._frame
                 try:
-                    n = self._inner.read_into(memoryview(buf))
+                    n = self._inner.read_into(
+                        self._scratch if frame is None
+                        else frame[self._frame_pos:])
                 except BlockingIOError:
                     break
                 if n == 0:
                     self._closed_read = True
                     break
-                self._inbuf += buf[:n]
-            while len(self._inbuf) >= _HDR.size:
-                ftype, length = _HDR.unpack_from(self._inbuf, 0)
-                if length > _MAX_FRAME:
-                    raise ConnectionError(
-                        f"tpud frame of {length}B exceeds max")
-                if len(self._inbuf) < _HDR.size + length:
-                    break
-                payload = bytes(self._inbuf[_HDR.size:_HDR.size + length])
-                del self._inbuf[:_HDR.size + length]
-                if ftype == _F_BYTES:
-                    self._appbuf += payload
-                elif ftype == _F_DEVICE:
-                    # decoded at the take, which the Socket times
-                    self._lane.append(payload)
-                elif ftype == _F_HELLO:
-                    try:
-                        self.peer_info = json.loads(payload.decode())
-                    except ValueError:
-                        raise ConnectionError("tpud: bad hello")
-                else:
-                    raise ConnectionError(f"tpud: unknown frame type {ftype}")
+                if frame is None:
+                    self._deframe(n)
+                    continue
+                self._frame_pos += n
+                if self._frame_pos == len(frame):
+                    self._lane.append(frame)
+                    self._frame = None
+
+    def _deframe(self, n: int) -> None:
+        """De-envelope the scratch's first ``n`` bytes behind what an
+        earlier read left in ``_inbuf``; what no whole frame holds stays
+        there for the next read."""
+        if not self._inbuf:
+            data = self._scratch[:n]
+            self._inbuf += data[self._cut(data):]
+            return
+        self._inbuf += self._scratch[:n]
+        data = memoryview(self._inbuf)
+        pos = self._cut(data)
+        data.release()
+        del self._inbuf[:pos]
+
+    def _cut(self, data: memoryview) -> int:
+        """File the whole frames at the head of ``data``; returns the
+        bytes they took. A device frame is filed in ``_lane`` before the
+        bytes behind it reach ``_appbuf``; one the data's end cuts off
+        becomes ``_frame``, to be filled by the reads that follow."""
+        pos, end = 0, len(data)
+        while end - pos >= _HDR.size:
+            ftype, length = _HDR.unpack_from(data, pos)
+            if length > _MAX_FRAME:
+                raise ConnectionError(f"tpud frame of {length}B exceeds max")
+            body = pos + _HDR.size
+            if ftype == _F_DEVICE:
+                # the frame's own buffer, the head read so far moved in
+                have = min(length, end - body)
+                frame = memoryview(np.empty(length, np.uint8))
+                frame[:have] = data[body:body + have]
+                _stats.tpud_copied_bytes_in.add(have)
+                if have < length:
+                    self._frame, self._frame_pos = frame, have
+                    return end
+                self._lane.append(frame)    # decoded at the take
+            elif end - body < length:
+                break
+            elif ftype == _F_BYTES:
+                self._appbuf += data[body:body + length]
+            elif ftype == _F_HELLO:
+                try:
+                    self.peer_info = json.loads(
+                        bytes(data[body:body + length]).decode())
+                except ValueError:
+                    raise ConnectionError("tpud: bad hello")
+            else:
+                raise ConnectionError(f"tpud: unknown frame type {ftype}")
+            pos = body + length
+        return pos
 
     def read_into(self, mv: memoryview) -> int:
         self._pump()
@@ -315,6 +408,8 @@ class TpudConn(Conn):
         t1 = time.monotonic_ns()
         _stats.tpud_batches_in.add(1)
         _stats.tpud_bytes_in.add(len(payload))
+        _stats.tpud_copied_bytes_in.add(
+            sum(a.nbytes for a in batch if a.flags.owndata))
         _stats.tpud_decode_us.add((t1 - t0) // 1000)
         jax = sys.modules.get("jax")
         if jax is None:
@@ -368,7 +463,8 @@ class TpudConn(Conn):
         if not self._inner.peek_closed():
             return False
         with self._pump_lock:
-            return not (self._inbuf or self._appbuf or self._lane)
+            return not (self._inbuf or self._appbuf or self._lane
+                        or self._frame is not None)
 
     @property
     def local_endpoint(self):
