@@ -1,11 +1,10 @@
-"""What PR 37 added to BENCHMARK.json, found by name: one configuration,
-one cell, the cell's name appended to ``call_p50_us`` (NOT to
-``calls_per_s``: ``test_benchmark_collective.py::
-test_pr29s_six_entries_stand_unchanged_and_together`` holds that metric's
-cells to PR 29's three, and stands), six per-layer entries after
-everything that was there, and the cell's name appended to the two
-accepted readers that move ``call_p50_us`` and find their source in the
-cell."""
+"""The remote caller's entries in BENCHMARK.json, found by name and by
+membership, never by position or count: one configuration, one cell,
+the cell's name listed once under ``call_p50_us``, six per-layer entries
+standing together after what was there, and the cell's name listed under
+the two accepted readers that move ``call_p50_us`` and find their source
+in the cell. A later cell appends after all of them and edits none of
+this."""
 
 import bench_testlib
 from bench_testlib import ROOT, read_json
@@ -29,10 +28,9 @@ def test_the_cell_reports_the_median_and_the_rate_cells_are_pr29s():
     from test_benchmark_thread_roles import CELLS
 
     e2e = {e["name"]: e for e in bench_testlib.bench()["end_to_end"]}
-    # a closed loop at depth 8: the median is depth / rate. The rate's
-    # own list is pinned to the three role cells by an accepted test
-    assert sorted(e2e["calls_per_s"]["workloads"]) == sorted(CELLS)
-    assert e2e["call_p50_us"]["workloads"][-1] == CELL
+    # a closed loop at depth 8: the median is depth / rate. The role
+    # cells report the rate, among any cell listed there later
+    assert set(CELLS) <= set(e2e["calls_per_s"]["workloads"])
     assert e2e["call_p50_us"]["workloads"].count(CELL) == 1
     assert CELL not in e2e["call_p99_us"]["workloads"]  # spread over 3%
     assert "workloads" not in e2e["setup_s"]
@@ -44,18 +42,18 @@ def test_the_six_new_entries_stand_together_after_what_was_there():
     per_layer = bench_testlib.bench()["per_layer"]
     names = [m["name"] for m in per_layer]
     first = names.index(THE_SIX[0][0])
-    assert per_layer[first:] == [
+    assert per_layer[first:first + len(THE_SIX)] == [
         {"name": name, "unit": unit, "better": "lower", "source": source,
          "layer": layer, "moves": "call_p50_us", "workloads": [CELL]}
         for name, unit, source, layer in THE_SIX]
     # after PR 35's four shares of the loop's awake time, the list's end
     # at the parent. Of the entries that were there, two list the new
-    # cell, as their last: both move the metric the cell reports
+    # cell, once each: both move the metric the cell reports
     assert names[first - 1] == "dispatcher_process_share"
     listing = [m for m in per_layer[:first] if CELL in m["workloads"]]
     assert sorted(m["name"] for m in listing) == sorted(APPENDED_TO)
-    assert all(m["workloads"][-1] == CELL and m["moves"] == "call_p50_us"
-               for m in listing)
+    assert all(m["workloads"].count(CELL) == 1
+               and m["moves"] == "call_p50_us" for m in listing)
     layers = {m["layer"] for m in per_layer[:first]}
     assert {layer for *_r, layer in THE_SIX} <= layers   # no new layer
 
@@ -65,14 +63,12 @@ def test_the_cell_and_its_configuration_as_the_issue_names_them():
     (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     # the deployment runs on chip 0; the cell holds a four-chip host for
     # steadiness alone, and says so (PERF.md section 6)
-    assert cell == bench["workloads"][-1] and cell["chips"] == 4
+    assert cell["chips"] == 4
     assert "steadiness" in cell["why"]
     assert cell["config"] == "remote_caller" \
         and cell["traffic"] == "step_2mb_d8_tpud"
-    assert len(bench["workloads"]) == 8
-    assert sum(1 for w in bench["workloads"] if w["chips"] == 4) == 4
     (conf,) = [c for c in bench["configs"] if c["name"] == "remote_caller"]
-    assert conf == bench["configs"][-1] and conf["reduced"] == ["hosts"]
+    assert conf["reduced"] == ["hosts"]
     assert len(conf["source"]) <= 200 and "rdma_performance" in conf["source"]
     body = read_json(ROOT, conf["file"])
     assert body["architecture"] is None and body["service"] == "remote_caller"
