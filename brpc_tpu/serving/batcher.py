@@ -22,6 +22,13 @@ batch. Here the unit of scheduling is ONE decode step:
     token, or client disconnect) leaves at the END of the step it
     finished in; survivors never notice.
 
+Device work: a step runs the model's prefill program for each request
+admitted in it (its first token comes from there) and ONE decode
+program for the slots that were running, both on the device with the
+cache donated and written in place; then one ``device_get`` brings the
+tokens and the model's small counter arrays to the host. Nothing else
+crosses.
+
 Thread model: ``step()`` is called from fiber-worker threads through
 the engine's WorkerModule hook (serving/engine.py) and is serialized by
 the engine's decode lock; THIS lock only guards the queues/slots, so
@@ -48,6 +55,23 @@ from brpc_tpu.rpc import errno_codes as berr
 
 from . import serving_stats as _sstats
 from .model import TinyDecoder
+
+
+def _flat(counts):
+    """A counter array from the device as a flat tuple of ints (None
+    where the model counts nothing)."""
+    if counts is None:
+        return None
+    return tuple(int(c) for c in np.ravel(counts))
+
+
+def _sum_counts(arrays):
+    """The element-wise sum of the prefills' counter arrays (None where
+    there are none, or the model counts nothing)."""
+    arrays = [a for a in arrays if a is not None]
+    if not arrays:
+        return None
+    return np.sum(arrays, axis=0)
 
 # request states
 WAITING = "waiting"
@@ -165,9 +189,8 @@ class GenRequest:
 
 
 class ContinuousBatcher:
-    def __init__(self, model: Optional[TinyDecoder] = None,
-                 max_batch: int = 8, max_waiting: int = 32,
-                 wake=None):
+    def __init__(self, model=None, max_batch: int = 8,
+                 max_waiting: int = 32, wake=None):
         self.model = model or TinyDecoder()
         # worker wake-up hook (TaskControl.parking_lot.signal): a submit
         # landing while every fiber worker is parked must not wait out
@@ -175,16 +198,17 @@ class ContinuousBatcher:
         # worker is stepping it keeps polling until the batch drains, so
         # only the idle->busy edge needs the kick.
         self._wake = wake
-        cfg = self.model.config
         self.max_batch = int(max_batch)
         self.max_waiting = int(max_waiting)
-        self.cache_len = cfg.cache_len
+        self.cache_len = int(self.model.cache_len)
         self._lock = threading.Lock()
-        self._k = np.zeros((self.max_batch, cfg.cache_len, cfg.dim),
-                           np.float32)
-        self._v = np.zeros_like(self._k)
-        self._h = np.zeros((self.max_batch, cfg.dim), np.float32)
-        self._lens = np.ones((self.max_batch,), np.int64)  # 1 = idle-safe
+        # the model's cache lives on the device; only step() touches it
+        # (steps are serialized by the engine's decode lock). stop()
+        # lets it go, or leaves that to the step in flight (_stepping)
+        self._stepping = False
+        self._cache = self.model.new_cache(self.max_batch, self.cache_len)
+        self._lens = np.zeros((self.max_batch,), np.int64)  # rows cached
+        self._last = np.zeros((self.max_batch,), np.int32)  # pending token
         self._slots: List[Optional[GenRequest]] = [None] * self.max_batch
         self._free = list(range(self.max_batch))
         self._waiting: deque = deque()
@@ -201,15 +225,24 @@ class ContinuousBatcher:
         self.tokens_out = 0
         _live_batchers.add(self)
 
+    def warm_up(self) -> None:
+        """Compile every program the model will run (before the first
+        request, so its TTFT measures scheduling, not XLA)."""
+        self._cache = self.model.warm_up(self._cache)
+
     # ------------------------------------------------------------- intake
     def submit(self, req: GenRequest) -> bool:
         """Queue a request for admission at the next step boundary.
         False = shed (bounded queue full, or batcher stopped); raises
         RequestTooLong when the prompt cannot fit a KV slot at all."""
-        if len(req.prompt) + 1 > self.cache_len:
+        if not req.prompt:
+            raise ValueError("empty prompt")
+        if len(req.prompt) + 1 > self.cache_len \
+                or len(req.prompt) > self.model.max_prompt:
             raise RequestTooLong(
                 f"prompt of {len(req.prompt)} tokens cannot fit a "
-                f"{self.cache_len}-token KV slot")
+                f"{self.cache_len}-token KV slot (prompts up to "
+                f"{self.model.max_prompt})")
         # clamp the budget to the slot: a request asking for more than
         # fits generates what fits (the response says how many it got)
         req.max_new_tokens = min(req.max_new_tokens,
@@ -249,6 +282,16 @@ class ContinuousBatcher:
     def waiting_count(self) -> int:
         return len(self._waiting)
 
+    def cache_bytes(self) -> int:
+        """Bytes the cache pins: the model's device arrays (none once
+        stopped) and the slot table's host arrays."""
+        nbytes = self._lens.nbytes + self._last.nbytes
+        cache = self._cache
+        if cache is not None:
+            import jax
+            nbytes += sum(a.nbytes for a in jax.tree.leaves(cache))
+        return nbytes
+
     def kv_occupancy(self) -> float:
         """Fraction of the KV budget (all slots x cache_len) holding
         live sequence state."""
@@ -275,7 +318,7 @@ class ContinuousBatcher:
         if req.slot is not None:
             i = req.slot
             self._slots[i] = None
-            self._lens[i] = 1
+            self._lens[i] = 0
             self._free.append(i)
             self._nrunning -= 1
             req.slot = None
@@ -288,6 +331,20 @@ class ContinuousBatcher:
         when there was nothing to do (the caller's worker may park).
         Callers serialize steps (engine decode lock); this lock only
         covers slot/queue state."""
+        with self._lock:
+            if self._cache is None:         # stopped: the cache is gone
+                return False
+            self._stepping = True
+        try:
+            return self._step(group_index)
+        finally:
+            with self._lock:
+                self._stepping = False
+                if self.stopped:
+                    self._cache = None
+
+    def _step(self, group_index: Optional[int]) -> bool:
+        """The step itself (``step`` guards the cache around it)."""
         emits: List[Tuple[GenRequest, int]] = []
         done: List[Tuple[GenRequest, str]] = []
         admitted: List[GenRequest] = []
@@ -355,46 +412,61 @@ class ContinuousBatcher:
                 self._record_step(t0, t_sweep, t_sweep, t_sweep,
                                   group_index, 0, len(admitted),
                                   n_evicted, n_canceled, 0,
-                                  len(self._waiting), len(self._free), 0)
+                                  len(self._waiting), len(self._free), 0,
+                                  0, None, None)
             return bool(done)
-        # prefill the admissions outside the lock: the caches and lens
-        # are only written by step(), and steps are serialized by the
-        # engine's decode lock, so only the slot TABLE needed the lock
+        # the device work, outside the lock: the cache, lens and last
+        # tokens are only written by step(), and steps are serialized by
+        # the engine's decode lock, so only the slot TABLE needed it.
+        # Admitted requests prefill (their first token comes from it);
+        # the slots that were running before this step decode one token
+        fresh = set(id(r) for r in admitted)
+        firsts, pre_counts = [], []
         for req in admitted:
-            i = req.slot
-            kp, vp, hl = self.model.prefill(req.prompt)
-            n = len(req.prompt)
-            self._k[i, :n], self._v[i, :n] = kp, vp
-            self._h[i] = hl
-            self._lens[i] = n
-            if req.tracker is not None:
-                req.tracker.gen_prefilled(time.monotonic_ns())
+            self._cache, tok, counts, _aux = self.model.prefill(
+                self._cache, req.slot, req.prompt)
+            firsts.append(tok)
+            pre_counts.append(counts)
         t_admit = time.monotonic_ns() if stats_on else 0
-        # 3. the decode step proper — outside the lock (jax releases
-        # the GIL; submit/cancel must not wait a full step)
-        nxt, k_new, v_new, h_new = self.model.decode_step(
-            self._k, self._v, self._h, self._lens.copy())
+        lengths = np.full((self.max_batch,), -1, np.int32)
+        for i, req in active:
+            if id(req) not in fresh:
+                lengths[i] = self._lens[i]
+        nxt = dec_counts = None
+        if (lengths >= 0).any():
+            self._cache, nxt, dec_counts, _aux = self.model.decode(
+                self._cache, self._last, lengths)
+        # one wait for the device: the tokens and the counters only
+        import jax
+        firsts, pre_counts, nxt, dec_counts = jax.device_get(
+            (firsts, pre_counts, nxt, dec_counts))
         t_decode = time.monotonic_ns() if stats_on else 0
+        first_of = dict(zip((id(r) for r in admitted), firsts))
         with self._lock:
             for i, req in active:
                 if self._slots[i] is not req:
                     continue        # canceled+retired during the step
-                tok = int(nxt[i])
-                pos = int(self._lens[i])
-                self._k[i, pos], self._v[i, pos] = k_new[i], v_new[i]
-                self._h[i] = h_new[i]
-                self._lens[i] = pos + 1
+                now = time.monotonic_ns()
+                if id(req) in fresh:
+                    tok = int(first_of[id(req)])
+                    self._lens[i] = len(req.prompt)
+                    if req.tracker is not None:
+                        req.tracker.gen_prefilled(now)
+                else:
+                    tok = int(nxt[i])
+                    self._lens[i] += 1
+                self._last[i] = tok
                 req.tokens.append(tok)
                 self.tokens_out += 1
                 ntokens.add(1)
                 if not req.first_token_ns:
-                    req.first_token_ns = time.monotonic_ns()
+                    req.first_token_ns = now
                 if req.tracker is not None:
-                    req.tracker.gen_token(time.monotonic_ns())
+                    req.tracker.gen_token(now)
                 emits.append((req, tok))
                 if (req.stop_token is not None and tok == req.stop_token) \
                         or req.ntokens >= req.max_new_tokens \
-                        or int(self._lens[i]) >= self.cache_len:
+                        or int(self._lens[i]) + 1 >= self.cache_len:
                     self._retire_locked(req, COMPLETED, done)
             if stats_on:
                 waiting_after = len(self._waiting)
@@ -407,14 +479,18 @@ class ContinuousBatcher:
             self._record_step(t0, t_sweep, t_admit, t_decode,
                               group_index, len(active), len(admitted),
                               n_evicted, n_canceled, len(emits),
-                              waiting_after, free_after, kv_used)
+                              waiting_after, free_after, kv_used,
+                              sum(len(r.prompt) for r in admitted),
+                              _flat(dec_counts), _flat(_sum_counts(
+                                  pre_counts)))
         return True
 
     def _record_step(self, t0: int, t_sweep: int, t_admit: int,
                      t_decode: int, group_index, batch: int,
                      admitted: int, evicted: int, canceled: int,
                      tokens: int, waiting: int, free_slots: int,
-                     kv_used: int) -> None:
+                     kv_used: int, prefill_tokens: int, routed,
+                     prefill_routed) -> None:
         """One bounded iteration record into the flight deck's step
         ring (leaf lock, outside every batcher lock): the Orca view —
         what THIS step did and where its microseconds went."""
@@ -442,6 +518,10 @@ class ContinuousBatcher:
             max(0, t_decode - t_admit) // 1000,
             max(0, t_end - t_decode) // 1000,
             max(0, t_end - t0) // 1000,
+            prefill_tokens,
+            kv_used,
+            routed,
+            prefill_routed,
         ))
 
     @staticmethod
@@ -481,8 +561,9 @@ class ContinuousBatcher:
 
     # ----------------------------------------------------------- shutdown
     def stop(self) -> List[GenRequest]:
-        """Refuse new work and retire everything in flight (CANCELED).
-        Returns the retired requests (the service fails their calls)."""
+        """Refuse new work, retire everything in flight (CANCELED) and
+        release the device cache once no step is running. Returns the
+        retired requests (the service fails their calls)."""
         done: List[Tuple[GenRequest, str]] = []
         with self._lock:
             self.stopped = True
@@ -492,6 +573,8 @@ class ContinuousBatcher:
             for r in victims:
                 if r.state not in _TERMINAL:
                     self._retire_locked(r, CANCELED, done)
+            if not self._stepping:
+                self._cache = None
         self._fire([], done)
         return [r for r, _ in done]
 

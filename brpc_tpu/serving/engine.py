@@ -84,15 +84,9 @@ class ServingEngine(WorkerModule):
         }
 
     def warm_up(self) -> None:
-        """Trigger the one-time jit compile of the decode step so the
-        first real request's TTFT measures scheduling, not XLA."""
-        m = self.batcher.model
-        import numpy as np
-        cfg = m.config
-        k = np.zeros((self.batcher.max_batch, cfg.cache_len, cfg.dim),
-                     np.float32)
-        v = np.zeros_like(k)
-        h = np.zeros((self.batcher.max_batch, cfg.dim), np.float32)
+        """Compile the model's programs once (every prefill bucket and
+        the decode step) so the first real request's TTFT measures
+        scheduling, not XLA."""
         stats_on = _sstats.enabled()
         if stats_on:
             # XLA compile runs on the start thread, outside any fiber
@@ -100,8 +94,7 @@ class ServingEngine(WorkerModule):
             # land on a bare thread-name leaf
             _sstats.stamp_serving_thread("serving:warmup")
         try:
-            m.decode_step(k, v, h,
-                          np.ones((self.batcher.max_batch,), np.int64))
+            self.batcher.warm_up()
         finally:
             if stats_on:
                 _sstats.unstamp_serving_thread()

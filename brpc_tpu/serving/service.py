@@ -5,25 +5,37 @@ One registered method, three transports, one batcher behind them all:
   * **tpu_std streaming** — the client attaches a Stream to the
     Generate call; the handler admits the request and returns
     ``b"accepted"`` immediately. Tokens ride back as credit-controlled
-    stream frames AS THEY DECODE (time-to-first-token = the first
-    decode step after admission, not batch completion). Frame payloads
-    are tagged: ``t<byte>`` one token, ``d<json>`` done summary,
-    ``e<errno>`` terminal error (deadline eviction sends ``e1008``);
+    stream frames AS THEY DECODE (time-to-first-token = the prefill in
+    the first step after admission, not batch completion). Frame
+    payloads are tagged: ``t<id>`` one token (the id as one byte where
+    the model's vocabulary is the byte one, else as 4 little-endian
+    bytes), ``d<json>`` done summary, ``e<errno>`` terminal error
+    (deadline eviction sends ``e1008``);
   * **HTTP** — the same method over ``POST /GenerateService/Generate``
-    streams tokens as chunked-transfer bytes through a
+    streams tokens as chunked-transfer bytes (one a token, or 4
+    little-endian a token past the byte vocabulary) through a
     ProgressiveAttachment, with a trailing ``\\n#<state> ...`` status
     line (chunked bodies cannot carry a late status code). A dead peer
     flips ``pa.write()`` to False — the feeder cancels the sequence
     and the KV slot frees (the progressive dead-peer fix exists for
     exactly this loop);
   * **unary** — a plain tpu_std call parks its handler fiber until the
-    sequence retires and returns every token in one JSON response
-    (deadline eviction fails the call with ``ERPCTIMEDOUT``).
+    sequence retires and returns every token in one JSON response:
+    ``tokens`` as ids, and ``text`` where the vocabulary is the byte
+    one (deadline eviction fails the call with ``ERPCTIMEDOUT``).
 
 Request body: JSON ``{"prompt": str, "max_tokens": int,
-"stop_token": int?}`` — or a bare byte string treated as the prompt
-with the default token budget. Prompt bytes ARE the tokens (byte-level
-vocab).
+"stop_token": int?}`` (prompt bytes ARE the tokens), or ``{"tokens":
+[int, ...], ...}`` (token ids below the model's vocabulary), or a bare
+byte string treated as the prompt with the default token budget. A
+call that carries a device array (``request_device_arrays``, one
+integer vector) takes its prompt ids from it, the JSON body then giving
+only the budget: ids born on the device stay ids.
+
+The model: ``GenerateService(model=...)`` takes a model (the interface
+in ``serving/model.py``) or a factory that makes one; each server start
+builds its replica from it. The default is the TinyDecoder the
+``serving_*`` flags shape.
 
 Wiring: ``add_generate_service(server)`` registers the service and
 arms the engine lifecycle — ``Server.start`` builds a FRESH
@@ -35,6 +47,7 @@ private replica), ``Server.stop`` unregisters and drains it.
 from __future__ import annotations
 
 import json
+import struct
 import threading
 import time
 from collections import deque
@@ -67,36 +80,78 @@ define_flag("serving_warmup", True,
             "run one throwaway decode step at server start so the "
             "first request's TTFT measures scheduling, not XLA compile")
 
+_ID = struct.Struct("<I")
+
 # pending-frame cap for a stream consumer that stopped granting
 # credits: past this the sequence is canceled (a slow reader must not
 # pin a KV slot forever)
 _MAX_PENDING_FRAMES = 512
 
 
-def _parse_request(body) -> Tuple[List[int], int, Optional[int]]:
+def _parse_request(body, vocab: int = 256, device_tokens=None
+                   ) -> Tuple[List[int], int, Optional[int]]:
+    """(prompt ids, token budget, stop token) of a request; ValueError
+    for one the service refuses. ``device_tokens`` are the ids the call
+    carried as a device array."""
     raw = bytes(body) if not isinstance(body, bytes) else body
     max_tokens = int(flag("serving_default_max_tokens"))
     stop_token = None
+    tokens = device_tokens
     if raw[:1] == b"{":
         try:
             doc = json.loads(raw.decode("utf-8"))
         except (ValueError, UnicodeDecodeError) as e:
             raise ValueError(f"bad request json: {e}")
-        prompt = doc.get("prompt", "")
-        if not isinstance(prompt, str) or not prompt:
-            raise ValueError("request needs a non-empty 'prompt' string")
-        tokens = list(prompt.encode("utf-8"))
+        if tokens is not None:
+            pass
+        elif "tokens" in doc:
+            tokens = doc["tokens"]
+            if not isinstance(tokens, list) or not all(
+                    isinstance(t, int) and not isinstance(t, bool)
+                    for t in tokens):
+                raise ValueError("'tokens' must be a list of ints")
+        else:
+            prompt = doc.get("prompt", "")
+            if not isinstance(prompt, str) or not prompt:
+                raise ValueError(
+                    "request needs a non-empty 'prompt' string or "
+                    "'tokens' list")
+            tokens = list(prompt.encode("utf-8"))
         if "max_tokens" in doc:
             max_tokens = int(doc["max_tokens"])
         if doc.get("stop_token") is not None:
             stop_token = int(doc["stop_token"])
-    else:
-        if not raw:
-            raise ValueError("empty prompt")
+    elif tokens is None:
         tokens = list(raw)
+    if not tokens:
+        raise ValueError("empty prompt")
+    if min(tokens) < 0 or max(tokens) >= vocab:
+        raise ValueError(f"token ids must lie in [0, {vocab})")
     if max_tokens < 1:
         raise ValueError("max_tokens must be >= 1")
     return tokens, max_tokens, stop_token
+
+
+def _device_tokens(cntl) -> Optional[List[int]]:
+    """The prompt ids a call carried as its one device array (None
+    where it carried none)."""
+    arrs = getattr(cntl, "request_device_arrays", None)
+    if not arrs:
+        return None
+    import numpy as np
+    ids = np.asarray(arrs[0])
+    if len(arrs) != 1 or ids.ndim != 1 \
+            or not np.issubdtype(ids.dtype, np.integer):
+        raise ValueError("a prompt on the device is one integer vector")
+    return ids.tolist()
+
+
+def token_bytes(tok: int, vocab: int) -> bytes:
+    """A token on the wire: one byte in the byte vocabulary, else its id
+    as 4 little-endian bytes."""
+    if vocab <= 256:
+        return bytes((tok & 0xFF,))
+    return _ID.pack(tok)
 
 
 class _StreamSender:
@@ -109,6 +164,7 @@ class _StreamSender:
     def __init__(self, stream, batcher: ContinuousBatcher):
         self.stream = stream
         self.batcher = batcher
+        self.vocab = batcher.model.vocab
         self.req: Optional[GenRequest] = None   # set right after ctor
         self._pending: deque = deque()
         self._lock = threading.Lock()
@@ -135,7 +191,7 @@ class _StreamSender:
             return len(self._pending) <= _MAX_PENDING_FRAMES
 
     def token(self, req: GenRequest, tok: int) -> None:
-        if not self._push(b"t" + bytes([tok & 0xFF])):
+        if not self._push(b"t" + token_bytes(tok, self.vocab)):
             self.batcher.cancel(req)
 
     def finish(self, req: GenRequest, state: str) -> None:
@@ -183,9 +239,10 @@ class _HttpSender:
     def __init__(self, pa, batcher: ContinuousBatcher):
         self.pa = pa
         self.batcher = batcher
+        self.vocab = batcher.model.vocab
 
     def token(self, req: GenRequest, tok: int) -> None:
-        if not self.pa.write(bytes([tok & 0xFF])):
+        if not self.pa.write(token_bytes(tok, self.vocab)):
             self.batcher.cancel(req)
 
     def finish(self, req: GenRequest, state: str) -> None:
@@ -209,8 +266,11 @@ class GenerateService:
                  max_waiting: Optional[int] = None,
                  model_seed: Optional[int] = None,
                  warmup: Optional[bool] = None,
-                 name: str = "GenerateService"):
+                 name: str = "GenerateService", model=None):
         self.name = name
+        # a model instance (shared by every start) or a factory called
+        # at each start; None: the TinyDecoder of the flags below
+        self._model = model
         self._max_batch = max_batch
         self._cache_len = cache_len
         self._max_waiting = max_waiting
@@ -220,15 +280,22 @@ class GenerateService:
         self.engine: Optional[ServingEngine] = None
 
     # ----------------------------------------------------------- lifecycle
+    def _build_model(self):
+        if self._model is None:
+            cfg = TinyDecoderConfig(
+                cache_len=int(self._cache_len
+                              if self._cache_len is not None
+                              else flag("serving_cache_len")))
+            if self._model_seed is not None:
+                cfg.seed = self._model_seed
+            return TinyDecoder(cfg)
+        if hasattr(self._model, "decode"):
+            return self._model
+        return self._model()
+
     def on_server_start(self, server) -> None:
-        cfg = TinyDecoderConfig(
-            cache_len=int(self._cache_len
-                          if self._cache_len is not None
-                          else flag("serving_cache_len")))
-        if self._model_seed is not None:
-            cfg.seed = self._model_seed
         self.batcher = ContinuousBatcher(
-            TinyDecoder(cfg),
+            self._build_model(),
             max_batch=int(self._max_batch if self._max_batch is not None
                           else flag("serving_max_batch")),
             max_waiting=int(self._max_waiting
@@ -279,7 +346,8 @@ class GenerateService:
             cntl.set_failed(berr.ELOGOFF, "serving engine not running")
             return b""
         try:
-            prompt, max_tokens, stop_token = _parse_request(request)
+            prompt, max_tokens, stop_token = _parse_request(
+                request, batcher.model.vocab, _device_tokens(cntl))
         except ValueError as e:
             cntl.set_failed(berr.EREQUEST, str(e))
             return b""
@@ -374,10 +442,11 @@ class GenerateService:
         if state != COMPLETED:
             cntl.set_failed(berr.EINTERNAL, f"generation {state}")
             return b""
-        return json.dumps({"status": "completed", "n": req.ntokens,
-                           "tokens": req.tokens,
-                           "text": bytes(req.tokens).decode(
-                               "utf-8", "replace")}).encode()
+        reply = {"status": "completed", "n": req.ntokens,
+                 "tokens": req.tokens}
+        if batcher.model.vocab <= 256:
+            reply["text"] = bytes(req.tokens).decode("utf-8", "replace")
+        return json.dumps(reply).encode()
 
 
 def add_generate_service(server, **kwargs) -> GenerateService:

@@ -180,6 +180,16 @@ class ServingCell(Variable):
             else:
                 self.causes["_other"] = self.causes.get("_other", 0) + 1
 
+    def restart_samples(self) -> None:
+        """Empty the TTFT and token-gap reservoirs: what they hold from
+        now on is the generations settled from now on (a measurement
+        window). The counters keep counting."""
+        with self._cell_lock:
+            self._ttft_samples = []
+            self._nttft = 0
+            self._tpot_samples = []
+            self._ntpot = 0
+
     # ------------------------------------------------------------- reads
     def ttft_samples(self, limit: int = 256) -> List[float]:
         with self._cell_lock:
@@ -346,7 +356,8 @@ class GenTracker:
 STEP_FIELDS = ("t_ms", "group", "batch", "admitted", "evicted",
                "canceled", "tokens", "waiting", "free_slots",
                "kv_occupancy", "sweep_us", "admit_us", "decode_us",
-               "emit_us", "step_us")
+               "emit_us", "step_us", "prefill_tokens", "kv_used",
+               "routed", "prefill_routed")
 
 
 class ServingStats:
@@ -624,19 +635,17 @@ postfork.register("serving.serving_stats", _postfork_reset)
 # --------------------------------------------------------------- census
 
 def _serving_census() -> dict:
-    """Resource census: the KV-slot bytes every live batcher pins (the
-    [max_batch, cache_len, dim] k/v/h arrays) plus what the flight
-    deck itself holds (reservoirs + step ring) — so /census totals
-    include the serving lane's working set (the PR 6 accounting
-    discipline)."""
+    """Resource census: the cache bytes every live batcher pins (the
+    model's device cache and the slot table's host arrays) plus what
+    the flight deck itself holds (reservoirs + step ring) — so /census
+    totals include the serving lane's working set."""
     count = 0
     nbytes = 0
     bm = sys.modules.get("brpc_tpu.serving.batcher")
     if bm is not None:
         for b in list(bm._live_batchers):
             count += 1
-            for arr in (b._k, b._v, b._h, b._lens):
-                nbytes += getattr(arr, "nbytes", 0)
+            nbytes += b.cache_bytes()
     reg = _registry
     if reg is not None:
         for _, cell in reg.rows():

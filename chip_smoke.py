@@ -435,9 +435,10 @@ def phase_fabric(smoke: Smoke) -> dict:
 def phase_serving(smoke: Smoke) -> dict:
     """Four concurrent streaming Generate calls; each token stream must
     equal TinyDecoder.generate for the same prompt on the same device.
-    TinyDecoder (dim 32) is the only model the repo has: this proves the
-    batcher's jitted decode_step runs here, nothing more. No shard
-    group: forked workers cannot share the chip."""
+    TinyDecoder (dim 32): this proves the batcher's prefill and decode
+    programs run here with the cache on the device, nothing more (the
+    benchmark's Moonlight cell runs the real model). No shard group:
+    forked workers cannot share the chip."""
     from brpc_tpu.rpc import Channel, ChannelOptions, Controller, Server
     from brpc_tpu.rpc.stream import StreamOptions
     from brpc_tpu.serving import (TinyDecoder, TinyDecoderConfig,
