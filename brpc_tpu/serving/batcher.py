@@ -22,21 +22,31 @@ batch. Here the unit of scheduling is ONE decode step:
     token, or client disconnect) leaves at the END of the step it
     finished in; survivors never notice.
 
-Device work: a step runs the model's prefill program for each request
-admitted in it (its first token comes from there) and ONE decode
-program for the slots that were running, both on the device with the
-cache donated and written in place; then one ``device_get`` brings the
-tokens and the model's small counter arrays to the host. Nothing else
-crosses.
+Device work: one step of lookahead. The decode a ``step()`` collects
+was dispatched by the step before it; a call runs the model's prefill
+program for each request admitted in it (the donated cache orders it
+behind the decode in flight), then ONE ``device_get`` brings the
+in-flight decode's tokens and counters and the prefills' first tokens
+to the host. From those alone it decides, in numpy over the slot table,
+which slots continue (budget, stop token, cache room) and at which
+lengths, and dispatches the NEXT decode at once; only then does it hand
+out this step's tokens, retire the finished and fire the callbacks,
+while the device runs the decode just queued. Slots that run with no
+decode in flight (behind a step that raised before it dispatched one)
+decode before the fetch, as a plain synchronous step. Both programs take
+the cache donated and write it in place; nothing else crosses.
 
 Thread model: ``step()`` is called from fiber-worker threads through
 the engine's WorkerModule hook (serving/engine.py) and is serialized by
 the engine's decode lock; THIS lock only guards the queues/slots, so
-``submit``/``cancel`` from handler fibers stay cheap. The jitted decode
-call runs OUTSIDE the lock (jax releases the GIL; a submit must not
-wait out a whole step), and user callbacks (``on_token``/``on_finish``)
-fire outside it too — they write to sockets whose failure paths call
-straight back into ``cancel``.
+``submit``/``cancel`` from handler fibers stay cheap. The jitted calls
+and the fetch run OUTSIDE the lock (jax releases the GIL; a submit must
+not wait out a whole step), and user callbacks (``on_token``/
+``on_finish``) fire outside it too — they write to sockets whose
+failure paths call straight back into ``cancel``. A slot swept (cancel,
+deadline) after its row was dispatched keeps that row on the device
+and out of every stream: a row counts only while its slot's epoch is
+the one it was dispatched under.
 """
 
 from __future__ import annotations
@@ -44,7 +54,7 @@ from __future__ import annotations
 import threading
 import time
 import weakref
-from collections import Counter, deque
+from collections import Counter, deque, namedtuple
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
@@ -63,6 +73,11 @@ def _flat(counts):
     if counts is None:
         return None
     return tuple(int(c) for c in np.ravel(counts))
+
+
+# a decode dispatched and not yet collected: its device outputs, and the
+# lengths and slot epochs it was dispatched with
+_Flight = namedtuple("_Flight", "tokens counts lengths epoch")
 
 
 def _sum_counts(arrays):
@@ -92,6 +107,7 @@ nevicted = Adder()
 nshed = Adder()
 ncanceled = Adder()
 ntokens = Adder()
+ndecode_overlapped = Adder()  # steps that queued the next decode early
 _tokens_ps = None           # PerSecond over ntokens, built on expose
 _live_batchers: "weakref.WeakSet[ContinuousBatcher]" = weakref.WeakSet()
 
@@ -108,6 +124,7 @@ def expose_serving_vars() -> None:
     nshed.expose("serving_shed")
     ncanceled.expose("serving_canceled")
     ntokens.expose("serving_tokens")
+    ndecode_overlapped.expose("serving_decode_overlapped")
     if _tokens_ps is None:
         _tokens_ps = PerSecond(ntokens, 10)
     _tokens_ps.expose("serving_tokens_per_second_10s")
@@ -209,6 +226,14 @@ class ContinuousBatcher:
         self._cache = self.model.new_cache(self.max_batch, self.cache_len)
         self._lens = np.zeros((self.max_batch,), np.int64)  # rows cached
         self._last = np.zeros((self.max_batch,), np.int32)  # pending token
+        # what the lookahead decides on, a slot each: the epoch (bumped
+        # whenever the slot is let go), tokens emitted, the budget and
+        # the stop token (-1: none)
+        self._epoch = np.zeros((self.max_batch,), np.int64)
+        self._ntok = np.zeros((self.max_batch,), np.int64)
+        self._budget = np.zeros((self.max_batch,), np.int64)
+        self._stop = np.full((self.max_batch,), -1, np.int64)
+        self._flight: Optional[_Flight] = None
         self._slots: List[Optional[GenRequest]] = [None] * self.max_batch
         self._free = list(range(self.max_batch))
         self._waiting: deque = deque()
@@ -319,6 +344,7 @@ class ContinuousBatcher:
             i = req.slot
             self._slots[i] = None
             self._lens[i] = 0
+            self._epoch[i] += 1
             self._free.append(i)
             self._nrunning -= 1
             req.slot = None
@@ -326,11 +352,11 @@ class ContinuousBatcher:
 
     def step(self, group_index: Optional[int] = None) -> bool:
         """One scheduling iteration: sweep evictions/cancels, admit from
-        the queue into free slots, run ONE decode step for the live
-        batch, emit tokens, retire finished sequences. Returns False
-        when there was nothing to do (the caller's worker may park).
-        Callers serialize steps (engine decode lock); this lock only
-        covers slot/queue state."""
+        the queue into free slots, collect the decode in flight (or run
+        one), dispatch the next, emit tokens, retire finished sequences.
+        Returns False when there was nothing to do (the caller's worker
+        may park). Callers serialize steps (engine decode lock); this
+        lock only covers slot/queue state."""
         with self._lock:
             if self._cache is None:         # stopped: the cache is gone
                 return False
@@ -341,7 +367,7 @@ class ContinuousBatcher:
             with self._lock:
                 self._stepping = False
                 if self.stopped:
-                    self._cache = None
+                    self._cache = self._flight = None
 
     def _step(self, group_index: Optional[int]) -> bool:
         """The step itself (``step`` guards the cache around it)."""
@@ -359,7 +385,8 @@ class ContinuousBatcher:
         waiting_after = free_after = kv_used = 0
         with self._lock:
             # 1. sweep the running batch: client-gone and deadline-dead
-            # sequences leave BEFORE we spend a step on them
+            # sequences leave BEFORE we spend a step on them (a row
+            # already in flight for one is dropped at collection)
             for req in [r for r in self._slots if r is not None]:
                 if req._cancel:
                     self._retire_locked(req, CANCELED, done)
@@ -398,6 +425,10 @@ class ContinuousBatcher:
                 if req.tracker is not None:
                     req.tracker.gen_admitted(req.admitted_ns)
                 self._nrunning += 1
+                self._lens[i] = self._ntok[i] = 0
+                self._budget[i] = req.max_new_tokens
+                self._stop[i] = (-1 if req.stop_token is None
+                                 else req.stop_token)
                 admitted.append(req)
             active = [(i, r) for i, r in enumerate(self._slots)
                       if r is not None]
@@ -406,21 +437,22 @@ class ContinuousBatcher:
                 self.batch_hist[len(active)] += 1
                 if group_index is not None:
                     self.steps_by_group[group_index] += 1
+        flight, self._flight = self._flight, None
         if not active:
+            # nothing runs: a decode still in flight holds rows of
+            # swept slots alone, and is dropped
             self._fire(emits, done)
             if stats_on and done:
                 self._record_step(t0, t_sweep, t_sweep, t_sweep,
                                   group_index, 0, len(admitted),
                                   n_evicted, n_canceled, 0,
                                   len(self._waiting), len(self._free), 0,
-                                  0, None, None)
+                                  0, None, None, 0)
             return bool(done)
         # the device work, outside the lock: the cache, lens and last
         # tokens are only written by step(), and steps are serialized by
         # the engine's decode lock, so only the slot TABLE needed it.
-        # Admitted requests prefill (their first token comes from it);
-        # the slots that were running before this step decode one token
-        fresh = set(id(r) for r in admitted)
+        # Admitted requests prefill (their first token comes from it)
         firsts, pre_counts = [], []
         for req in admitted:
             self._cache, tok, counts, _aux = self.model.prefill(
@@ -428,34 +460,67 @@ class ContinuousBatcher:
             firsts.append(tok)
             pre_counts.append(counts)
         t_admit = time.monotonic_ns() if stats_on else 0
-        lengths = np.full((self.max_batch,), -1, np.int32)
-        for i, req in active:
-            if id(req) not in fresh:
-                lengths[i] = self._lens[i]
-        nxt = dec_counts = None
-        if (lengths >= 0).any():
-            self._cache, nxt, dec_counts, _aux = self.model.decode(
-                self._cache, self._last, lengths)
+        if flight is None:
+            # nothing in flight (a step raised before it dispatched
+            # one): the slots running before this step decode now
+            fresh = set(id(r) for r in admitted)
+            lengths = np.full((self.max_batch,), -1, np.int32)
+            for i, req in active:
+                if id(req) not in fresh:
+                    lengths[i] = self._lens[i]
+            if (lengths >= 0).any():
+                flight = self._dispatch(self._last, lengths,
+                                        self._epoch.copy())
         # one wait for the device: the tokens and the counters only
         import jax
         firsts, pre_counts, nxt, dec_counts = jax.device_get(
-            (firsts, pre_counts, nxt, dec_counts))
+            (firsts, pre_counts, None if flight is None else flight.tokens,
+             None if flight is None else flight.counts))
         t_decode = time.monotonic_ns() if stats_on else 0
-        first_of = dict(zip((id(r) for r in admitted), firsts))
         with self._lock:
-            for i, req in active:
+            # 3. decide from the fetched tokens alone which slots go on,
+            # at which lengths: a row counts while its slot's epoch is
+            # the one it was dispatched under
+            got = np.zeros((self.max_batch,), bool)
+            toks = np.zeros((self.max_batch,), np.int64)
+            lens = self._lens.copy()
+            if flight is not None:
+                rows = (flight.lengths >= 0) & (flight.epoch == self._epoch)
+                got |= rows
+                toks[rows] = nxt[rows]
+                lens[rows] += 1
+            for req, first in zip(admitted, firsts):
+                if req.slot is not None:    # not retired by stop()
+                    got[req.slot] = True
+                    toks[req.slot] = first
+                    lens[req.slot] = len(req.prompt)
+            finish = got & ((toks == self._stop)
+                            | (self._ntok + 1 >= self._budget)
+                            | (lens + 1 >= self.cache_len))
+            going = got & ~finish & (not self.stopped)
+            slots = np.flatnonzero(got)
+            owners = [self._slots[i] for i in slots]
+            epoch = self._epoch.copy()
+        # 4. the next decode, queued before this step's host work
+        overlapped = 0
+        if going.any():
+            self._flight = self._dispatch(
+                np.where(going, toks, 0).astype(np.int32),
+                np.where(going, lens, -1).astype(np.int32), epoch)
+            overlapped = 1
+            ndecode_overlapped.add(1)
+        # 5. hand out the tokens and retire, while the device decodes
+        with self._lock:
+            for i, req in zip(slots, owners):
                 if self._slots[i] is not req:
-                    continue        # canceled+retired during the step
+                    continue        # retired by stop() during the step
                 now = time.monotonic_ns()
-                if id(req) in fresh:
-                    tok = int(first_of[id(req)])
-                    self._lens[i] = len(req.prompt)
-                    if req.tracker is not None:
-                        req.tracker.gen_prefilled(now)
-                else:
-                    tok = int(nxt[i])
-                    self._lens[i] += 1
+                if not req.tokens and req.tracker is not None:
+                    req.tracker.gen_prefilled(now)
+                tok = int(toks[i])
+                self._lens[i] = lens[i]
                 self._last[i] = tok
+                self._ntok[i] += 1
                 req.tokens.append(tok)
                 self.tokens_out += 1
                 ntokens.add(1)
@@ -464,9 +529,7 @@ class ContinuousBatcher:
                 if req.tracker is not None:
                     req.tracker.gen_token(now)
                 emits.append((req, tok))
-                if (req.stop_token is not None and tok == req.stop_token) \
-                        or req.ntokens >= req.max_new_tokens \
-                        or int(self._lens[i]) + 1 >= self.cache_len:
+                if finish[i]:
                     self._retire_locked(req, COMPLETED, done)
             if stats_on:
                 waiting_after = len(self._waiting)
@@ -482,15 +545,22 @@ class ContinuousBatcher:
                               waiting_after, free_after, kv_used,
                               sum(len(r.prompt) for r in admitted),
                               _flat(dec_counts), _flat(_sum_counts(
-                                  pre_counts)))
+                                  pre_counts)), overlapped)
         return True
+
+    def _dispatch(self, last, lengths, epoch) -> _Flight:
+        """Queue one decode on the device (the cache donated) and keep
+        its outputs for the step that collects them."""
+        self._cache, nxt, counts, _aux = self.model.decode(
+            self._cache, last, lengths)
+        return _Flight(nxt, counts, lengths, epoch)
 
     def _record_step(self, t0: int, t_sweep: int, t_admit: int,
                      t_decode: int, group_index, batch: int,
                      admitted: int, evicted: int, canceled: int,
                      tokens: int, waiting: int, free_slots: int,
                      kv_used: int, prefill_tokens: int, routed,
-                     prefill_routed) -> None:
+                     prefill_routed, overlapped: int) -> None:
         """One bounded iteration record into the flight deck's step
         ring (leaf lock, outside every batcher lock): the Orca view —
         what THIS step did and where its microseconds went."""
@@ -522,6 +592,7 @@ class ContinuousBatcher:
             kv_used,
             routed,
             prefill_routed,
+            overlapped,
         ))
 
     @staticmethod
@@ -562,8 +633,9 @@ class ContinuousBatcher:
     # ----------------------------------------------------------- shutdown
     def stop(self) -> List[GenRequest]:
         """Refuse new work, retire everything in flight (CANCELED) and
-        release the device cache once no step is running. Returns the
-        retired requests (the service fails their calls)."""
+        release the device cache, and the decode in flight with it, once
+        no step is running. Returns the retired requests (the service
+        fails their calls)."""
         done: List[Tuple[GenRequest, str]] = []
         with self._lock:
             self.stopped = True
@@ -574,7 +646,7 @@ class ContinuousBatcher:
                 if r.state not in _TERMINAL:
                     self._retire_locked(r, CANCELED, done)
             if not self._stepping:
-                self._cache = None
+                self._cache = self._flight = None
         self._fire([], done)
         return [r for r, _ in done]
 

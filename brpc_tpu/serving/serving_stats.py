@@ -352,12 +352,19 @@ class GenTracker:
 
 # the step ring's record schema: the batcher writes positional tuples
 # in THIS order (cheap on the per-iteration path), step_records() zips
-# them back into dicts for every reader
+# them back into dicts for every reader. One record a step() call: it
+# describes the decode that call COLLECTED (``tokens`` - ``admitted``
+# are that decode's rows, ``routed`` its counter), the admissions whose
+# first tokens it emitted, and ``kv_used`` after. ``decode_us`` is the
+# wait for the collected decode (and the prefills), ``emit_us`` the
+# rest: the decision, the next decode's dispatch and the per-request
+# work and callbacks that overlap it. ``overlapped`` is 1 where the
+# call dispatched the next decode before that per-request work
 STEP_FIELDS = ("t_ms", "group", "batch", "admitted", "evicted",
                "canceled", "tokens", "waiting", "free_slots",
                "kv_occupancy", "sweep_us", "admit_us", "decode_us",
                "emit_us", "step_us", "prefill_tokens", "kv_used",
-               "routed", "prefill_routed")
+               "routed", "prefill_routed", "overlapped")
 
 
 class ServingStats:

@@ -207,6 +207,236 @@ class TestBatcherScheduling:
         assert b.canceled == 1
 
 
+# ------------------------------------------------------------ lookahead
+
+class _Recorder:
+    """The model behind a log: each decode call's live rows (and what
+    ``watch()`` reads then) go into ``log`` beside whatever the test's
+    callbacks write there, and the decode reports the rows as its
+    counter, so that a step record's ``routed`` names the decode it
+    collected."""
+
+    def __init__(self, inner, log):
+        self.inner = inner
+        self.log = log
+        self.watch = lambda: None
+        self.vocab = inner.vocab
+        self.cache_len = inner.cache_len
+        self.max_prompt = inner.max_prompt
+        self.rows = []
+
+    def new_cache(self, max_batch, cache_len):
+        return self.inner.new_cache(max_batch, cache_len)
+
+    def warm_up(self, cache):
+        return self.inner.warm_up(cache)
+
+    def prefill(self, cache, slot, tokens):
+        return self.inner.prefill(cache, slot, tokens)
+
+    def decode(self, cache, last_tokens, lengths):
+        import jax.numpy as jnp
+
+        live = int((np.asarray(lengths) >= 0).sum())
+        self.rows.append(live)
+        self.log.append(("decode", live, self.watch()))
+        cache, nxt, _counts, aux = self.inner.decode(cache, last_tokens,
+                                                     lengths)
+        return cache, nxt, jnp.asarray([live], jnp.int32), aux
+
+
+class _Deadline:
+    """A controller whose deadline the test expires by hand."""
+
+    expired = False
+
+    def deadline_expired(self) -> bool:
+        return self.expired
+
+    def remaining_ms(self):
+        return None
+
+
+def _records_of(run):
+    """The step records that ``run()`` wrote, and its result."""
+    from brpc_tpu.serving import serving_stats as ss
+
+    reg = ss.global_serving_stats()
+    before = reg.steps_recorded()
+    out = run()
+    n = reg.steps_recorded() - before
+    return (reg.step_records(n) if n else []), out
+
+
+class TestLookahead:
+    def test_next_decode_is_queued_before_this_steps_tokens(self, model):
+        """While the batch goes on, each step() dispatches the decode of
+        the next step before it hands out any of its own tokens (none
+        appended, no on_token fired), and says so in its record
+        (``overlapped``) and in ``serving_decode_overlapped``; the step
+        that drains the batch dispatches nothing."""
+        from brpc_tpu.serving import batcher as bm
+
+        log = []
+        rec = _Recorder(model, log)
+        b = ContinuousBatcher(rec, max_batch=2, max_waiting=4)
+        rec.watch = lambda: b.tokens_out
+        prompts, budgets = [b"order one", b"order two"], [12, 8]
+        reqs = [GenRequest(list(p), n,
+                           on_token=lambda r, t: log.append(("token",
+                                                             r.req_id)))
+                for p, n in zip(prompts, budgets)]
+        for r in reqs:
+            assert b.submit(r)
+        overlapped_before = bm.ndecode_overlapped.get_value()
+
+        def run():
+            calls = 0
+            while b.has_work():
+                log.append(("step", calls, b.tokens_out))
+                b.step(0)
+                calls += 1
+            return calls
+
+        records, calls = _records_of(run)
+        assert calls == 12 and len(records) == calls
+        steps = [i for i, e in enumerate(log) if e[0] == "step"]
+        for k, (lo, hi) in enumerate(zip(steps, steps[1:] + [len(log)])):
+            kinds = [e[0] for e in log[lo + 1:hi]]
+            assert "token" in kinds
+            if k < calls - 1:
+                # the decode of step k+1 precedes step k's tokens
+                assert kinds.count("decode") == 1
+                assert kinds.index("decode") < kinds.index("token"), kinds
+                decode = log[lo + 1 + kinds.index("decode")]
+                assert decode[2] == log[lo][2]
+            else:
+                assert "decode" not in kinds
+        assert [r["overlapped"] for r in records] == [1] * 11 + [0]
+        assert bm.ndecode_overlapped.get_value() - overlapped_before == 11
+        for r, p, n in zip(reqs, prompts, budgets):
+            assert r.state == COMPLETED
+            assert r.tokens == model.generate(list(p), n)
+
+    def test_streams_through_admission_cancel_deadline_and_stop(
+            self, model):
+        """Every stream equals the single-sequence oracle, cut where the
+        request left: staggered admission, a deadline and a cancel
+        between steps (each with its next row already in flight), a stop
+        token, a request admitted into the evicted slot in the step that
+        sweeps it, and the cancelled slot left empty with its row in
+        flight. No on_token after on_finish."""
+        prompts = [b"alpha run", b"bravo!", b"charlie c", b"delta",
+                   b"echo echo"]
+        budgets = [30, 20, 20, 40, 10]
+        oracle = [model.generate(list(p), n)
+                  for p, n in zip(prompts, budgets)]
+        stop_at = next(j for j in range(3, len(oracle[3]))
+                       if oracle[3][j] not in oracle[3][:j])
+        events = {i: [] for i in range(5)}
+        deadline = _Deadline()
+        b = ContinuousBatcher(model, max_batch=3, max_waiting=8)
+
+        def on_token(i):
+            def cb(req, tok):
+                events[i].append("token")
+                if i == 1 and req.ntokens == 4:
+                    b.cancel(req)
+                if i == 2 and req.ntokens == 3:
+                    deadline.expired = True
+            return cb
+
+        reqs = [GenRequest(list(p), n, on_token=on_token(i),
+                           on_finish=lambda r, s, i=i:
+                           events[i].append(s),
+                           cntl=deadline if i == 2 else None,
+                           stop_token=oracle[3][stop_at] if i == 3
+                           else None)
+                for i, (p, n) in enumerate(zip(prompts, budgets))]
+        assert b.submit(reqs[0])
+        b.step(0); b.step(0)
+        assert b.submit(reqs[1]) and b.submit(reqs[2])
+        b.step(0)
+        assert b.submit(reqs[3])
+        while reqs[1].state != CANCELED:
+            b.step(0)
+        assert b.running_count() == 2 and b.waiting_count() == 0
+        b.step(0)
+        assert b.submit(reqs[4])
+        _drain(b)
+        assert reqs[1].state == CANCELED and reqs[2].state == EVICTED
+        assert [r.state for r in (reqs[0], reqs[3], reqs[4])] \
+            == [COMPLETED] * 3
+        want = [oracle[0], oracle[1][:4], oracle[2][:3],
+                oracle[3][:stop_at + 1], oracle[4]]
+        for r, w in zip(reqs, want):
+            assert r.tokens == w
+        for i, ev in events.items():
+            assert ev[-1] in (COMPLETED, CANCELED, EVICTED)
+            assert ev[:-1] == ["token"] * len(want[i]), (i, ev)
+        assert b.kv_occupancy() == 0.0 and b.running_count() == 0
+
+    def test_step_records_pair_each_decode_with_its_rows(self, model):
+        """The records of a staggered run: their ``tokens`` sum to the
+        tokens emitted, and each record's ``tokens - admitted`` is the
+        live rows of the very decode whose counter it carries in
+        ``routed`` (how benchmark/lib/generation.py pairs them), in
+        dispatch order."""
+        log = []
+        rec = _Recorder(model, log)
+        b = ContinuousBatcher(rec, max_batch=3, max_waiting=8)
+        prompts = [b"p-%d" % i for i in range(6)]
+        budgets = [9, 4, 13, 6, 11, 5]
+        reqs = [GenRequest(list(p), n) for p, n in zip(prompts, budgets)]
+
+        def run():
+            for i, r in enumerate(reqs):
+                assert b.submit(r)
+                b.step(0)
+                if i % 2:
+                    b.step(0)
+            _drain(b)
+
+        records, _ = _records_of(run)
+        assert sum(r["tokens"] for r in records) \
+            == sum(len(r.tokens) for r in reqs) == sum(budgets)
+        made = [r["tokens"] - r["admitted"] for r in records
+                if r["routed"] is not None]
+        assert made == rec.rows
+        assert all(r["routed"] == (r["tokens"] - r["admitted"],)
+                   for r in records if r["routed"] is not None)
+        assert all(r["tokens"] == r["admitted"] for r in records
+                   if r["routed"] is None)
+        for r, p, n in zip(reqs, prompts, budgets):
+            assert r.tokens == model.generate(list(p), n)
+
+    def test_stop_with_a_decode_in_flight(self, model):
+        """stop() between steps, with the next decode in flight: every
+        request retires CANCELED with no token from that decode, the
+        cache and the decode in flight are let go, and no step runs."""
+        b = ContinuousBatcher(model, max_batch=2, max_waiting=4)
+        seen = {}
+        reqs = [GenRequest(list(p), 20,
+                           on_token=lambda r, t: seen.setdefault(
+                               r.req_id, []).append("token"),
+                           on_finish=lambda r, s: seen.setdefault(
+                               r.req_id, []).append(s))
+                for p in (b"stop me", b"and me")]
+        for r in reqs:
+            assert b.submit(r)
+        b.step(0); b.step(0)
+        assert b._flight is not None
+        retired = b.stop()
+        assert set(map(id, retired)) == set(map(id, reqs))
+        for r in reqs:
+            assert r.state == CANCELED and r.ntokens == 2
+            assert seen[r.req_id] == ["token", "token", CANCELED]
+        assert b._cache is None and b._flight is None
+        assert b.cache_bytes() == b._lens.nbytes + b._last.nbytes
+        assert not b.step(0) and not b.has_work()
+        assert b.kv_occupancy() == 0.0
+
+
 # ------------------------------------------------------------ end-to-end
 
 def _start_serving_server(addr="tcp://127.0.0.1:0", builtin=True, **kw):
