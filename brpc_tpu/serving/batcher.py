@@ -26,8 +26,9 @@ Device work: one step of lookahead. The decode a ``step()`` collects
 was dispatched by the step before it; a call runs the model's prefill
 program for each request admitted in it (the donated cache orders it
 behind the decode in flight), then ONE ``device_get`` brings the
-in-flight decode's tokens and counters and the prefills' first tokens
-to the host. From those alone it decides, in numpy over the slot table,
+in-flight decode's tokens and counters (and its aux's ``attn_fetched``
+where the model gives one) and the prefills' first tokens to the
+host. From those alone it decides, in numpy over the slot table,
 which slots continue (budget, stop token, cache room) and at which
 lengths, and dispatches the NEXT decode at once; only then does it hand
 out this step's tokens, retire the finished and fire the callbacks,
@@ -75,9 +76,10 @@ def _flat(counts):
     return tuple(int(c) for c in np.ravel(counts))
 
 
-# a decode dispatched and not yet collected: its device outputs, and the
-# lengths and slot epochs it was dispatched with
-_Flight = namedtuple("_Flight", "tokens counts lengths epoch")
+# a decode dispatched and not yet collected: its device outputs (with
+# the aux's ``attn_fetched``, or None), and the lengths and slot epochs
+# it was dispatched with
+_Flight = namedtuple("_Flight", "tokens counts fetched lengths epoch")
 
 
 def _sum_counts(arrays):
@@ -447,7 +449,7 @@ class ContinuousBatcher:
                                   group_index, 0, len(admitted),
                                   n_evicted, n_canceled, 0,
                                   len(self._waiting), len(self._free), 0,
-                                  0, None, None, 0)
+                                  0, None, None, 0, None)
             return bool(done)
         # the device work, outside the lock: the cache, lens and last
         # tokens are only written by step(), and steps are serialized by
@@ -473,9 +475,10 @@ class ContinuousBatcher:
                                         self._epoch.copy())
         # one wait for the device: the tokens and the counters only
         import jax
-        firsts, pre_counts, nxt, dec_counts = jax.device_get(
-            (firsts, pre_counts, None if flight is None else flight.tokens,
-             None if flight is None else flight.counts))
+        firsts, pre_counts, nxt, dec_counts, fetched = jax.device_get(
+            (firsts, pre_counts) + ((None,) * 3 if flight is None else
+                                    (flight.tokens, flight.counts,
+                                     flight.fetched)))
         t_decode = time.monotonic_ns() if stats_on else 0
         with self._lock:
             # 3. decide from the fetched tokens alone which slots go on,
@@ -545,22 +548,25 @@ class ContinuousBatcher:
                               waiting_after, free_after, kv_used,
                               sum(len(r.prompt) for r in admitted),
                               _flat(dec_counts), _flat(_sum_counts(
-                                  pre_counts)), overlapped)
+                                  pre_counts)), overlapped,
+                              None if fetched is None else int(fetched))
         return True
 
     def _dispatch(self, last, lengths, epoch) -> _Flight:
         """Queue one decode on the device (the cache donated) and keep
         its outputs for the step that collects them."""
-        self._cache, nxt, counts, _aux = self.model.decode(
+        self._cache, nxt, counts, aux = self.model.decode(
             self._cache, last, lengths)
-        return _Flight(nxt, counts, lengths, epoch)
+        return _Flight(nxt, counts, (aux or {}).get("attn_fetched"),
+                       lengths, epoch)
 
     def _record_step(self, t0: int, t_sweep: int, t_admit: int,
                      t_decode: int, group_index, batch: int,
                      admitted: int, evicted: int, canceled: int,
                      tokens: int, waiting: int, free_slots: int,
                      kv_used: int, prefill_tokens: int, routed,
-                     prefill_routed, overlapped: int) -> None:
+                     prefill_routed, overlapped: int,
+                     attn_fetched) -> None:
         """One bounded iteration record into the flight deck's step
         ring (leaf lock, outside every batcher lock): the Orca view —
         what THIS step did and where its microseconds went."""
@@ -593,6 +599,7 @@ class ContinuousBatcher:
             routed,
             prefill_routed,
             overlapped,
+            attn_fetched,
         ))
 
     @staticmethod

@@ -18,7 +18,10 @@ with one slot a sequence, and three calls; the batcher
 Both take the cache DONATED and return the new one: the rows are
 written in place on the device, and per step only the tokens and the
 small ``counters`` array (or None) cross to the host. ``aux`` holds
-device arrays the batcher never reads (the logits, for checks). Beside
+device arrays for checks (the logits), or None; of a decode's aux the
+batcher reads ``attn_fetched`` alone where it is there (an int32
+scalar: the cached rows the attention fetched a layer) into that
+decode's step record, in the same fetch as its tokens. Beside
 the calls a model has ``vocab``, ``cache_len`` (positions a slot
 holds), ``max_prompt`` and ``warm_up(cache) -> cache``, which compiles
 every program the batcher will run. ``serving/moonlight.py`` is the

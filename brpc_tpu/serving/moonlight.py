@@ -45,7 +45,10 @@ the tokens each held expert received in each expert layer (int32
 ``[moe_layers, experts_held]``) and, as device arrays the batcher never
 reads, the logits that chose the token(s) and the experts every token
 chose, and from decode what each expert layer's router read (for
-checks against the reference).
+checks against the reference). Decode's aux also carries
+``attn_fetched``, the cached rows its attention kernel fetched a layer
+(``ops/latent_attention.fetched_rows``), which the batcher keeps in
+the step record of that decode.
 """
 
 from __future__ import annotations
@@ -58,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from brpc_tpu.ops.latent_attention import latent_attention
+from brpc_tpu.ops.latent_attention import fetched_rows, latent_attention
 
 WEIGHT_DTYPE = jnp.bfloat16
 LATENT_DTYPE = jnp.bfloat16
@@ -451,7 +454,9 @@ def decode_program(params, cache, last_tokens, lengths, *, cfg):
              "experts": _stack(chosen, (0,) + last_tokens.shape
                                + (cfg.num_experts_per_tok,)),
              "router_in": _stack(router_in, (0,) + last_tokens.shape
-                                 + (cfg.hidden_size,), jnp.float32)})
+                                 + (cfg.hidden_size,), jnp.float32),
+             "attn_fetched": fetched_rows(jnp.where(live, pos + 1, 0),
+                                          cache[0].shape[1])})
 
 
 def _stack(arrays, empty_shape, dtype=jnp.int32):

@@ -359,12 +359,14 @@ class GenTracker:
 # wait for the collected decode (and the prefills), ``emit_us`` the
 # rest: the decision, the next decode's dispatch and the per-request
 # work and callbacks that overlap it. ``overlapped`` is 1 where the
-# call dispatched the next decode before that per-request work
+# call dispatched the next decode before that per-request work;
+# ``attn_fetched`` the cached rows the collected decode's attention
+# fetched a layer (None where the model does not say)
 STEP_FIELDS = ("t_ms", "group", "batch", "admitted", "evicted",
                "canceled", "tokens", "waiting", "free_slots",
                "kv_occupancy", "sweep_us", "admit_us", "decode_us",
                "emit_us", "step_us", "prefill_tokens", "kv_used",
-               "routed", "prefill_routed", "overlapped")
+               "routed", "prefill_routed", "overlapped", "attn_fetched")
 
 
 class ServingStats:

@@ -261,23 +261,64 @@ def test_each_program_is_compiled_once(state):
     assert model._decode._cache_size() == 1
 
 
-@pytest.mark.parametrize("rows", [[0, 1, 16, 17, 48], [48, 47, 33, 2, 5]])
-def test_latent_attention_kernel_over_blocks(rows):
-    """The decode kernel (blocks of 16 rows here, three a slot) against
-    a plain softmax over each slot's live rows: one row, a whole block,
-    a block and one, the whole cache; an idle slot (0 rows) is left
-    alone by the caller."""
+@pytest.mark.parametrize("cache_len,rows", [
+    (48, [0, 1, 16, 17, 48]), (48, [48, 47, 33, 2, 5]),
+    # chunk - 1, and an idle slot between two live ones
+    (48, [15, 0, 17, 48, 1]),
+    # the first slots idle, the last live
+    (48, [0, 0, 16, 15, 48]),
+    # nothing live: nothing fetched, nothing computed
+    (48, [0, 0, 0, 0, 0]),
+    # several turns of four chunks (64 rows): a whole turn, one less,
+    # one more, the whole cache; turns ending in 1, 2 and 3 chunks
+    (208, [64, 63, 0, 65, 208]), (208, [0, 129, 112, 0, 17])])
+def test_latent_attention_kernel_over_blocks(cache_len, rows):
+    """The decode kernel (chunks of 16 rows here, up to four a turn of
+    its loop) against a plain softmax over each slot's live rows: one
+    row, a chunk less one, a whole chunk, a chunk and one, the whole
+    cache; the stream of copies passes over idle slots (0 rows), whose
+    output the caller leaves alone."""
     kq, kc = jax.random.split(jax.random.key(5))
     width, kvr, heads = 128, 96, 4
     q = jax.random.normal(kq, (5, heads, width)).astype(jnp.bfloat16)
-    cache = jax.random.normal(kc, (5, 48, width)).astype(jnp.bfloat16)
+    cache = jax.random.normal(kc, (5, cache_len, width)).astype(jnp.bfloat16)
     n = jnp.asarray(rows, jnp.int32)
     out = np.asarray(latent_attention(q, cache, n, scale=0.25, kvr=kvr))
     s = jnp.einsum("bhc,btc->bht", q.astype(jnp.float32),
                    cache.astype(jnp.float32)) * 0.25
-    s = jnp.where(jnp.arange(48)[None, None] < n[:, None, None], s, -jnp.inf)
+    s = jnp.where(jnp.arange(cache_len)[None, None] < n[:, None, None], s,
+                  -jnp.inf)
     want = np.asarray(jnp.einsum("bht,btc->bhc", jax.nn.softmax(s, -1),
                                  cache.astype(jnp.float32))[..., :kvr])
     live = np.asarray(n) > 0
     # the kernel weights the rows in bfloat16 (2^-9 relative)
     np.testing.assert_allclose(out[live], want[live], atol=0.03)
+
+
+@pytest.mark.parametrize("cache_len,chunk", [(48, 16), (64, 64),
+                                             (4096, 256)])
+def test_fetched_rows_are_live_rows_in_whole_chunks(cache_len, chunk):
+    """What the kernel fetches: each live slot's rows rounded up to whole
+    chunks (the largest of 256 ... 8 rows that divides the cache's
+    length), nothing for an idle slot or an all-idle batch; the decode
+    program returns it as ``attn_fetched``."""
+    from brpc_tpu.ops.latent_attention import _chunk_rows, fetched_rows
+
+    assert _chunk_rows(cache_len) == chunk
+    rows = np.array([0, 1, chunk - 1, chunk, chunk + 1, cache_len, 0])
+    rows = np.minimum(rows, cache_len)
+    want = sum(-(-int(r) // chunk) * chunk for r in rows if r > 0)
+    got = fetched_rows(jnp.asarray(rows, jnp.int32), cache_len)
+    assert got.dtype == jnp.int32 and int(got) == want
+    assert int(fetched_rows(jnp.zeros((5,), jnp.int32), cache_len)) == 0
+
+
+def test_decode_reports_what_its_attention_fetched(model):
+    """The decode program's ``attn_fetched``: a slot at length L attends
+    over L + 1 rows, fetched in whole chunks of 64 here (the cache's 64
+    positions); an idle slot (length -1) fetches nothing."""
+    cache = model.new_cache(4, CACHE_LEN)
+    lengths = np.array([0, -1, 63, 30], np.int32)
+    _cache, _nxt, _counts, aux = model.decode(
+        cache, np.zeros((4,), np.int32), lengths)
+    assert int(aux["attn_fetched"]) == 64 + 64 + 64

@@ -436,6 +436,67 @@ class TestLookahead:
         assert not b.step(0) and not b.has_work()
         assert b.kv_occupancy() == 0.0
 
+    def test_records_carry_the_collected_decodes_fetch(self, model):
+        """A decode whose aux says what its attention fetched
+        (``attn_fetched``) has it written into the record of the step
+        that collected it: through the lookahead, and in the step after
+        one that raised before it dispatched, which decodes with nothing
+        in flight. The toy's records say nothing (None)."""
+        import jax.numpy as jnp
+
+        class Fetching(_Recorder):
+            fail_at = None
+
+            def decode(self, cache, last_tokens, lengths):
+                if len(self.rows) == self.fail_at:
+                    self.fail_at = None
+                    raise RuntimeError("decode refused once")
+                cache, nxt, counts, _aux = super().decode(
+                    cache, last_tokens, lengths)
+                n = np.asarray(lengths)
+                self.fetched.append(int((n[n >= 0] + 1).sum()) * 10)
+                return cache, nxt, counts, {
+                    "attn_fetched": jnp.int32(self.fetched[-1])}
+
+        rec = Fetching(model, [])
+        rec.fetched = []
+        b = ContinuousBatcher(rec, max_batch=3, max_waiting=8)
+        reqs = [GenRequest(list(p), n) for p, n in
+                ((b"fetch one", 9), (b"fetch two", 6), (b"three", 12))]
+
+        def run():
+            for r in reqs:
+                assert b.submit(r)
+            b.step(0); b.step(0); b.step(0)
+            rec.fail_at = len(rec.rows)
+            with pytest.raises(RuntimeError):
+                b.step(0)
+            assert b._flight is None
+            _drain(b)
+
+        records, _ = _records_of(run)
+        collected = [r for r in records if r["routed"] is not None]
+        # every decode dispatched was collected by one record, in order,
+        # but the third: the step that collected it raised, and wrote none
+        assert [r["attn_fetched"] for r in collected] \
+            == rec.fetched[:2] + rec.fetched[3:]
+        assert all(r["attn_fetched"] is None for r in records
+                   if r["routed"] is None)
+        # the step after the raise decoded with nothing in flight, over
+        # the rows the raising step collected but never handed out
+        assert rec.fetched[3] == rec.fetched[2]
+        assert all(r.state == COMPLETED for r in reqs)
+
+        plain, _ = _records_of(lambda: _drain(_fed(model)))
+        assert plain and all(r["attn_fetched"] is None for r in plain)
+
+
+def _fed(model):
+    b = ContinuousBatcher(model, max_batch=2, max_waiting=4)
+    for p in (b"plain one", b"plain two"):
+        assert b.submit(GenRequest(list(p), 5))
+    return b
+
 
 # ------------------------------------------------------------ end-to-end
 
